@@ -19,6 +19,7 @@ import time
 import numpy as np
 
 from benchmarks.fog import DEFAULT, FULL, QUICK, dataset, fog_experiment
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "results")
@@ -1404,7 +1405,7 @@ def scenario_batched(scale):
     the min over ``--repeat`` steady-state repeats (the forced loop
     runs in between mark the loop programs compiled, so warm dispatch
     prices the loop path fairly and keeps only buckets where batching
-    still wins — the warm staged-cache / donation path re-uses device
+    still wins — the warm staged cache re-uses device
     buckets across repeats). RECORDS (the test suite is what asserts —
     tests/test_engine_batched.py) whether the per-scenario accuracy
     histories are bitwise-equal to the loop path, whether a fig5
@@ -1619,6 +1620,7 @@ def dryrun_roofline(scale):
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names or glob "

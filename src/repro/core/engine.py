@@ -10,9 +10,9 @@ the whole horizon is one device-resident program:
   ``PRESTAGE_LIMIT_BYTES``, or per-round inside the scan body);
 * the vmapped local-SGD step (eq. 3), the every-τ H-weighted
   aggregation (eq. 4), synchronization, churn masking and
-  H-accumulation are folded into a single ``jax.lax.scan`` over rounds
-  with donated carries (donation is skipped on CPU where XLA does not
-  support it).
+  H-accumulation are folded into a single ``jax.lax.scan`` over rounds.
+  Nothing is donated: no output has the shape of the parameter stack,
+  so XLA could reuse none of its buffers.
 
 ``run_rounds_batched`` makes the SWEEP axis itself a compiled
 dimension: S scenarios — padded up to a shared shape bucket
@@ -27,7 +27,7 @@ compiles #buckets programs (``batched_compile_count``).
 
 ``run_rounds_sharded`` is the S=1 slice of the batched path with the
 fog-device axis partitioned across a 1-D "data" mesh via ``shard_map``
-(``distributed/sharding.py`` shim, ``launch/mesh.make_data_mesh``);
+(``distributed/sharding.py``, ``launch/mesh.make_data_mesh``);
 the every-τ H-weighted aggregation is a cross-shard ``psum``
 reduction. Test evaluation is streamed OFF the hot path by an
 :class:`AsyncEvaluator` — the scan emits per-window global-parameter
@@ -458,8 +458,7 @@ def _scan_program(apply_fn, eta: float, prestage: bool,
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
 
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(train, donate_argnums=donate)
+    return jax.jit(train)
 
 
 @functools.lru_cache(maxsize=16)
@@ -620,8 +619,7 @@ def _hier_program(apply_fn, eta: float, prestage: bool,
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
 
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(train, donate_argnums=donate)
+    return jax.jit(train)
 
 
 def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
@@ -818,8 +816,7 @@ class AsyncEvaluator:
     parameter snapshots (e.g. the (S, windows) grid of a scenario
     bucket) in one vmapped dispatch, so one evaluator drains an entire
     bucket's eval queue. The test set is pinned device-resident;
-    submissions hold device arrays only, which keeps them
-    donation-friendly for the surrounding engine programs.
+    submissions hold device arrays only.
 
     Error handling: a failure while dispatching (trace/compile errors)
     or while the device computation resolves is never swallowed — it is
@@ -1281,8 +1278,7 @@ def _bucket_program(apply_fn, eta: float, prestage: bool, mesh,
             out_specs = out_specs + (P(), P(), P())
         fn = shard_map(fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    fn = jax.jit(fn, donate_argnums=donate)
+    fn = jax.jit(fn)
     _BUCKET_PROGRAMS[key] = fn
     return fn
 
@@ -1302,10 +1298,8 @@ def _pad_axis(a, size: int, axis: int):
 # fresh host->device upload per operand. The cache keys the STAGED
 # device operands by a fingerprint of the pre-staging inputs (stream
 # bytes, activity, fault views, dataset identity, staging/bucket/τ
-# config), so a warm re-run reuses the device buffers outright. Safe
-# under donation: the only donated argument of the bucket programs is
-# the parameter stack W0, which is staged fresh per call — cached
-# operands are never donated. Bytes-capped LRU like the other caches.
+# config), so a warm re-run reuses the device buffers outright.
+# Bytes-capped LRU like the other caches.
 # ---------------------------------------------------------------------------
 _STAGED_CACHE_LIMIT_BYTES = 512 * 1024 ** 2
 _STAGED_CACHE: collections.OrderedDict = collections.OrderedDict()
@@ -1592,8 +1586,6 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
 
     # parameter stacks staged host-side: one device put per leaf
     # instead of per-(bucket shape) broadcast/stack mini-programs.
-    # W0 is the donated operand, so it is built fresh every call and
-    # never cached.
     tree_map = jax.tree_util.tree_map
     W0 = tree_map(
         lambda *ps: jnp.asarray(np.stack([np.broadcast_to(

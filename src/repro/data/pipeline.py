@@ -136,8 +136,9 @@ def counts(streams) -> np.ndarray:
 def counts_flat(flat: FlatStreams) -> np.ndarray:
     """(T, n) per-cell sample counts of a flat stream — the per-device
     gather of held shares, computed through the segment-sum kernel
-    dispatch (``kernels.ops.segment_sum``: jnp scatter on CPU, Pallas
-    one-hot-matmul on accelerators)."""
+    dispatch (``kernels.ops.segment_sum``: jnp scatter on CPU and for
+    more cells than the kernel's segment bound, Pallas one-hot kernel
+    on accelerators otherwise)."""
     from repro.kernels import ops
     N = flat.idx.shape[0]
     if N == 0:

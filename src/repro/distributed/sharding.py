@@ -7,7 +7,7 @@ that falls back to replication when a dim does not divide the mesh extent
 (we design shapes so this never triggers for the production meshes — see
 DESIGN.md §6 — but the guard keeps arbitrary smoke configs safe).
 
-Also home of the version-compat ``shard_map`` shim used by every
+Also home of the ``shard_map`` (replication checks off) used by every
 manual-SPMD path (the fog scan engine's device-sharded runner and the
 production FedAvg round): the per-fog-device parameter stacks diverge
 between aggregations, which replicated-pjit params cannot express.
@@ -20,14 +20,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax < 0.5 ships shard_map under experimental with check_rep instead of
-# check_vma; keep both spellings working
-if hasattr(jax, "shard_map"):
-    shard_map = partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    shard_map = partial(_shard_map_exp, check_rep=False)
+shard_map = partial(jax.shard_map, check_vma=False)
 
 # Default logical->mesh rules for the production meshes. "batch" maps to
 # ("pod","data") — on the single-pod mesh "pod" is simply absent and drops

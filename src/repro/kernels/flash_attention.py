@@ -21,10 +21,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; keep both spellings working
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -126,7 +122,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,10 +19,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 INF = 3.4e38  # python float: jnp scalars would be captured as consts
 
-# jax < 0.5 names this TPUCompilerParams; keep both spellings working
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(clink_ref, cnext_ref, cnode_ref, ferr_ref, adj_ref,
             choice_ref, bestj_ref, bestc_ref, min_sc, arg_sc, *,
@@ -99,7 +95,7 @@ def offload_greedy(c_link, c_next, c_node, f_err, adj, *, bn: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((bn,), jnp.float32),
                         pltpu.VMEM((bn,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(c_link, c_next[None, :], c_node[None, :], f_err[None, :], adj)

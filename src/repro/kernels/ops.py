@@ -28,6 +28,15 @@ from repro.kernels.ssd_scan import ssd_scan
 # count (accelerators only — on CPU the kernel runs in interpret mode
 # and the fused jnp scatter wins); mirrors movement.PALLAS_MIN_N
 PALLAS_MIN_N = 256
+# ...and only up to this many segments: the one-hot kernel visits every
+# element once per 128-segment tile, so its work is E·⌈S/128⌉ and an
+# unbounded S (counts_flat has S = T·n) would make its grid quadratic
+PALLAS_MAX_SEGMENTS = 1024
+
+
+def _segment_kernel_default(n_elems: int, num_segments: int) -> bool:
+    return (jax.default_backend() != "cpu" and n_elems >= PALLAS_MIN_N
+            and num_segments <= PALLAS_MAX_SEGMENTS)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "use_pallas"))
@@ -146,12 +155,15 @@ def _topk_csr_core(c_link_e, c_next, indices, live, pad, *, k):
 
 @partial(jax.jit, static_argnames=("num_segments", "use_pallas"))
 def segment_sum(data, segment_ids, *, num_segments, use_pallas=None):
-    """out[s] = Σ data[segment_ids == s] over (E,) edge data. Pallas
-    one-hot-matmul kernel on accelerators above PALLAS_MIN_N elements,
-    fused jnp scatter otherwise (bitwise oracle)."""
+    """out[s] = Σ data[segment_ids == s] over (E,) edge data.
+
+    ``use_pallas=None`` takes the Pallas one-hot kernel on accelerators
+    when E ≥ PALLAS_MIN_N and ``num_segments`` ≤ PALLAS_MAX_SEGMENTS —
+    the kernel then makes at most 8 passes over the elements, so every
+    call finishes in O(E) — and the fused jnp scatter (bitwise oracle)
+    otherwise."""
     if use_pallas is None:
-        use_pallas = (jax.default_backend() != "cpu"
-                      and data.shape[0] >= PALLAS_MIN_N)
+        use_pallas = _segment_kernel_default(data.shape[0], num_segments)
     if use_pallas:
         return segment_sum_pallas(data, segment_ids, num_segments)
     return jax.ops.segment_sum(jnp.asarray(data, jnp.float32),
@@ -169,7 +181,7 @@ def segment_sum_rows(data, segment_ids, *, num_segments,
     updates in row-index order — per-segment accumulation order is the
     row order, independent of how many rows other segments own (the
     property the ragged engine's in-bucket-equals-alone bitwise
-    guarantee rests on). The Pallas one-hot-matmul kernel covers the
+    guarantee rests on). The Pallas one-hot kernel covers the
     flat (E,) case only; ND payloads flatten through it column-wise
     when it is forced on."""
     data = jnp.asarray(data, jnp.float32)
@@ -189,10 +201,10 @@ def segment_sum_rows(data, segment_ids, *, num_segments,
 
 @partial(jax.jit, static_argnames=("num_segments", "use_pallas"))
 def segment_max(data, segment_ids, *, num_segments, use_pallas=None):
-    """out[s] = max data[segment_ids == s] (−inf for empty segments)."""
+    """out[s] = max data[segment_ids == s] (−inf for empty segments);
+    same kernel dispatch rule as :func:`segment_sum`."""
     if use_pallas is None:
-        use_pallas = (jax.default_backend() != "cpu"
-                      and data.shape[0] >= PALLAS_MIN_N)
+        use_pallas = _segment_kernel_default(data.shape[0], num_segments)
     if use_pallas:
         return segment_max_pallas(data, segment_ids, num_segments)
     return jax.ops.segment_max(jnp.asarray(data, jnp.float32),

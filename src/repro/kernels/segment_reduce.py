@@ -7,19 +7,24 @@ volumes grouped by receiver) and H-weighted aggregation over an active
 device list. Both are segment reductions ``out[s] = op over
 data[segment_ids == s]``.
 
-Kernel shape: elements are padded/reshaped to (chunks, CHUNK) and
-segments to (tiles, BS); the grid is (segment tiles × element chunks)
-with the chunk axis ``arbitrary`` so each output tile is revisited and
-accumulated in place (same discipline as ``offload_greedy``'s column
-sweep). Each (tile, chunk) step builds the one-hot membership matrix
-``hit[s, c] = (ids[c] == tile_base + s)`` and reduces it — a (BS, CHUNK)
-matmul for sum (MXU-friendly) and a masked row-max for max. Segment ids
-need NOT be sorted.
+Kernel shape: elements are padded/reshaped to (rows, CHUNK) and read
+ROWS rows — one (8, 128) f32 tile — per grid step; segments are cut
+into tiles of BS. The grid is (segment tiles × element blocks) with the
+block axis ``arbitrary``, so each tile's (BS, CHUNK) VMEM accumulator
+is revisited and folded in place (same discipline as
+``offload_greedy``'s column sweep). Each row of a step builds the
+one-hot membership ``hit[s, c] = (ids[c] == tile_base + s)`` and folds
+the selected values into the accumulator elementwise (add for sum, max
+for max); the tile's last step transposes the accumulator so segments
+run along lanes and reduces it to the (1, BS) output row. Segment ids
+need NOT be sorted. The work is E·⌈S/BS⌉ element visits, so the
+dispatch in ``kernels.ops`` bounds S.
 
 Empty segments match the jnp fallback identities (``jax.ops``):
 0 for sum, −inf for max. On CPU the kernel runs in interpret mode;
 ``kernels.ops.segment_sum`` / ``segment_max`` pick the jnp fallback
-below ``PALLAS_MIN_N`` elements or off-accelerator.
+off-accelerator, below ``PALLAS_MIN_N`` elements and above
+``PALLAS_MAX_SEGMENTS`` segments.
 """
 from __future__ import annotations
 
@@ -30,33 +35,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 BS = 128      # segment tile (lane dimension of the output)
-CHUNK = 128   # element chunk reduced per grid step
+CHUNK = 128   # elements per row (lane dimension of the inputs)
+ROWS = 8      # rows per grid step: one (8, 128) tile of ids and data
 
 
-def _seg_kernel(ids_ref, data_ref, out_ref, *, op: str):
+def _seg_kernel(ids_ref, data_ref, out_ref, acc_ref, *, op: str):
     cj = pl.program_id(1)
+    ident = 0.0 if op == "sum" else -jnp.inf
 
     @pl.when(cj == 0)
     def _init():
-        out_ref[...] = jnp.full_like(
-            out_ref, 0.0 if op == "sum" else -jnp.inf)
+        acc_ref[...] = jnp.full_like(acc_ref, ident)
 
-    si = pl.program_id(0)
-    ids = ids_ref[0, :]                                   # (CHUNK,) int32
-    vals = data_ref[0, :].astype(jnp.float32)             # (CHUNK,)
-    rows = si * BS + jax.lax.broadcasted_iota(jnp.int32, (BS, CHUNK), 0)
-    hit = rows == ids[None, :]                            # (BS, CHUNK)
-    if op == "sum":
-        acc = jnp.dot(hit.astype(jnp.float32), vals[:, None],
-                      preferred_element_type=jnp.float32)[:, 0]
-        out_ref[0, :] += acc
-    else:
-        masked = jnp.where(hit, vals[None, :], -jnp.inf)
-        out_ref[0, :] = jnp.maximum(out_ref[0, :], masked.max(axis=1))
+    seg = (pl.program_id(0) * BS
+           + jax.lax.broadcasted_iota(jnp.int32, (BS, CHUNK), 0))
+    acc = acc_ref[...]
+    for r in range(ROWS):
+        hit = seg == ids_ref[r:r + 1, :]                  # (BS, CHUNK)
+        sel = jnp.where(hit, data_ref[r:r + 1, :], ident)
+        acc = acc + sel if op == "sum" else jnp.maximum(acc, sel)
+    acc_ref[...] = acc
+
+    @pl.when(cj == pl.num_programs(1) - 1)
+    def _finalize():
+        lanes = acc_ref[...].T                            # (CHUNK, BS)
+        if op == "sum":
+            out_ref[...] = lanes.sum(axis=0, keepdims=True)
+        else:
+            out_ref[...] = lanes.max(axis=0, keepdims=True)
 
 
 def _segment_reduce(data, segment_ids, num_segments: int, op: str,
@@ -64,27 +71,29 @@ def _segment_reduce(data, segment_ids, num_segments: int, op: str,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     E = data.shape[0]
-    nchunks = max(1, -(-E // CHUNK))
+    block = ROWS * CHUNK
+    nblocks = max(1, -(-E // block))
     ntiles = max(1, -(-num_segments // BS))
-    epad = nchunks * CHUNK - E
+    epad = nblocks * block - E
     # padded elements point one past the last segment tile: they match
     # no output row, so padding contributes the identity
     ids = jnp.concatenate([
         jnp.asarray(segment_ids, jnp.int32),
-        jnp.full((epad,), ntiles * BS, jnp.int32)]).reshape(nchunks, CHUNK)
+        jnp.full((epad,), ntiles * BS, jnp.int32)]).reshape(-1, CHUNK)
     vals = jnp.concatenate([
         jnp.asarray(data, jnp.float32),
-        jnp.zeros((epad,), jnp.float32)]).reshape(nchunks, CHUNK)
+        jnp.zeros((epad,), jnp.float32)]).reshape(-1, CHUNK)
     out = pl.pallas_call(
         partial(_seg_kernel, op=op),
-        grid=(ntiles, nchunks),
+        grid=(ntiles, nblocks),
         in_specs=[
-            pl.BlockSpec((1, CHUNK), lambda si, cj: (cj, 0)),
-            pl.BlockSpec((1, CHUNK), lambda si, cj: (cj, 0)),
+            pl.BlockSpec((ROWS, CHUNK), lambda si, cj: (cj, 0)),
+            pl.BlockSpec((ROWS, CHUNK), lambda si, cj: (cj, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BS), lambda si, cj: (si, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntiles, BS), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((None, 1, BS), lambda si, cj: (si, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ntiles, 1, BS), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((BS, CHUNK), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids, vals)

@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; keep both spellings working
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_sc, *, l: int):
     ci = pl.program_id(2)
@@ -90,7 +86,7 @@ def ssd_scan(xdt, a, Bm, Cm, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xdt, a, Bm, Cm)

@@ -40,6 +40,7 @@ from repro.core.topology import make_schedule, make_topology
 from repro.data import pipeline as pl
 from repro.data.synthetic import make_image_dataset, make_token_dataset
 from repro.launch import steps as St
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.module import init_params
 from repro.optim import optimizers as opt_lib
@@ -299,6 +300,7 @@ def run_lm(args) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["fog", "lm"], default="fog")
     ap.add_argument("--seed", type=int, default=0)

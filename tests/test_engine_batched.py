@@ -1,15 +1,16 @@
 """Scenario-batched engine vs the per-point scan path.
 
-Bitwise history equivalence across a shape bucket — mixed (T, n)
-shapes padded with phantom rounds/devices, churn schedules, mixed
-replan modes — plus the program-cache guarantee (a sweep compiles at
-most one program per shape bucket), the shape-bucketing policy and its
+History equivalence across a shape bucket — mixed (T, n) shapes
+padded with phantom rounds/devices, churn schedules, mixed replan modes
+— plus the program-cache guarantee (a sweep compiles at most one
+program per shape bucket), the shape-bucketing policy and its
 once-per-sweep inflation warning, and the stacked AsyncEvaluator.
 
-Bitwise equality holds at MATCHED staging (the per-point run padded to
-the same bucket P); with each point's exact P the padded reductions
-associate differently, so only the shape-insensitive history pieces
-(agg rounds, H weights, accuracy curves) are asserted exact there.
+At MATCHED staging (the per-point run padded to the same bucket P) the
+aggregation schedule, H weights and accuracies are exact and losses
+agree to LOSS_RTOL (see ``_assert_matches``); with each point's exact P
+the padded reductions associate differently, so per-device losses get a
+looser tolerance there.
 """
 import copy
 import dataclasses
@@ -64,6 +65,34 @@ def _assert_bitwise(h_ref, h_bat):
                                   np.stack(h_bat["device_loss"]))
     np.testing.assert_array_equal(np.stack(h_ref["H_agg"]),
                                   np.stack(h_bat["H_agg"]))
+
+
+# Two programs that train the same parameters still EVALUATE them in
+# differently fused programs: the scan engine's in-scan eval, and the
+# batched engine's stacked vmapped AsyncEvaluator dispatch, whose
+# reduction order also moves with the stack extent S. The test-set mean
+# loss then rounds differently (observed on the CPU: 1 ulp, ~1e-7
+# relative), and an accelerator's fusion can reorder the training
+# reductions too. Losses therefore compare to LOSS_RTOL = 1e-6, about 8
+# f32 ulps; the per-device losses are read off the parameters each
+# round, so they bound the parameter drift as well. Everything the
+# reduction order cannot touch stays exact: the aggregation schedule,
+# accuracies (400 test images, a quantum of 0.0025) and H weights.
+LOSS_RTOL = 1e-6
+
+
+def _assert_matches(h_ref, h_bat, device_loss_rtol=LOSS_RTOL,
+                    device_loss_atol=0.0):
+    assert h_ref["agg_round"] == h_bat["agg_round"]
+    assert h_ref["test_acc"] == h_bat["test_acc"]
+    np.testing.assert_array_equal(np.stack(h_ref["H_agg"]),
+                                  np.stack(h_bat["H_agg"]))
+    np.testing.assert_allclose(h_bat["test_loss"], h_ref["test_loss"],
+                               rtol=LOSS_RTOL, atol=0.0)
+    np.testing.assert_allclose(np.stack(h_bat["device_loss"]),
+                               np.stack(h_ref["device_loss"]),
+                               rtol=device_loss_rtol,
+                               atol=device_loss_atol)
 
 
 # ---------------------------------------------------------------------------
@@ -165,14 +194,15 @@ def test_batched_single_matches_scan_bitwise():
     h_bat = F.run_network_aware(s[0], s[1], None, None, s[2],
                                 streams=copy.deepcopy(s[3]),
                                 engine="batched", mesh=None)
-    _assert_bitwise(h_scan, h_bat)
+    _assert_matches(h_scan, h_bat)
 
 
 def test_batched_mixed_bucket_matches_scan_bitwise():
     """One bucket holding three different scenarios — smaller n
     (phantom devices), shorter T (phantom rounds + offset tau) and a
     churned schedule — each trained per-point at the bucket's padded
-    staging: the batched histories must be bitwise-identical."""
+    staging: the batched histories must match (``_assert_matches``:
+    exact schedule, accuracies and H; losses to LOSS_RTOL)."""
     P_b = 128                           # bucket P for this data density
     specs = [dict(n=4, T=12, tau=4, seed=0, max_points=P_b),
              dict(n=6, T=12, tau=4, seed=1, max_points=P_b),
@@ -183,7 +213,7 @@ def test_batched_mixed_bucket_matches_scan_bitwise():
     outs = _batched(setups, setups[0][1], mesh=None)
     assert not all(a.all() for a in refs[2]["active"])   # churn is live
     for h_ref, h_bat in zip(refs, outs):
-        _assert_bitwise(h_ref, h_bat)
+        _assert_matches(h_ref, h_bat)
 
 
 def test_batched_exact_staging_matches_scan_histories():
@@ -430,22 +460,16 @@ def test_ragged_mixed_bucket_matches_alone_bitwise():
 def test_ragged_matches_scan_histories():
     """Ragged staging reduces each device's samples in stream order
     (chunk-major), so the shape-insensitive history — aggregation
-    schedule, H weights, accuracy/loss curves — matches the per-point
-    scan exactly; per-device losses differ only by padded-reduction
-    association."""
+    schedule, H weights, accuracy curves — matches the per-point scan
+    exactly and test losses to LOSS_RTOL; per-device losses differ only
+    by padded-reduction association."""
     setups = [_setup(n=4, T=12, tau=4, seed=0),
               _setup(n=6, T=12, tau=4, seed=1)]
     refs = [_scan(s) for s in setups]
     outs = _batched_ragged(setups, setups[0][1])
     for h_ref, h_bat in zip(refs, outs):
-        assert h_ref["agg_round"] == h_bat["agg_round"]
-        assert h_ref["test_acc"] == h_bat["test_acc"]
-        assert h_ref["test_loss"] == h_bat["test_loss"]
-        np.testing.assert_array_equal(np.stack(h_ref["H_agg"]),
-                                      np.stack(h_bat["H_agg"]))
-        np.testing.assert_allclose(np.stack(h_bat["device_loss"]),
-                                   np.stack(h_ref["device_loss"]),
-                                   rtol=1e-5, atol=1e-6)
+        _assert_matches(h_ref, h_bat, device_loss_rtol=1e-5,
+                        device_loss_atol=1e-6)
 
 
 def test_ragged_with_faults_matches_alone_bitwise():
@@ -464,7 +488,7 @@ def test_ragged_with_faults_matches_alone_bitwise():
         h_alone = _batched_ragged([s], setups[0][1],
                                   faults=[f] if f is not None else None,
                                   guard=True, quorum=0.3)[0]
-        _assert_bitwise(h_alone, h_grp)
+        _assert_matches(h_alone, h_grp)
         if f is not None:       # clean points carry no fault history
             assert h_alone["agg_survivors"] == h_grp["agg_survivors"]
             assert h_alone["agg_quorum_ok"] == h_grp["agg_quorum_ok"]

@@ -214,7 +214,19 @@ def test_batched_matches_scan_under_faults():
     fs = _mixed_faults()
     hs = _run("scan", faults=fs, guard=True, quorum=0.3)
     hb = _run("batched", faults=fs, guard=True, quorum=0.3)
-    _assert_hist_bitwise(hs, hb)
+    # the batched engine evaluates through the stacked AsyncEvaluator,
+    # not in-scan: its test-loss mean rounds in another order (1 ulp
+    # observed on the CPU), so losses match to 1e-6 (~8 f32 ulps, as
+    # test_engine_batched.LOSS_RTOL) and the rest exactly
+    assert hs["agg_round"] == hb["agg_round"]
+    assert hs["test_acc"] == hb["test_acc"]
+    np.testing.assert_array_equal(np.asarray(hs["H_agg"]),
+                                  np.asarray(hb["H_agg"]))
+    np.testing.assert_allclose(hb["test_loss"], hs["test_loss"],
+                               rtol=1e-6, atol=0.0)
+    np.testing.assert_allclose(np.stack(hb["device_loss"]),
+                               np.stack(hs["device_loss"]),
+                               rtol=1e-6, atol=0.0)
     assert hs["agg_survivors"] == hb["agg_survivors"]
     assert hs["agg_quorum_ok"] == hb["agg_quorum_ok"]
 

@@ -120,3 +120,42 @@ def test_offload_greedy_isolated_nodes_never_offload():
         jnp.asarray(RNG.random(n), jnp.float32),
         jnp.asarray(RNG.random(n), jnp.float32), adj, interpret=True)
     assert not bool(jnp.any(choice == 1))
+
+
+@pytest.mark.parametrize("E,S", [(1, 1), (1000, 300), (2048, 128)])
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_segment_reduce_matches_jax_ops(E, S, op):
+    """Unsorted ids, empty segments and element padding; integer-valued
+    data makes every summation order exact, so both ops match bitwise."""
+    from repro.kernels import segment_reduce as sr
+
+    rng = np.random.default_rng(E + S)
+    ids = jnp.asarray(rng.integers(0, S, E), jnp.int32)
+    vals = jnp.asarray(rng.integers(-8, 9, E), jnp.float32)
+    kernel = sr.segment_sum_pallas if op == "sum" else sr.segment_max_pallas
+    want = (jax.ops.segment_sum if op == "sum" else jax.ops.segment_max)(
+        vals, ids, num_segments=S)
+    got = kernel(vals, ids, S, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("E,S,kernel", [
+    (256, 1024, True),            # at both bounds
+    (255, 64, False),             # too few elements
+    (4096, 1025, False),          # one segment past the bound
+    (512_000, 512_000, False),    # counts_flat at n=10,240, T=50
+])
+def test_segment_dispatch_bounds_the_kernel_grid(monkeypatch, E, S, kernel):
+    """On an accelerator the one-hot kernel is taken only where its
+    E·⌈S/128⌉ work stays within 8 passes over the elements."""
+    from functools import partial
+
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    data = jax.ShapeDtypeStruct((E,), jnp.float32)
+    ids = jax.ShapeDtypeStruct((E,), jnp.int32)
+    for fn in (ops.segment_sum, ops.segment_max):
+        jaxpr = jax.make_jaxpr(partial(fn.__wrapped__, num_segments=S))(
+            data, ids)
+        assert ("pallas_call" in str(jaxpr)) == kernel
