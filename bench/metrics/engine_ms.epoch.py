@@ -1,0 +1,9 @@
+"""Mean host time per job of the benchmark's span around the engine stage,
+in ms."""
+
+
+def read(run):
+    calls = run["calls"]
+    if not calls:
+        return None
+    return 1e3 * sum(c["stages"]["engine"] for c in calls) / len(calls)
