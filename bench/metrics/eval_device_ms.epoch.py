@@ -1,0 +1,12 @@
+"""Mean device time per job of the ops under the scope ``eval``: the test-
+set evaluation at each aggregation, in ms (device trace)."""
+import os
+
+import progtrace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def read(run):
+    return progtrace.scope_ms(run, ROOT, "eval")

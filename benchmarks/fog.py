@@ -431,7 +431,6 @@ def run_scenarios(scenarios: list[Scenario], scale: BenchScale, *,
     import jax
 
     from repro.core import costmodel as cm
-    from repro.core import engine as eng
     from repro.core.engine import resolve_engine
 
     if plans is None:
@@ -477,15 +476,14 @@ def run_scenarios(scenarios: list[Scenario], scale: BenchScale, *,
         for gkey, idxs in groups.items():
             fault_list = [scenarios[b].faults for b in idxs]
             any_faults = any(f is not None for f in fault_list)
-            t_prep0 = time.perf_counter()
+            # the sweep's own prep: each point's host data plane is a
+            # ``prep`` span of _prepare_streams
             prepared = []
             for b in idxs:
                 sc = scenarios[b]
                 prepared.append(F._prepare_streams(
                     sc.cfg, data, plans[b], sc.streams, sc.activity,
                     sc.schedule, sc.faults))
-            eng.add_phase_time("stage_s",
-                               time.perf_counter() - t_prep0)
             tau = scenarios[idxs[0]].cfg.tau
             dims = _group_dims(prepared, tau, bucket)
             dims["idents"] = [_point_ident(scenarios[b]) for b in idxs]

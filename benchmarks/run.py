@@ -49,6 +49,30 @@ def compile_count() -> int:
     return monitoring.compile_events()
 
 
+def phase_timings() -> dict:
+    """Host seconds by phase since the last ``monitoring.reset()``, read
+    from the program's spans (``repro.core.monitoring.totals()``)."""
+    from repro.core import monitoring
+
+    tot = monitoring.totals()
+
+    def s(*names):
+        return sum(tot.get(k, {}).get("seconds", 0.0) for k in names)
+
+    # stage_s:    prep (the host data plane) + train.stage
+    #             (staging, fingerprint, uploads)
+    # program_s:  train.device (compiled-program dispatch through
+    #             block_until_ready)
+    # eval_s:     train.eval (the batched path's stacked off-scan eval)
+    # train_s:    train.device + train.eval + train.readback
+    # tier_agg_s: train.tiers (the hierarchical plane's tier staging)
+    return {"stage_s": s("prep", "train.stage"),
+            "program_s": s("train.device"),
+            "eval_s": s("train.eval"),
+            "train_s": s("train.device", "train.eval", "train.readback"),
+            "tier_agg_s": s("train.tiers")}
+
+
 # hierarchical-run provenance: set by benches that build a TierTree /
 # tier mesh (``set_tier_meta``); flat benches stamp the keys as None so
 # every bench JSON carries the same meta schema
@@ -839,6 +863,7 @@ def hier_scale(scale):
     from repro.core import engine as eng
     from repro.core import federated as F
     from repro.core import hierarchy as hr
+    from repro.core import monitoring
     from repro.core import movement as mv
     from repro.core import topology as topo
     from repro.core.costs import synthetic_edge_costs
@@ -919,13 +944,13 @@ def hier_scale(scale):
     cfg = F.FedConfig(n=n_big, T=T_tr, tau=taus[0], eta=0.1,
                       model="linear", seed=0)
 
-    eng.reset_phase_timings()
+    monitoring.reset()
     t = time.time()
     hist_h = guarded("train_hier", lambda: F.run_network_aware(
         cfg, data, etr, None, plan_h, streams=flat, schedule=sched,
         engine="scan", hierarchy=tree))
     hier_s = time.time() - t
-    phases = eng.phase_timings()
+    phases = phase_timings()
 
     # flat baseline at the same τ_0: full-support movement, all
     # uploads converge on one server every window
@@ -1421,6 +1446,7 @@ def scenario_batched(scale):
     prices."""
     from repro.core import costmodel as cm
     from repro.core import engine as eng
+    from repro.core import monitoring
 
     from benchmarks.fog import (make_scenario, run_scenarios,
                                 scenario_bucket_key,
@@ -1483,14 +1509,14 @@ def scenario_batched(scale):
             for _ in range(repeats))
         disp_warm_s, phases, disp_warm = None, None, disp
         for _ in range(repeats):
-            eng.reset_phase_timings()
+            monitoring.reset()
             t = time.time()
             out = run_scenarios(scenarios, scale, plans=plans,
                                 engine="auto")
             dt = time.time() - t
             if disp_warm_s is None or dt < disp_warm_s:
                 disp_warm_s, phases, disp_warm = (
-                    dt, eng.phase_timings(), out)
+                    dt, phase_timings(), out)
         dispatch_warm = _uniq_dispatches(disp_warm)
 
         acc_bitwise = all(

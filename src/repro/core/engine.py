@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import sanitize
+from repro.core import monitoring, sanitize
 from repro.data import pipeline as pl
 from repro.models import mnist as mm
 from repro.models.module import init_params
@@ -88,9 +88,18 @@ def _to_device_cached(arr: np.ndarray):
 
 
 def make_model(name: str, rng):
-    specs_fn, apply_fn = mm.MODELS[name]
-    params = init_params(specs_fn(), rng, jnp.float32)
+    with monitoring.span("train.init"):
+        specs_fn, apply_fn = mm.MODELS[name]
+        params = init_params(specs_fn(), rng, jnp.float32)
     return params, apply_fn
+
+
+@jax.jit
+def _gather_rows(x, idx):
+    """The prestaged pixel gather ``x[idx]``, one program under the
+    ``gather`` scope like the in-scan gather."""
+    with jax.named_scope("gather"):
+        return jnp.take(x, idx, axis=0)
 
 
 def resolve_engine(engine: str) -> str:
@@ -309,35 +318,39 @@ def _make_scan_body(apply_fn, vstep, prestage: bool, faults: bool,
         else:
             xb, idx, yb, w, cnt, a, agg = xs
         if not prestage:
-            xb = jnp.take(x_tr, idx, axis=0)
+            with jax.named_scope("gather"):
+                xb = jnp.take(x_tr, idx, axis=0)
         active = a * (1.0 - waiting)
-        W, losses = vstep(W, xb, yb, w, active)
+        with jax.named_scope("local_sgd"):
+            W, losses = vstep(W, xb, yb, w, active)
         H = H + cnt * active
 
         def do_agg(ops):
             W, wg, H, waiting = ops
-            if faults:
-                Wu, contrib = _guarded_uploads(W, active, upl, cor,
-                                               guard, 1)
-                surv = contrib.sum()
-                qok = surv >= quorum * active.sum()
-                wg2 = aggregate(Wu, H, contrib, wg)
-                # quorum failed: the whole aggregation event is skipped
-                # — previous global carries forward, no sync, H keeps
-                # accumulating into the next window
-                wg2 = tree_map(lambda nw, old: jnp.where(qok, nw, old),
-                               wg2, wg)
-                W2 = _sync(W, wg2, (a > 0.5) & qok)
-                H2 = jnp.where(qok, jnp.zeros_like(H), H)
-                waiting2 = jnp.where(qok, 1.0 - a, waiting)
-            else:
-                wg2 = aggregate(W, H, active, wg)
-                W2 = _sync(W, wg2, a > 0.5)
-                H2 = jnp.zeros_like(H)
-                waiting2 = 1.0 - a
-            logits = apply_fn(wg2, x_te)
-            tl = mm.ce_loss(logits, y_te)
-            ta = mm.accuracy(logits, y_te)
+            with jax.named_scope("aggregate"):
+                if faults:
+                    Wu, contrib = _guarded_uploads(W, active, upl, cor,
+                                                   guard, 1)
+                    surv = contrib.sum()
+                    qok = surv >= quorum * active.sum()
+                    wg2 = aggregate(Wu, H, contrib, wg)
+                    # quorum failed: the whole aggregation event is
+                    # skipped — previous global carries forward, no sync,
+                    # H keeps accumulating into the next window
+                    wg2 = tree_map(lambda nw, old: jnp.where(qok, nw, old),
+                                   wg2, wg)
+                    W2 = _sync(W, wg2, (a > 0.5) & qok)
+                    H2 = jnp.where(qok, jnp.zeros_like(H), H)
+                    waiting2 = jnp.where(qok, 1.0 - a, waiting)
+                else:
+                    wg2 = aggregate(W, H, active, wg)
+                    W2 = _sync(W, wg2, a > 0.5)
+                    H2 = jnp.zeros_like(H)
+                    waiting2 = 1.0 - a
+            with jax.named_scope("eval"):
+                logits = apply_fn(wg2, x_te)
+                tl = mm.ce_loss(logits, y_te)
+                ta = mm.accuracy(logits, y_te)
             out = (W2, wg2, H2, waiting2, tl, ta, H)
             if faults:
                 out += (surv, qok.astype(jnp.float32))
@@ -358,66 +371,68 @@ def _make_scan_body(apply_fn, vstep, prestage: bool, faults: bool,
 
             def hier_do_agg(ops):
                 W, wg, H, waiting = ops
-                if faults:
-                    Wu, contrib = _guarded_uploads(W, active, upl, cor,
-                                                   guard, 1)
-                    surv = contrib.sum()
-                    qok = surv >= quorum * active.sum()
-                else:
-                    Wu, contrib = W, active
-                    qok = None
-                # compose eq. (4) up the tree: tier l aggregates tier
-                # l-1's stack under CUMULATIVE H weights, so feeding
-                # each tier's (models, H_g) into the next telescopes to
-                # the flat eq. (4) over all contributing devices — the
-                # top row IS the global model
-                Wl, Hl = Wu, H * contrib
-                tiers = []
-                for gids, ng in zip(hier.group_ids, hier.num_groups):
-                    Wl, Hl = aggregate_tier(Wl, Hl, gids, ng)
-                    tiers.append((Wl, Hl))
-                Wtop, Htop = tiers[-1]
-                ok_top = is_top & (Htop[0] > 0)
-                if qok is not None:
-                    ok_top = ok_top & qok
-                wg2 = tree_map(
-                    lambda nw, old: jnp.where(ok_top, nw[0], old),
-                    Wtop, wg)
+                with jax.named_scope("aggregate"):
+                    if faults:
+                        Wu, contrib = _guarded_uploads(W, active, upl, cor,
+                                                       guard, 1)
+                        surv = contrib.sum()
+                        qok = surv >= quorum * active.sum()
+                    else:
+                        Wu, contrib = W, active
+                        qok = None
+                    # compose eq. (4) up the tree: tier l aggregates tier
+                    # l-1's stack under CUMULATIVE H weights, so feeding
+                    # each tier's (models, H_g) into the next telescopes to
+                    # the flat eq. (4) over all contributing devices — the
+                    # top row IS the global model
+                    Wl, Hl = Wu, H * contrib
+                    tiers = []
+                    for gids, ng in zip(hier.group_ids, hier.num_groups):
+                        Wl, Hl = aggregate_tier(Wl, Hl, gids, ng)
+                        tiers.append((Wl, Hl))
+                    Wtop, Htop = tiers[-1]
+                    ok_top = is_top & (Htop[0] > 0)
+                    if qok is not None:
+                        ok_top = ok_top & qok
+                    wg2 = tree_map(
+                        lambda nw, old: jnp.where(ok_top, nw[0], old),
+                        Wtop, wg)
 
-                # every device syncs from its ancestor group at the
-                # round's highest aggregating tier; empty groups
-                # (H_g == 0) leave their members' params untouched
-                def pick(lv):
-                    Wg, Hg = tiers[lv]
-                    return (tree_map(lambda g: g[anc[lv]], Wg),
-                            Hg[anc[lv]])
+                    # every device syncs from its ancestor group at the
+                    # round's highest aggregating tier; empty groups
+                    # (H_g == 0) leave their members' params untouched
+                    def pick(lv):
+                        Wg, Hg = tiers[lv]
+                        return (tree_map(lambda g: g[anc[lv]], Wg),
+                                Hg[anc[lv]])
 
-                target, Hsel = jax.lax.switch(
-                    jnp.maximum(lvl - 1, 0),
-                    [lambda lv=lv: pick(lv) for lv in range(L)])
-                sync_ok = (a > 0.5) & (Hsel > 0)
-                if qok is not None:
-                    sync_ok = sync_ok & qok
-                W2 = tree_map(
-                    lambda p, tg: jnp.where(
-                        sync_ok.reshape(sync_ok.shape
-                                        + (1,) * (p.ndim - 1)), tg, p),
-                    W, target)
-                # H accumulates across sub-tier windows and resets only
-                # once the TOP tier has consumed it (that is what makes
-                # the tier composition telescope); quorum failure skips
-                # the whole event, flat-plane style
-                if faults:
-                    H2 = jnp.where(is_top & qok, jnp.zeros_like(H), H)
-                    waiting2 = jnp.where(qok, 1.0 - a, waiting)
-                else:
-                    H2 = jnp.where(is_top, jnp.zeros_like(H), H)
-                    waiting2 = 1.0 - a
+                    target, Hsel = jax.lax.switch(
+                        jnp.maximum(lvl - 1, 0),
+                        [lambda lv=lv: pick(lv) for lv in range(L)])
+                    sync_ok = (a > 0.5) & (Hsel > 0)
+                    if qok is not None:
+                        sync_ok = sync_ok & qok
+                    W2 = tree_map(
+                        lambda p, tg: jnp.where(
+                            sync_ok.reshape(sync_ok.shape
+                                            + (1,) * (p.ndim - 1)), tg, p),
+                        W, target)
+                    # H accumulates across sub-tier windows and resets only
+                    # once the TOP tier has consumed it (that is what makes
+                    # the tier composition telescope); quorum failure skips
+                    # the whole event, flat-plane style
+                    if faults:
+                        H2 = jnp.where(is_top & qok, jnp.zeros_like(H), H)
+                        waiting2 = jnp.where(qok, 1.0 - a, waiting)
+                    else:
+                        H2 = jnp.where(is_top, jnp.zeros_like(H), H)
+                        waiting2 = 1.0 - a
 
                 def ev(_):
-                    logits = apply_fn(wg2, x_te)
-                    return mm.ce_loss(logits, y_te), mm.accuracy(logits,
-                                                                 y_te)
+                    with jax.named_scope("eval"):
+                        logits = apply_fn(wg2, x_te)
+                        return (mm.ce_loss(logits, y_te),
+                                mm.accuracy(logits, y_te))
 
                 tl, ta = jax.lax.cond(
                     is_top, ev,
@@ -447,8 +462,12 @@ def _scan_program(apply_fn, eta: float, prestage: bool,
 
     vstep = jax.vmap(_device_step_fn(apply_fn, eta))
 
-    def train(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, counts,
-              act, is_agg, x_te, y_te, *fault_ops):
+    # the name is the module's in traces (``jit_fog_scan``) and in the
+    # persistent compile cache's key, which ignores op metadata: a
+    # program whose scopes change must not keep the name of one
+    # compiled without them
+    def fog_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, counts,
+                 act, is_agg, x_te, y_te, *fault_ops):
         n = counts.shape[1]
         body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te)
@@ -458,7 +477,7 @@ def _scan_program(apply_fn, eta: float, prestage: bool,
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
 
-    return jax.jit(train)
+    return jax.jit(fog_scan)
 
 
 @functools.lru_cache(maxsize=16)
@@ -473,15 +492,15 @@ def _scan_chunk_program(apply_fn, eta: float, prestage: bool,
 
     vstep = jax.vmap(_device_step_fn(apply_fn, eta))
 
-    def train(carry, x_tr, xb_all, idx_all, yb_all, w_all, counts,
-              act, is_agg, x_te, y_te, *fault_ops):
+    def fog_scan_chunk(carry, x_tr, xb_all, idx_all, yb_all, w_all,
+                       counts, act, is_agg, x_te, y_te, *fault_ops):
         body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te)
         xs = (xb_all, idx_all, yb_all, w_all, counts, act, is_agg)
         xs = xs + tuple(fault_ops)
         return jax.lax.scan(body, carry, xs)
 
-    return jax.jit(train)
+    return jax.jit(fog_scan_chunk)
 
 
 def _stage_fault_ops(faults, T: int, n: int, tau: int):
@@ -495,6 +514,43 @@ def _stage_fault_ops(faults, T: int, n: int, tau: int):
                          f"run aggregates every tau={tau}")
     upl, cor = faults.engine_arrays()
     return jnp.asarray(upl), jnp.asarray(cor)
+
+
+def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
+                faults, is_agg, *tail):
+    """Stage one horizon for the scan programs, as the ``train.stage``
+    span: the padded (T, n, P) slots, the activity (crash outages ANDed
+    in), the fault views, and the pixels gathered up front when the
+    (T, n, P, ...) tensor fits ``PRESTAGE_LIMIT_BYTES``. Its counters:
+    ``slots`` T·n·P, ``samples`` the unpadded ones, ``h2d_bytes`` the
+    host arrays uploaded (the datasets stay pinned across calls). Host
+    arrays in ``tail`` ride after the dataset operands. Returns
+    (prestage, args, fault_ops)."""
+    with monitoring.span("train.stage") as sp:
+        idx, yb, wts, counts = pl.stage_rounds(processed, y_tr, max_pts)
+        T, n, P = idx.shape
+        act = np.asarray(act_all)
+        fault_ops = ()
+        if faults is not None:
+            act = np.asarray(act_all, bool) & faults.activity_mask()
+            fault_ops = _stage_fault_ops(faults, T, n, tau)
+        host = (yb, wts, counts, act.astype(np.float32), is_agg)
+        x_dev = _to_device_cached(x_tr)
+        idx_dev = jnp.asarray(idx)
+        item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
+        prestage = T * n * P * item_bytes <= PRESTAGE_LIMIT_BYTES
+        if prestage:
+            xb_all, idx_arg = _gather_rows(x_dev, idx_dev), None
+        else:
+            xb_all, idx_arg = None, idx_dev
+        args = ((x_dev, xb_all, idx_arg)
+                + tuple(jnp.asarray(a) for a in host)
+                + (_to_device_cached(x_te), _to_device_cached(y_te))
+                + tuple(jnp.asarray(a) for a in tail))
+        sp.count(slots=idx.size, samples=int(counts.sum()),
+                 h2d_bytes=sum(a.nbytes for a in (idx,) + host + tail
+                               + tuple(fault_ops)))
+    return prestage, args, fault_ops
 
 
 def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
@@ -526,31 +582,13 @@ def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
         T, n = processed.T, processed.n
     else:
         T, n = len(processed), len(processed[0])
-    idx, yb, wts, counts = pl.stage_rounds(processed, y_tr, max_pts)
     is_agg = (np.arange(T) + 1) % tau == 0
-
     use_faults = faults is not None
-    act_arr = np.asarray(act_all)
-    fault_ops = ()
-    if use_faults:
-        act_arr = np.asarray(act_all, bool) & faults.activity_mask()
-        fault_ops = _stage_fault_ops(faults, T, n, tau)
     guard_f = bool(guard) if use_faults else False
     quorum_f = float(quorum) if use_faults else 0.0
-
-    x_dev = _to_device_cached(x_tr)
-    idx_dev = jnp.asarray(idx)
-    item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
-    prestage = T * n * max_pts * item_bytes <= PRESTAGE_LIMIT_BYTES
-    if prestage:
-        xb_all, idx_arg = jnp.take(x_dev, idx_dev, axis=0), None
-    else:
-        xb_all, idx_arg = None, idx_dev
-
-    args = (x_dev, xb_all, idx_arg, jnp.asarray(yb), jnp.asarray(wts),
-            jnp.asarray(counts), jnp.asarray(act_arr, jnp.float32),
-            jnp.asarray(is_agg), _to_device_cached(x_te),
-            _to_device_cached(y_te))
+    prestage, args, fault_ops = _stage_scan(
+        processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
+        is_agg)
 
     if checkpoint_path is not None or resume is not None:
         return _run_scan_checkpointed(
@@ -563,21 +601,22 @@ def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
     # sanitize hook: under run_network_aware(sanitize=True) the guard
     # disallows implicit transfers across the whole-horizon dispatch
     # (staging above and history readback below are explicit, by design)
-    with sanitize.hot_loop_guard():
+    with monitoring.span("train.device"), sanitize.hot_loop_guard():
         res = fn(_stack(params, n), params, *args, *fault_ops)
         losses, tl, ta, H_at = res[1:5]
         jax.block_until_ready(losses)
-    agg_rounds = np.nonzero(is_agg)[0]
-    tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
-    out = {"device_loss": list(np.asarray(losses)),
-           "test_loss": [float(v) for v in tl[agg_rounds]],
-           "test_acc": [float(v) for v in ta[agg_rounds]],
-           "agg_round": [int(t) for t in agg_rounds],
-           "H_agg": list(H_at[agg_rounds])}
-    if use_faults:
-        surv, qokf = np.asarray(res[5]), np.asarray(res[6])
-        out["agg_survivors"] = [float(v) for v in surv[agg_rounds]]
-        out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[agg_rounds]]
+    with monitoring.span("train.readback"):
+        agg_rounds = np.nonzero(is_agg)[0]
+        tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
+        out = {"device_loss": list(np.asarray(losses)),
+               "test_loss": [float(v) for v in tl[agg_rounds]],
+               "test_acc": [float(v) for v in ta[agg_rounds]],
+               "agg_round": [int(t) for t in agg_rounds],
+               "H_agg": list(H_at[agg_rounds])}
+        if use_faults:
+            surv, qokf = np.asarray(res[5]), np.asarray(res[6])
+            out["agg_survivors"] = [float(v) for v in surv[agg_rounds]]
+            out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[agg_rounds]]
     return out
 
 
@@ -607,8 +646,8 @@ def _hier_program(apply_fn, eta: float, prestage: bool,
     spec = _HIER_SPECS[tree_fp]
     vstep = jax.vmap(_device_step_fn(apply_fn, eta))
 
-    def train(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, counts,
-              act, is_agg, x_te, y_te, lvl, *fault_ops):
+    def fog_hier_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all,
+                      counts, act, is_agg, x_te, y_te, lvl, *fault_ops):
         n = counts.shape[1]
         body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te, hier=spec)
@@ -619,7 +658,7 @@ def _hier_program(apply_fn, eta: float, prestage: bool,
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
 
-    return jax.jit(train)
+    return jax.jit(fog_hier_scan)
 
 
 def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
@@ -660,60 +699,43 @@ def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
     if n != tree.n:
         raise ValueError(f"run has n={n} devices but the tree has "
                          f"n={tree.n}")
-    idx, yb, wts, counts = pl.stage_rounds(processed, y_tr, max_pts)
 
-    t_tier0 = time.perf_counter()
-    lvl = tree.level_rounds(T)
-    is_agg = lvl > 0
-    fp = tree.fingerprint()
-    if fp not in _HIER_SPECS:
-        _HIER_SPECS[fp] = _HierSpec(group_ids=tree.parents,
-                                    num_groups=tree.group_counts,
-                                    anc=tree.ancestors())
-    add_phase_time("tier_agg_s", time.perf_counter() - t_tier0)
+    with monitoring.span("train.tiers"):
+        lvl = tree.level_rounds(T)
+        is_agg = lvl > 0
+        fp = tree.fingerprint()
+        if fp not in _HIER_SPECS:
+            _HIER_SPECS[fp] = _HierSpec(group_ids=tree.parents,
+                                        num_groups=tree.group_counts,
+                                        anc=tree.ancestors())
 
     use_faults = faults is not None
-    act_arr = np.asarray(act_all)
-    fault_ops = ()
-    if use_faults:
-        act_arr = np.asarray(act_all, bool) & faults.activity_mask()
-        fault_ops = _stage_fault_ops(faults, T, n, tau)
     guard_f = bool(guard) if use_faults else False
     quorum_f = float(quorum) if use_faults else 0.0
-
-    x_dev = _to_device_cached(x_tr)
-    idx_dev = jnp.asarray(idx)
-    item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
-    prestage = T * n * max_pts * item_bytes <= PRESTAGE_LIMIT_BYTES
-    if prestage:
-        xb_all, idx_arg = jnp.take(x_dev, idx_dev, axis=0), None
-    else:
-        xb_all, idx_arg = None, idx_dev
-
-    args = (x_dev, xb_all, idx_arg, jnp.asarray(yb), jnp.asarray(wts),
-            jnp.asarray(counts), jnp.asarray(act_arr, jnp.float32),
-            jnp.asarray(is_agg), _to_device_cached(x_te),
-            _to_device_cached(y_te), jnp.asarray(lvl))
+    prestage, args, fault_ops = _stage_scan(
+        processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
+        is_agg, lvl)
 
     fn = _hier_program(apply_fn, float(eta), prestage, use_faults,
                        guard_f, quorum_f, fp)
-    with sanitize.hot_loop_guard():
+    with monitoring.span("train.device"), sanitize.hot_loop_guard():
         res = fn(_stack(params, n), params, *args, *fault_ops)
         losses, tl, ta, H_at = res[1:5]
         jax.block_until_ready(losses)
-    top = np.nonzero(lvl == tree.levels)[0]
-    tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
-    out = {"device_loss": list(np.asarray(losses)),
-           "test_loss": [float(v) for v in tl[top]],
-           "test_acc": [float(v) for v in ta[top]],
-           "agg_round": [int(t) for t in top],
-           "H_agg": list(H_at[top]),
-           "tier_agg_round": [int(t) for t in np.nonzero(is_agg)[0]],
-           "tier_agg_level": [int(v) for v in lvl[is_agg]]}
-    if use_faults:
-        surv, qokf = np.asarray(res[5]), np.asarray(res[6])
-        out["agg_survivors"] = [float(v) for v in surv[top]]
-        out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[top]]
+    with monitoring.span("train.readback"):
+        top = np.nonzero(lvl == tree.levels)[0]
+        tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
+        out = {"device_loss": list(np.asarray(losses)),
+               "test_loss": [float(v) for v in tl[top]],
+               "test_acc": [float(v) for v in ta[top]],
+               "agg_round": [int(t) for t in top],
+               "H_agg": list(H_at[top]),
+               "tier_agg_round": [int(t) for t in np.nonzero(is_agg)[0]],
+               "tier_agg_level": [int(v) for v in lvl[is_agg]]}
+        if use_faults:
+            surv, qokf = np.asarray(res[5]), np.asarray(res[6])
+            out["agg_survivors"] = [float(v) for v in surv[top]]
+            out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[top]]
     return out
 
 
@@ -772,15 +794,17 @@ def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
             break
         t1 = min(t0 + step, T)
         sl = slice(t0, t1)
-        with sanitize.hot_loop_guard():
+        with monitoring.span("train.device"), sanitize.hot_loop_guard():
             carry, ys = fn(
                 carry, x_dev,
                 None if xb_all is None else xb_all[sl],
                 None if idx_arg is None else idx_arg[sl],
                 yb[sl], wts[sl], counts[sl], act[sl], is_agg[sl], x_te,
                 y_te, *(op[sl] for op in fault_ops))
-        for k, y in zip(keys, ys):
-            hist[k][sl] = np.asarray(y)
+            jax.block_until_ready(ys)
+        with monitoring.span("train.readback"):
+            for k, y in zip(keys, ys):
+                hist[k][sl] = np.asarray(y)
         t0 = t1
         if checkpoint_path is not None:
             ckpt.save(checkpoint_path, _as_state(carry, hist, t0),
@@ -1368,32 +1392,6 @@ def _staged_fingerprint(processed_list, act_list, tau, bucket, staging,
     return h.digest()
 
 
-# per-phase wall-clock accumulators for the batched path, surfaced in
-# bench breakdowns: "stage" covers host staging + fingerprint + upload
-# dispatch, "train" the program dispatch + eval drain + history
-# assembly ("program"/"eval" are the two big slices inside "train"),
-# "tier_agg" the hierarchical plane's host-side slice (tier staging +
-# traffic accounting) so bench breakdowns separate intra-tier compute
-# from up-tree work. Reset/read around a timed region via accessors.
-_PHASE = {"stage_s": 0.0, "program_s": 0.0, "eval_s": 0.0,
-          "train_s": 0.0, "tier_agg_s": 0.0}
-
-
-def phase_timings() -> dict:
-    return dict(_PHASE)
-
-
-def reset_phase_timings() -> None:
-    _PHASE.update(stage_s=0.0, program_s=0.0, eval_s=0.0, train_s=0.0,
-                  tier_agg_s=0.0)
-
-
-def add_phase_time(phase: str, seconds: float) -> None:
-    """Fold externally-timed work (e.g. the sweep driver's host data
-    prep) into a phase accumulator."""
-    _PHASE[phase] = _PHASE.get(phase, 0.0) + float(seconds)
-
-
 def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
                            staging, max_points, mesh, faults, x_dev,
                            x_tr):
@@ -1474,7 +1472,7 @@ def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
 
     idx_dev = jnp.asarray(idx)
     if prestage:
-        xb_all, idx_arg = jnp.take(x_dev, idx_dev, axis=0), None
+        xb_all, idx_arg = _gather_rows(x_dev, idx_dev), None
     else:
         xb_all, idx_arg = None, idx_dev
 
@@ -1529,7 +1527,6 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
     the shared ``guard``/``quorum`` config applied across the bucket
     (see ``run_rounds_scan`` for the semantics).
     """
-    t_stage0 = time.perf_counter()
     if staging not in ("dense", "ragged"):
         raise ValueError(f"staging must be 'dense' or 'ragged'; "
                          f"got {staging!r}")
@@ -1565,75 +1562,73 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
                          "pass mesh=None (or staging='dense')")
 
     mesh_shape = None if mesh is None else tuple(mesh.devices.shape)
-    x_dev = _to_device_cached(x_tr)
-    cache_key = _staged_fingerprint(
-        processed_list, act_list, tau, bucket, staging, max_points,
-        mesh_shape, faults if use_faults else None, x_tr, y_tr)
-    hit = _STAGED_CACHE.get(cache_key)
-    if hit is not None:
-        _STAGED_CACHE.move_to_end(cache_key)
-        _STAGED_CACHE_STATS["hits"] += 1
-        staged_args, meta, _ = hit
-    else:
-        _STAGED_CACHE_STATS["misses"] += 1
-        staged_args, meta = _stage_bucket_operands(
-            processed_list, act_list, y_tr, tau, bucket, staging,
-            max_points, mesh, faults if use_faults else None, x_dev,
-            x_tr)
-        _staged_cache_put(cache_key, staged_args, meta)
-    n_pad = meta["n_pad"]
-    T_b, n_win = meta["T_b"], meta["n_win"]
+    with monitoring.span("train.stage"):
+        x_dev = _to_device_cached(x_tr)
+        cache_key = _staged_fingerprint(
+            processed_list, act_list, tau, bucket, staging, max_points,
+            mesh_shape, faults if use_faults else None, x_tr, y_tr)
+        hit = _STAGED_CACHE.get(cache_key)
+        if hit is not None:
+            _STAGED_CACHE.move_to_end(cache_key)
+            _STAGED_CACHE_STATS["hits"] += 1
+            staged_args, meta, _ = hit
+        else:
+            _STAGED_CACHE_STATS["misses"] += 1
+            staged_args, meta = _stage_bucket_operands(
+                processed_list, act_list, y_tr, tau, bucket, staging,
+                max_points, mesh, faults if use_faults else None, x_dev,
+                x_tr)
+            _staged_cache_put(cache_key, staged_args, meta)
+        n_pad = meta["n_pad"]
+        T_b, n_win = meta["T_b"], meta["n_win"]
 
-    # parameter stacks staged host-side: one device put per leaf
-    # instead of per-(bucket shape) broadcast/stack mini-programs.
-    tree_map = jax.tree_util.tree_map
-    W0 = tree_map(
-        lambda *ps: jnp.asarray(np.stack([np.broadcast_to(
-            np.asarray(p), (n_pad, *p.shape)) for p in ps])),
-        *params_list)
-    wg0 = tree_map(
-        lambda *ps: jnp.asarray(np.stack([np.asarray(p) for p in ps])),
-        *params_list)
+        # parameter stacks staged host-side: one device put per leaf
+        # instead of per-(bucket shape) broadcast/stack mini-programs.
+        tree_map = jax.tree_util.tree_map
+        W0 = tree_map(
+            lambda *ps: jnp.asarray(np.stack([np.broadcast_to(
+                np.asarray(p), (n_pad, *p.shape)) for p in ps])),
+            *params_list)
+        wg0 = tree_map(
+            lambda *ps: jnp.asarray(np.stack([np.asarray(p) for p in ps])),
+            *params_list)
 
-    t_train0 = time.perf_counter()
-    _PHASE["stage_s"] += t_train0 - t_stage0
     fn = _bucket_program(apply_fn, float(eta), meta["prestage"], mesh,
                          use_faults, guard_f, quorum_f, staging)
-    with sanitize.hot_loop_guard():
+    with monitoring.span("train.device"), sanitize.hot_loop_guard():
         res = fn(W0, wg0, x_dev, *staged_args)
         jax.block_until_ready(res)
-    t_eval0 = time.perf_counter()
-    _PHASE["program_s"] += t_eval0 - t_train0
     losses, H_w, wg_win = res[:3]
-    if use_faults:
-        surv_win, expd_win, qok_win = (np.asarray(r) for r in res[3:])
 
     # one stacked eval dispatch drains the whole bucket's (windows, S)
     # snapshot grid off the hot path; per-scenario agg windows are
     # selected host-side (phantom windows' results are simply unused)
-    ev = AsyncEvaluator(apply_fn, x_te, y_te)
-    ev.submit_stack(wg_win, n_axes=2)
-    (tl,), (ta,) = ev.collect()
-    _PHASE["eval_s"] += time.perf_counter() - t_eval0
+    with monitoring.span("train.eval"):
+        ev = AsyncEvaluator(apply_fn, x_te, y_te)
+        ev.submit_stack(wg_win, n_axes=2)
+        (tl,), (ta,) = ev.collect()
 
-    losses = np.asarray(losses).reshape(T_b, S, n_pad)
-    H_w = np.asarray(H_w)
-    hists = []
-    for b in range(S):
-        T, n = meta["T"][b], meta["n"][b]
-        agg_rounds = np.nonzero(meta["is_agg"][b, :T])[0]
-        wins = agg_rounds // tau
-        h = {
-            "device_loss": list(losses[:T, b, :n]),
-            "test_loss": [float(v) for v in tl[wins, b]],
-            "test_acc": [float(v) for v in ta[wins, b]],
-            "agg_round": [int(t) for t in agg_rounds],
-            "H_agg": list(H_w[wins, b][:, :n])}
+    with monitoring.span("train.readback"):
         if use_faults:
-            h["agg_survivors"] = [float(v) for v in surv_win[wins, b]]
-            h["agg_quorum_ok"] = [bool(v > 0) for v in qok_win[wins, b]]
-        hists.append(h)
-    _PHASE["train_s"] += time.perf_counter() - t_train0
+            surv_win, expd_win, qok_win = (np.asarray(r) for r in res[3:])
+        losses = np.asarray(losses).reshape(T_b, S, n_pad)
+        H_w = np.asarray(H_w)
+        hists = []
+        for b in range(S):
+            T, n = meta["T"][b], meta["n"][b]
+            agg_rounds = np.nonzero(meta["is_agg"][b, :T])[0]
+            wins = agg_rounds // tau
+            h = {
+                "device_loss": list(losses[:T, b, :n]),
+                "test_loss": [float(v) for v in tl[wins, b]],
+                "test_acc": [float(v) for v in ta[wins, b]],
+                "agg_round": [int(t) for t in agg_rounds],
+                "H_agg": list(H_w[wins, b][:, :n])}
+            if use_faults:
+                h["agg_survivors"] = [float(v) for v in surv_win[wins, b]]
+                h["agg_quorum_ok"] = [bool(v > 0)
+                                      for v in qok_win[wins, b]]
+            hists.append(h)
     return hists
 
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import engine as eng
+from repro.core import monitoring
 from repro.core import movement as mv
 from repro.core import sanitize as sz
 from repro.core.costs import CostTraces
@@ -137,11 +138,7 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
         streams, processed, act_all, max_pts = _prepare_streams(
             cfg, data, plan, streams, activity, schedule, faults)
 
-    key = jax.random.PRNGKey(cfg.seed)
-    w_global, apply_fn = make_model(cfg.model, key)
-
-    hist = _history_base(cfg, y_tr, streams, processed, act_all)
-
+    extra = {}
     if hierarchy is not None:
         if engine not in ("auto", "scan", "hierarchical"):
             raise ValueError("hierarchy= runs on the scan substrate; "
@@ -154,9 +151,9 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
                              f"every {hierarchy.taus[0]} rounds but "
                              f"cfg.tau={cfg.tau}")
         engine = "hierarchical"
-        hist["hierarchy"] = {"levels": hierarchy.levels,
-                             "group_counts": list(hierarchy.group_counts),
-                             "taus": list(hierarchy.taus)}
+        extra["hierarchy"] = {"levels": hierarchy.levels,
+                              "group_counts": list(hierarchy.group_counts),
+                              "taus": list(hierarchy.taus)}
     else:
         if engine == "hierarchical":
             raise ValueError("engine='hierarchical' needs a hierarchy= "
@@ -169,7 +166,7 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
     fault_kw = {}
     if faults is not None:
         fault_kw = dict(faults=faults, guard=guard, quorum=quorum)
-        hist["fault_summary"] = faults.summary()
+        extra["fault_summary"] = faults.summary()
     ckpt_kw = {}
     if (checkpoint_path is not None or resume is not None
             or stop_after is not None):
@@ -196,10 +193,15 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
         raise ValueError(f"unknown engine {engine!r}; "
                          f"expected one of {sorted(runners)} or 'auto'")
     runner = runners[engine]
-    with sz.sanitized(sanitize):
-        hist.update(runner(apply_fn, w_global, x_tr, y_tr, x_te, y_te,
-                           processed, act_all, cfg.tau, cfg.eta,
-                           max_pts, **fault_kw, **ckpt_kw))
+    with monitoring.span("train"):
+        w_global, apply_fn = make_model(cfg.model,
+                                        jax.random.PRNGKey(cfg.seed))
+        hist = _history_base(cfg, y_tr, streams, processed, act_all)
+        hist.update(extra)
+        with sz.sanitized(sanitize):
+            hist.update(runner(apply_fn, w_global, x_tr, y_tr, x_te, y_te,
+                               processed, act_all, cfg.tau, cfg.eta,
+                               max_pts, **fault_kw, **ckpt_kw))
     return hist
 
 
@@ -214,48 +216,49 @@ def _prepare_streams(cfg: FedConfig, data, plan, streams, activity,
     and round staging all run as vectorized array ops over the flat
     sample table (O(samples)), so nothing O(n²) — and no (n, n) array
     at all — is built on the way into the compiled engine."""
-    _, y_tr, _, _ = data
-    rng = np.random.default_rng(cfg.seed)
-    if streams is None:
-        streams = pl.poisson_streams(cfg.n, cfg.T, y_tr, iid=cfg.iid,
-                                     rng=rng)
-    if schedule is not None:
-        if (schedule.T, schedule.n) != (cfg.T, cfg.n):
-            raise ValueError(
-                f"schedule is (T={schedule.T}, n={schedule.n}) but the "
-                f"run is (T={cfg.T}, n={cfg.n})")
-        if activity is None:
-            activity = schedule.activity()
-    if faults is not None and faults.has_crashes:
-        # a crashed device stops collecting/training like a churned one
-        # — except nobody announced it (no replanning saw it coming)
-        if (faults.T, faults.n) != (cfg.T, cfg.n):
-            raise ValueError(
-                f"fault schedule is (T={faults.T}, n={faults.n}) but "
-                f"the run is (T={cfg.T}, n={cfg.n})")
-        base = (np.asarray(activity, bool) if activity is not None
-                else np.ones((cfg.T, cfg.n), bool))
-        activity = base & faults.activity_mask()
-    if isinstance(streams, pl.FlatStreams):
-        if activity is not None:
-            act = np.asarray(activity, bool)
-            keep = act[streams.t, streams.dev]
-            streams = pl.FlatStreams(t=streams.t[keep],
-                                     dev=streams.dev[keep],
-                                     idx=streams.idx[keep],
-                                     n=streams.n, T=streams.T)
-        processed = pl.apply_movement_flat(streams, plan, rng)
-    else:
-        if activity is not None:
-            # inactive devices collect nothing (no-op for all-active
-            # masks, e.g. a constant schedule)
-            for t, i in zip(*np.nonzero(~np.asarray(activity, bool))):
-                streams.collected[t][i] = np.empty(0, np.int64)
-        processed = pl.apply_movement(streams, plan, rng)
-    max_pts = pl.pad_size(processed, cfg.max_points)
-    act_all = (np.asarray(activity, bool) if activity is not None
-               else np.ones((cfg.T, cfg.n), bool))
-    return streams, processed, act_all, max_pts
+    with monitoring.span("prep"):
+        _, y_tr, _, _ = data
+        rng = np.random.default_rng(cfg.seed)
+        if streams is None:
+            streams = pl.poisson_streams(cfg.n, cfg.T, y_tr, iid=cfg.iid,
+                                         rng=rng)
+        if schedule is not None:
+            if (schedule.T, schedule.n) != (cfg.T, cfg.n):
+                raise ValueError(
+                    f"schedule is (T={schedule.T}, n={schedule.n}) but the "
+                    f"run is (T={cfg.T}, n={cfg.n})")
+            if activity is None:
+                activity = schedule.activity()
+        if faults is not None and faults.has_crashes:
+            # a crashed device stops collecting/training like a churned one
+            # — except nobody announced it (no replanning saw it coming)
+            if (faults.T, faults.n) != (cfg.T, cfg.n):
+                raise ValueError(
+                    f"fault schedule is (T={faults.T}, n={faults.n}) but "
+                    f"the run is (T={cfg.T}, n={cfg.n})")
+            base = (np.asarray(activity, bool) if activity is not None
+                    else np.ones((cfg.T, cfg.n), bool))
+            activity = base & faults.activity_mask()
+        if isinstance(streams, pl.FlatStreams):
+            if activity is not None:
+                act = np.asarray(activity, bool)
+                keep = act[streams.t, streams.dev]
+                streams = pl.FlatStreams(t=streams.t[keep],
+                                         dev=streams.dev[keep],
+                                         idx=streams.idx[keep],
+                                         n=streams.n, T=streams.T)
+            processed = pl.apply_movement_flat(streams, plan, rng)
+        else:
+            if activity is not None:
+                # inactive devices collect nothing (no-op for all-active
+                # masks, e.g. a constant schedule)
+                for t, i in zip(*np.nonzero(~np.asarray(activity, bool))):
+                    streams.collected[t][i] = np.empty(0, np.int64)
+            processed = pl.apply_movement(streams, plan, rng)
+        max_pts = pl.pad_size(processed, cfg.max_points)
+        act_all = (np.asarray(activity, bool) if activity is not None
+                   else np.ones((cfg.T, cfg.n), bool))
+        return streams, processed, act_all, max_pts
 
 
 def _history_base(cfg: FedConfig, y_tr, streams, processed,
@@ -266,25 +269,28 @@ def _history_base(cfg: FedConfig, y_tr, streams, processed,
     On the flat-stream path the O(n²) pairwise label-similarity
     diagnostics are skipped (``None``) — they are a small-n figure, and
     computing them at fog scale would defeat the sparse staging."""
-    hist = {"round": list(range(cfg.T)), "sim_before": None,
-            "sim_after": None}
-    hist["active"] = [act_all[t].copy() for t in range(cfg.T)]
-    if isinstance(processed, pl.FlatStreams):
-        cnt = np.bincount(processed.cell_key(),
-                          minlength=cfg.T * cfg.n).reshape(cfg.T, cfg.n)
-        hist["processed_counts"] = [row for row in cnt]
+    with monitoring.span("train.history"):
+        hist = {"round": list(range(cfg.T)), "sim_before": None,
+                "sim_after": None}
+        hist["active"] = [act_all[t].copy() for t in range(cfg.T)]
+        if isinstance(processed, pl.FlatStreams):
+            cnt = np.bincount(processed.cell_key(),
+                              minlength=cfg.T * cfg.n).reshape(cfg.T, cfg.n)
+            hist["processed_counts"] = [row for row in cnt]
+            return hist
+        col_labels = [np.concatenate([y_tr[ix] for row in streams.collected
+                                      for ix in [row[i]]]
+                                     or [np.empty(0, int)])
+                      for i in range(cfg.n)]
+        proc_labels = [np.concatenate([y_tr[processed[t][i]]
+                                       for t in range(cfg.T)]
+                                      or [np.empty(0, int)])
+                       for i in range(cfg.n)]
+        hist["sim_before"] = pl.label_similarity(col_labels)
+        hist["sim_after"] = pl.label_similarity(proc_labels)
+        hist["processed_counts"] = [[len(ix) for ix in processed[t]]
+                                    for t in range(cfg.T)]
         return hist
-    col_labels = [np.concatenate([y_tr[ix] for row in streams.collected
-                                  for ix in [row[i]]] or [np.empty(0, int)])
-                  for i in range(cfg.n)]
-    proc_labels = [np.concatenate([y_tr[processed[t][i]]
-                                   for t in range(cfg.T)] or [np.empty(0, int)])
-                   for i in range(cfg.n)]
-    hist["sim_before"] = pl.label_similarity(col_labels)
-    hist["sim_after"] = pl.label_similarity(proc_labels)
-    hist["processed_counts"] = [[len(ix) for ix in processed[t]]
-                                for t in range(cfg.T)]
-    return hist
 
 
 def run_network_aware_batched(cfgs: list[FedConfig], data,

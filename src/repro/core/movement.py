@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import monitoring
 from repro.core.costs import CostTraces, EdgeCostTraces
 from repro.core.schedule import as_schedule
 
@@ -304,6 +305,13 @@ def greedy_linear(traces: CostTraces, adj, *,
     Schedules without churn (raw matrices, stacks, constant/flap
     schedules) are bitwise unaffected.
     """
+    with monitoring.span("plan.greedy") as sp:
+        plan = _greedy_linear(traces, adj, backend)
+        sp.count(edges=len(plan.edges.t))
+    return plan
+
+
+def _greedy_linear(traces: CostTraces, adj, backend: str) -> MovementPlan:
     if isinstance(traces, EdgeCostTraces):
         return greedy_linear_edges(traces, adj)
     T, n = traces.c_node.shape
@@ -591,29 +599,30 @@ def repair_capacities(plan: MovementPlan, traces: CostTraces,
     tensor, yet remains bitwise-equal to ``repair_capacities_dense``
     and ``repair_capacities_loop`` (fractional convex plans included).
     """
-    T, n = plan.r.shape
-    sched = as_schedule(adj, T)
-    r = plan.r.copy()
-    dg = np.arange(n)
-    eye = np.eye(n, dtype=bool)
-    diag0 = plan.diag()                  # pre-repair s_ii, read one round ahead
-    cur = np.zeros((n, n))
-    prev = np.zeros((n, n))
-    ts, srcs, dsts, qtys = [], [], [], []
-    for t in range(T):
-        plan.round_dense(t, out=cur)
-        _repair_round(cur, r[t], prev if t > 0 else None, t, T,
-                      sched.adj_at(t), traces, D,
-                      diag0[t + 1] if t + 1 < T else None, dg, eye)
-        ii, jj = np.nonzero(cur)
-        ts.append(np.full(len(ii), t, np.int64))
-        srcs.append(ii.astype(np.int64))
-        dsts.append(jj.astype(np.int64))
-        qtys.append(cur[ii, jj].copy())
-        prev, cur = cur, prev            # repaired round feeds t+1 arrivals
-    edges = PlanEdges(t=np.concatenate(ts), src=np.concatenate(srcs),
-                      dst=np.concatenate(dsts), qty=np.concatenate(qtys))
-    return MovementPlan(r=r, edges=edges, n=n)
+    with monitoring.span("plan.repair"):
+        T, n = plan.r.shape
+        sched = as_schedule(adj, T)
+        r = plan.r.copy()
+        dg = np.arange(n)
+        eye = np.eye(n, dtype=bool)
+        diag0 = plan.diag()      # pre-repair s_ii, read one round ahead
+        cur = np.zeros((n, n))
+        prev = np.zeros((n, n))
+        ts, srcs, dsts, qtys = [], [], [], []
+        for t in range(T):
+            plan.round_dense(t, out=cur)
+            _repair_round(cur, r[t], prev if t > 0 else None, t, T,
+                          sched.adj_at(t), traces, D,
+                          diag0[t + 1] if t + 1 < T else None, dg, eye)
+            ii, jj = np.nonzero(cur)
+            ts.append(np.full(len(ii), t, np.int64))
+            srcs.append(ii.astype(np.int64))
+            dsts.append(jj.astype(np.int64))
+            qtys.append(cur[ii, jj].copy())
+            prev, cur = cur, prev    # repaired round feeds t+1 arrivals
+        edges = PlanEdges(t=np.concatenate(ts), src=np.concatenate(srcs),
+                          dst=np.concatenate(dsts), qty=np.concatenate(qtys))
+        return MovementPlan(r=r, edges=edges, n=n)
 
 
 def repair_capacities_dense(plan: MovementPlan, traces: CostTraces,

@@ -16,6 +16,7 @@ import warnings
 
 import numpy as np
 
+from repro.core import monitoring
 from repro.core.movement import MovementPlan
 
 
@@ -164,6 +165,15 @@ def apply_movement(streams: FogStreams, plan: MovementPlan,
     stays bitwise-identical to ``apply_movement_dense`` (the preserved
     oracle) — the reconstructed row IS the dense row.
     """
+    with monitoring.span("prep.route") as sp:
+        processed, collected, kept = _route_cells(streams, plan, rng)
+        sp.count(collected=collected, processed=kept)
+    return processed
+
+
+def _route_cells(streams: FogStreams, plan: MovementPlan, rng):
+    """:func:`apply_movement` without its span; also returns the
+    samples collected and the samples processed."""
     # foglint: disable=rng-stream-discipline -- documented default: rng=None selects fixed stream 1 (kept distinct from the collection stream); callers on the scenario path pass a derived Generator
     rng = rng or np.random.default_rng(1)
     n, T = streams.n, streams.T
@@ -172,14 +182,17 @@ def apply_movement(streams: FogStreams, plan: MovementPlan,
     buckets: list[list[list[np.ndarray]]] = \
         [[[] for _ in range(n)] for _ in range(T)]
     row_buf = np.zeros(n + 1)
+    collected = kept = 0
     for t in range(T):
         src, dst, qty = plan.round_edges(t)
         starts_e = np.searchsorted(src, np.arange(n + 1))
         r_t = plan.r[t]
         for i in range(n):
             idx = streams.collected[t][i]
-            if len(idx) == 0:
+            k = len(idx)
+            if k == 0:
                 continue
+            collected += k
             idx = rng.permutation(idx)
             row_buf[:] = 0.0
             sl = slice(starts_e[i], starts_e[i + 1])
@@ -196,10 +209,13 @@ def apply_movement(streams: FogStreams, plan: MovementPlan,
                 part = idx[starts[j]:ends[j]]
                 if j == i:
                     buckets[t][i].append(part)
+                    kept += part.size
                 elif t + 1 < T:
                     buckets[t + 1][j].append(part)
-    return [[np.concatenate(cell) if cell else np.empty(0, np.int64)
-             for cell in row] for row in buckets]
+                    kept += part.size
+    processed = [[np.concatenate(cell) if cell else np.empty(0, np.int64)
+                  for cell in row] for row in buckets]
+    return processed, collected, kept
 
 
 def apply_movement_flat(flat: FlatStreams, plan: MovementPlan,
@@ -220,6 +236,14 @@ def apply_movement_flat(flat: FlatStreams, plan: MovementPlan,
     order follows collection order, not the dense path's permuted
     order. Fractional plans fall back to the dense-oracle path through
     the stream converters (small n only)."""
+    with monitoring.span("prep.route") as sp:
+        out = _route_flat(flat, plan, rng)
+        sp.count(collected=int(flat.idx.shape[0]),
+                 processed=int(out.idx.shape[0]))
+    return out
+
+
+def _route_flat(flat: FlatStreams, plan: MovementPlan, rng) -> FlatStreams:
     n, T = flat.n, flat.T
     r = np.asarray(plan.r)
     route = np.full((T, n), -1, np.int64)   # no edge, no retain: discard
@@ -236,7 +260,7 @@ def apply_movement_flat(flat: FlatStreams, plan: MovementPlan,
             break
         route[t, src[on]] = dst[on]
     if not bang:
-        processed = apply_movement(streams_from_flat(flat), plan, rng)
+        processed, _, _ = _route_cells(streams_from_flat(flat), plan, rng)
         return flat_from_streams(
             FogStreams(collected=processed, n=n, T=T))
     dev2 = route[flat.t, flat.dev]
