@@ -5,9 +5,11 @@ steps, re-padding and re-uploading the batch tensor every round.  Here
 the whole horizon is one device-resident program:
 
 * the padded sample stream is staged once as ``(T, n, P)`` index /
-  label / weight arrays (indices gathered on host, pixels gathered on
-  device — either up front when the ``(T, n, P, ...)`` tensor fits
-  ``PRESTAGE_LIMIT_BYTES``, or per-round inside the scan body);
+  label / weight arrays, or as packed ``(T, R, C)`` chunk rows where
+  those execute fewer slots (``_stage_scan``, :func:`ragged_step`)
+  (indices gathered on host, pixels gathered on device — either up
+  front when the pixel tensor fits ``PRESTAGE_LIMIT_BYTES``, or
+  per-round inside the scan body);
 * the vmapped local-SGD step (eq. 3), the every-τ H-weighted
   aggregation (eq. 4), synchronization, churn masking and
   H-accumulation are folded into a single ``jax.lax.scan`` over rounds.
@@ -132,11 +134,11 @@ def _device_step_fn(apply_fn, eta):
 
 def _row_loss_fn(apply_fn):
     """UNNORMALIZED weighted CE of one ragged chunk row — the summand
-    of ``mm.ce_loss``'s numerator. The ragged engine sums these per
-    device through the segment reduce and divides by the staged sample
-    count afterwards (the counts equal the dense path's ``w.sum()``
-    exactly: 0/1 weights sum to exact integers), so the per-device loss
-    and gradient match the dense step up to summation order."""
+    of ``mm.ce_loss``'s numerator. :func:`ragged_step` divides it by
+    its device's staged sample count (the counts equal the dense path's
+    ``w.sum()`` exactly: 0/1 weights sum to exact integers), so the
+    per-device loss and gradient match the dense step up to summation
+    order."""
 
     def lf(p, xb, yb, w):
         logp = jax.nn.log_softmax(apply_fn(p, xb).astype(jnp.float32))
@@ -144,6 +146,61 @@ def _row_loss_fn(apply_fn):
         return -(ll * w).sum()
 
     return lf
+
+
+def ragged_step(vrow, eta, Wf, xb, yb, w, cell, cnt, active):
+    """One local-SGD round on chunk rows, shared by the scan engine's
+    packed programs and the batched engine's ragged staging.
+
+    ``Wf`` is the (M, ...) device stack, ``cnt``/``active`` are (M,),
+    and row r of the (R, C) tables ``xb``/``yb``/``w`` belongs to
+    device ``cell[r]`` (M marks a phantom row). ``vrow`` is
+    ``vmap(_row_loss_fn(apply_fn))``. The summed per-row loss is
+    differentiated THROUGH the row-param gather, so the gather's
+    transpose — a deterministic row-index-order scatter-add, i.e.
+    exactly the ``segment_sum`` reduction — accumulates per-device
+    gradients without materializing a (rows, param) gradient stack.
+    Phantom rows map through the clipped gather to device M−1: their
+    zero sample weights make every contribution a signed zero, and
+    x + ±0.0 preserves x, so that device's bits are untouched. Loss and
+    update follow ``_device_step_fn``: each row's loss is divided by its
+    device's staged count (== the dense ``w.sum()`` exactly) before
+    differentiating, as the dense step divides its mean, so only the
+    gradient's summation order differs from it; and
+    ``scale = active · min(count, 1)``, so a device without data gets
+    loss 0.0 and no update."""
+    from repro.kernels import ops
+
+    tree_map = jax.tree_util.tree_map
+    M = cnt.shape[0]
+    denom = jnp.maximum(cnt, 1.0)
+    scale = active * jnp.minimum(cnt, 1.0)
+
+    def rows_loss(Wf):
+        Wr = tree_map(lambda p: jnp.take(p, cell, axis=0, mode="clip"), Wf)
+        rloss = vrow(Wr, xb, yb, w)
+        inv = jnp.take(1.0 / denom, cell, mode="clip")
+        return (rloss * inv).sum(), rloss
+
+    (_, rloss), g = jax.value_and_grad(rows_loss, has_aux=True)(Wf)
+    lsum = ops.segment_sum_rows(rloss, cell, num_segments=M + 1)[:M]
+
+    def upd(flat, gs):
+        return flat - eta * scale.reshape((M,) + (1,) * (gs.ndim - 1)) * gs
+
+    return tree_map(upd, Wf, g), lsum / denom
+
+
+def _scan_step(apply_fn, eta, packed: bool):
+    """The scan round's local SGD as ``(W, xb, yb, w, cell, cnt,
+    active) -> (W, losses)``: the vmapped dense step over (n, P) slots,
+    or, on packed chunk-row tables, :func:`ragged_step`."""
+    if packed:
+        vrow = jax.vmap(_row_loss_fn(apply_fn))
+        return functools.partial(ragged_step, vrow, eta)
+    vstep = jax.vmap(_device_step_fn(apply_fn, eta))
+    return lambda W, xb, yb, w, cell, cnt, active: vstep(W, xb, yb, w,
+                                                         active)
 
 
 def make_device_step(apply_fn, eta):
@@ -291,15 +348,18 @@ def _guarded_uploads(W, contributing, upl, cor, guard: bool,
 # ---------------------------------------------------------------------------
 
 
-def _make_scan_body(apply_fn, vstep, prestage: bool, faults: bool,
+def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
                     guard: bool, quorum: float, x_tr, x_te, y_te,
                     hier=None):
     """The per-round scan body, shared by the monolithic program and
     the window-chunked checkpoint driver (same closure -> same jaxpr ->
     the chunked dispatches reproduce the monolithic scan bit for bit).
-    With ``faults`` the xs gain (upload_ok, corrupt) rows and the
-    aggregation runs guarded + quorum-gated; without, the trace is
-    exactly the historical clean program.
+    ``step`` is :func:`_scan_step`'s local SGD; the xs row ``cell`` is
+    None on dense (n, P) slots and the packed rows' owners otherwise
+    (the sample rows are then (R, C)). With ``faults`` the xs gain
+    (upload_ok, corrupt) rows and the aggregation runs guarded +
+    quorum-gated; without, the trace is exactly the historical clean
+    program.
 
     ``hier`` — optional :class:`_HierSpec`: the xs gain a trailing
     per-round ``lvl`` row (highest aggregating tier, 0 = none) and the
@@ -308,21 +368,26 @@ def _make_scan_body(apply_fn, vstep, prestage: bool, faults: bool,
     ``hier=None`` this function is untouched — the flat trace is the
     historical program, bit for bit."""
     tree_map = jax.tree_util.tree_map
+    # the in-scan gather reads rows of the (N, features) view: on the
+    # TPU an (N, 28, 28) image pads to (32, 128) tiles, and a flat row
+    # gathers 80 ms a job faster at the CNN cell on a TPU v5e (PERF.md)
+    x_rows = None if prestage else x_tr.reshape(x_tr.shape[0], -1)
 
     def body(carry, xs):
         W, wg, H, waiting = carry
         if hier is not None:
             xs, lvl = xs[:-1], xs[-1]
         if faults:
-            xb, idx, yb, w, cnt, a, agg, upl, cor = xs
+            xb, idx, yb, w, cell, cnt, a, agg, upl, cor = xs
         else:
-            xb, idx, yb, w, cnt, a, agg = xs
+            xb, idx, yb, w, cell, cnt, a, agg = xs
         if not prestage:
             with jax.named_scope("gather"):
-                xb = jnp.take(x_tr, idx, axis=0)
+                xb = jnp.take(x_rows, idx, axis=0).reshape(
+                    idx.shape + x_tr.shape[1:])
         active = a * (1.0 - waiting)
         with jax.named_scope("local_sgd"):
-            W, losses = vstep(W, xb, yb, w, active)
+            W, losses = step(W, xb, yb, w, cell, cnt, active)
         H = H + cnt * active
 
         def do_agg(ops):
@@ -458,21 +523,23 @@ def _scan_program(apply_fn, eta: float, prestage: bool,
     """One jitted program per (model, η, staging mode, fault config);
     the aggregation schedule arrives as the traced ``is_agg`` round
     mask, so changing τ does not recompile. With ``faults=False`` the
-    trace (and therefore the bits) is the historical clean program."""
-
-    vstep = jax.vmap(_device_step_fn(apply_fn, eta))
+    trace (and therefore the bits) is the historical clean program.
+    ``cell_all`` — the (T, R) row owners of packed staging
+    (``pipeline.stage_rounds_scan``); None on dense slots, whose
+    trace is unchanged by it."""
 
     # the name is the module's in traces (``jit_fog_scan``) and in the
     # persistent compile cache's key, which ignores op metadata: a
     # program whose scopes change must not keep the name of one
     # compiled without them
     def fog_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, counts,
-                 act, is_agg, x_te, y_te, *fault_ops):
+                 act, is_agg, x_te, y_te, *fault_ops, cell_all=None):
         n = counts.shape[1]
-        body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
+        step = _scan_step(apply_fn, eta, cell_all is not None)
+        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te)
         carry0 = (W0, wg0, jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32))
-        xs = (xb_all, idx_all, yb_all, w_all, counts, act, is_agg)
+        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act, is_agg)
         xs = xs + tuple(fault_ops)
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
@@ -490,13 +557,13 @@ def _scan_chunk_program(apply_fn, eta: float, prestage: bool,
     carry at each boundary. Iterating the identical body over a sliced
     round axis reproduces the monolithic scan bit for bit on CPU."""
 
-    vstep = jax.vmap(_device_step_fn(apply_fn, eta))
-
     def fog_scan_chunk(carry, x_tr, xb_all, idx_all, yb_all, w_all,
-                       counts, act, is_agg, x_te, y_te, *fault_ops):
-        body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
+                       counts, act, is_agg, x_te, y_te, *fault_ops,
+                       cell_all=None):
+        step = _scan_step(apply_fn, eta, cell_all is not None)
+        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te)
-        xs = (xb_all, idx_all, yb_all, w_all, counts, act, is_agg)
+        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act, is_agg)
         xs = xs + tuple(fault_ops)
         return jax.lax.scan(body, carry, xs)
 
@@ -519,16 +586,23 @@ def _stage_fault_ops(faults, T: int, n: int, tau: int):
 def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
                 faults, is_agg, *tail):
     """Stage one horizon for the scan programs, as the ``train.stage``
-    span: the padded (T, n, P) slots, the activity (crash outages ANDed
-    in), the fault views, and the pixels gathered up front when the
-    (T, n, P, ...) tensor fits ``PRESTAGE_LIMIT_BYTES``. Its counters:
-    ``slots`` T·n·P, ``samples`` the unpadded ones, ``h2d_bytes`` the
-    host arrays uploaded (the datasets stay pinned across calls). Host
-    arrays in ``tail`` ride after the dataset operands. Returns
-    (prestage, args, fault_ops)."""
+    span: the sample slots, the activity (crash outages ANDed in), the
+    fault views, and the pixels gathered up front when the slots' pixel
+    tensor fits ``PRESTAGE_LIMIT_BYTES``. The slots are
+    ``pipeline.stage_rounds_scan``'s: packed chunk-row tables where
+    those execute fewer slots than the dense (T, n, P) slab, else the
+    slab. Its counters: ``slots`` the sample slots executed (T·R·C packed,
+    T·n·P dense), ``samples`` the unpadded ones, ``packed`` 1 or 0,
+    ``h2d_bytes`` the host arrays uploaded (the datasets stay pinned
+    across calls). Host arrays in ``tail`` ride after the dataset
+    operands. Returns (prestage, args, fault_ops, cell): ``cell`` the
+    packed rows' owners on the device (the programs' ``cell_all``), or
+    None on dense slots."""
     with monitoring.span("train.stage") as sp:
-        idx, yb, wts, counts = pl.stage_rounds(processed, y_tr, max_pts)
-        T, n, P = idx.shape
+        idx, yb, wts, cell_h, counts = pl.stage_rounds_scan(
+            processed, y_tr, max_pts)
+        rows = () if cell_h is None else (cell_h,)
+        T, n = counts.shape
         act = np.asarray(act_all)
         fault_ops = ()
         if faults is not None:
@@ -538,7 +612,7 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
         x_dev = _to_device_cached(x_tr)
         idx_dev = jnp.asarray(idx)
         item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
-        prestage = T * n * P * item_bytes <= PRESTAGE_LIMIT_BYTES
+        prestage = idx.size * item_bytes <= PRESTAGE_LIMIT_BYTES
         if prestage:
             xb_all, idx_arg = _gather_rows(x_dev, idx_dev), None
         else:
@@ -547,10 +621,12 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
                 + tuple(jnp.asarray(a) for a in host)
                 + (_to_device_cached(x_te), _to_device_cached(y_te))
                 + tuple(jnp.asarray(a) for a in tail))
+        cell = jnp.asarray(rows[0]) if rows else None
         sp.count(slots=idx.size, samples=int(counts.sum()),
-                 h2d_bytes=sum(a.nbytes for a in (idx,) + host + tail
-                               + tuple(fault_ops)))
-    return prestage, args, fault_ops
+                 packed=len(rows),
+                 h2d_bytes=sum(a.nbytes for a in (idx,) + rows + host
+                               + tail + tuple(fault_ops)))
+    return prestage, args, fault_ops, cell
 
 
 def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
@@ -586,14 +662,14 @@ def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
     use_faults = faults is not None
     guard_f = bool(guard) if use_faults else False
     quorum_f = float(quorum) if use_faults else 0.0
-    prestage, args, fault_ops = _stage_scan(
+    prestage, args, fault_ops, cell = _stage_scan(
         processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
         is_agg)
 
     if checkpoint_path is not None or resume is not None:
         return _run_scan_checkpointed(
             apply_fn, params, n, T, tau, eta, prestage, args, fault_ops,
-            use_faults, guard_f, quorum_f, checkpoint_path,
+            cell, use_faults, guard_f, quorum_f, checkpoint_path,
             checkpoint_every, resume, stop_after)
 
     fn = _scan_program(apply_fn, float(eta), prestage, use_faults,
@@ -602,7 +678,8 @@ def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
     # disallows implicit transfers across the whole-horizon dispatch
     # (staging above and history readback below are explicit, by design)
     with monitoring.span("train.device"), sanitize.hot_loop_guard():
-        res = fn(_stack(params, n), params, *args, *fault_ops)
+        res = fn(_stack(params, n), params, *args, *fault_ops,
+                 cell_all=cell)
         losses, tl, ta, H_at = res[1:5]
         jax.block_until_ready(losses)
     with monitoring.span("train.readback"):
@@ -644,16 +721,18 @@ def _hier_program(apply_fn, eta: float, prestage: bool,
     traced xs row, so trees with identical shape but different τ
     chains share one compiled program."""
     spec = _HIER_SPECS[tree_fp]
-    vstep = jax.vmap(_device_step_fn(apply_fn, eta))
 
     def fog_hier_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all,
-                      counts, act, is_agg, x_te, y_te, lvl, *fault_ops):
+                      counts, act, is_agg, x_te, y_te, lvl, *fault_ops,
+                      cell_all=None):
         n = counts.shape[1]
-        body = _make_scan_body(apply_fn, vstep, prestage, faults, guard,
+        step = _scan_step(apply_fn, eta, cell_all is not None)
+        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
                                quorum, x_tr, x_te, y_te, hier=spec)
         carry0 = (W0, wg0, jnp.zeros(n, jnp.float32),
                   jnp.zeros(n, jnp.float32))
-        xs = (xb_all, idx_all, yb_all, w_all, counts, act, is_agg)
+        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act,
+              is_agg)
         xs = xs + tuple(fault_ops) + (lvl,)
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
@@ -712,14 +791,15 @@ def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
     use_faults = faults is not None
     guard_f = bool(guard) if use_faults else False
     quorum_f = float(quorum) if use_faults else 0.0
-    prestage, args, fault_ops = _stage_scan(
+    prestage, args, fault_ops, cell = _stage_scan(
         processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
         is_agg, lvl)
 
     fn = _hier_program(apply_fn, float(eta), prestage, use_faults,
                        guard_f, quorum_f, fp)
     with monitoring.span("train.device"), sanitize.hot_loop_guard():
-        res = fn(_stack(params, n), params, *args, *fault_ops)
+        res = fn(_stack(params, n), params, *args, *fault_ops,
+                 cell_all=cell)
         losses, tl, ta, H_at = res[1:5]
         jax.block_until_ready(losses)
     with monitoring.span("train.readback"):
@@ -740,7 +820,7 @@ def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
 
 
 def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
-                           args, fault_ops, use_faults, guard, quorum,
+                           args, fault_ops, cell, use_faults, guard, quorum,
                            checkpoint_path, checkpoint_every, resume,
                            stop_after):
     """Window-chunked scan with checkpoint/resume (see
@@ -800,7 +880,8 @@ def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
                 None if xb_all is None else xb_all[sl],
                 None if idx_arg is None else idx_arg[sl],
                 yb[sl], wts[sl], counts[sl], act[sl], is_agg[sl], x_te,
-                y_te, *(op[sl] for op in fault_ops))
+                y_te, *(op[sl] for op in fault_ops),
+                cell_all=None if cell is None else cell[sl])
             jax.block_until_ready(ys)
         with monitoring.span("train.readback"):
             for k, y in zip(keys, ys):
@@ -838,7 +919,7 @@ class AsyncEvaluator:
     keep training the next scenario while eval results trickle from
     device to host. ``submit_stack`` evaluates a whole STACK of
     parameter snapshots (e.g. the (S, windows) grid of a scenario
-    bucket) in one vmapped dispatch, so one evaluator drains an entire
+    bucket) in one dispatch, so one evaluator drains an entire
     bucket's eval queue. The test set is pinned device-resident;
     submissions hold device arrays only.
 
@@ -891,8 +972,8 @@ class AsyncEvaluator:
 
     def submit_stack(self, params_stack, n_axes: int = 1) -> None:
         """Evaluate a stack of snapshots in ONE dispatch: the leading
-        ``n_axes`` axes of every leaf are batch axes (vmapped over the
-        pinned test set). The results arrive at ``collect()`` as arrays
+        ``n_axes`` axes of every leaf are batch axes (each snapshot
+        evaluated on the pinned test set in turn). The results arrive at ``collect()`` as arrays
         of that batch shape, in submission order."""
         if self._errors:
             return
@@ -959,10 +1040,17 @@ def _eval_stack_program(apply_fn, n_axes: int):
         logits = apply_fn(p, x)
         return mm.ce_loss(logits, y), mm.accuracy(logits, y)
 
-    fn = ev
-    for _ in range(n_axes):             # vmap the leading snapshot axes
-        fn = jax.vmap(fn, in_axes=(0, None, None))
-    return jax.jit(fn)
+    def stacked(ps, x, y):
+        # one snapshot at a time: every snapshot runs the same program
+        # whatever the stack's extent, so its bits do not depend on the
+        # scenarios it shares a bucket with
+        lead = jax.tree_util.tree_leaves(ps)[0].shape[:n_axes]
+        flat = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[n_axes:]), ps)
+        tl, ta = jax.lax.map(lambda p: ev(p, x, y), flat)
+        return tl.reshape(lead), ta.reshape(lead)
+
+    return jax.jit(stacked)
 
 
 # Scenario-batched / sharded bucket programs, keyed by
@@ -1077,44 +1165,15 @@ def _bucket_program(apply_fn, eta: float, prestage: bool, mesh,
     ragged = staging == "ragged"
 
     def ragged_round(W, xb, yb, w, cell, cnt, active):
-        """One ragged round: differentiate the summed per-row loss
-        THROUGH the row-param gather, so the gather's transpose — a
-        deterministic row-index-order scatter-add, i.e. exactly the
-        ``segment_sum`` reduction — accumulates per-device gradients
-        without ever materializing a (rows, param) gradient stack
-        (~1.4× faster than the explicit vmap(grad) + segment_sum
-        formulation on CPU). Phantom rows carry the trash cell id S·n,
-        which the clipped gather maps to row S·n−1: their zero sample
-        weights make every contribution a signed zero, and x + ±0.0
-        preserves x, so the last device's bits are untouched. The
-        per-device loss denominator is the STAGED count (== the dense
-        w.sum() exactly, see ``_row_loss_fn``); devices without data
-        get loss 0.0 and a zero-scaled update, like the dense step."""
-        from repro.kernels import ops
-
+        """One ragged round: :func:`ragged_step` on the flat (S·n)
+        device axis (phantom rows carry the trash cell id S·n)."""
         S_loc, n_loc = cnt.shape
         M = S_loc * n_loc
-        denom = jnp.maximum(cnt.reshape(M), 1.0)
-        scale = (active * jnp.minimum(cnt, 1.0)).reshape(M)
         Wf = tree_map(lambda p: p.reshape((M,) + p.shape[2:]), W)
-
-        def bucket_loss(Wf):
-            Wr = tree_map(lambda p: jnp.take(p, cell, axis=0,
-                                             mode="clip"), Wf)
-            rloss = vrow(Wr, xb, yb, w)
-            return rloss.sum(), rloss
-
-        (_, rloss), g = jax.value_and_grad(bucket_loss,
-                                           has_aux=True)(Wf)
-        lsum = ops.segment_sum_rows(rloss, cell, num_segments=M + 1)[:M]
-        losses = (lsum / denom).reshape(S_loc, n_loc)
-
-        def upd(p, flat, gs):
-            sh = (M,) + (1,) * (gs.ndim - 1)
-            gs = gs / denom.reshape(sh)
-            return (flat - eta * scale.reshape(sh) * gs).reshape(p.shape)
-
-        return tree_map(upd, W, Wf, g), losses
+        Wf, losses = ragged_step(vrow, eta, Wf, xb, yb, w, cell,
+                                 cnt.reshape(M), active.reshape(M))
+        return (tree_map(lambda p, f: f.reshape(p.shape), W, Wf),
+                losses.reshape(S_loc, n_loc))
 
     def agg_sums(W, H, contributing):
         """Numerator/denominator of eq. (4) — psum-reduced on a mesh.

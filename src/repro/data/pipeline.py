@@ -435,66 +435,17 @@ def pad_batches(processed_t: list[np.ndarray], x: np.ndarray,
 
 
 def stage_rounds(processed, y: np.ndarray, max_points: int):
-    """Stage the whole horizon for the scan engine.
+    """Stage the whole horizon as dense (T, n, P) slots.
 
     Returns (idx (T, n, P) int32 — global sample ids, 0-padded;
     yb (T, n, P) int32; w (T, n, P) float32 weight mask;
     counts (T, n) float32). Pixels are gathered on device from these
-    indices by ``core.engine``. A :class:`FlatStreams` input takes the
-    vectorized O(samples) path (:func:`stage_rounds_flat`); per-cell
-    lists take the original loop — same staged arrays for equivalent
-    cell contents."""
-    if isinstance(processed, FlatStreams):
-        return stage_rounds_flat(processed, y, max_points)
-    T, n, P = len(processed), len(processed[0]), max_points
-    idx = np.zeros((T, n, P), np.int32)
-    yb = np.zeros((T, n, P), np.int32)
-    w = np.zeros((T, n, P), np.float32)
-    counts = np.zeros((T, n), np.float32)
-    for t, row in enumerate(processed):
-        for i, ix in enumerate(row):
-            k = len(ix)
-            if k > P:
-                warnings.warn(
-                    f"stage_rounds: device {i} round {t} holds {k} "
-                    f"samples but P={P}; truncating", stacklevel=2)
-                k = P
-            if k:
-                idx[t, i, :k] = ix[:k]
-                yb[t, i, :k] = y[ix[:k]]
-                w[t, i, :k] = 1.0
-            counts[t, i] = k
-    return idx, yb, w, counts
-
-
-def stage_rounds_flat(flat: FlatStreams, y: np.ndarray, max_points: int):
-    """Vectorized :func:`stage_rounds` over a flat stream: one stable
-    sort by cell, within-cell slot positions by run-length arithmetic,
-    one scatter per staged array — no per-(t, i) Python work."""
-    T, n, P = flat.T, flat.n, max_points
-    idx = np.zeros((T, n, P), np.int32)
-    yb = np.zeros((T, n, P), np.int32)
-    w = np.zeros((T, n, P), np.float32)
-    key = flat.cell_key()
-    order = np.argsort(key, kind="stable")
-    sk, si = key[order], flat.idx[order]
-    cell_counts = np.bincount(sk, minlength=T * n).astype(np.int64)
-    starts = np.concatenate([[0], np.cumsum(cell_counts)])
-    pos = np.arange(sk.size, dtype=np.int64) \
-        - starts[:-1][np.repeat(np.arange(T * n), cell_counts)]
-    over = int(cell_counts.max()) if cell_counts.size else 0
-    if over > P:
-        warnings.warn(
-            f"stage_rounds_flat: a device holds {over} samples but "
-            f"P={P}; truncating", stacklevel=2)
-    fit = pos < P
-    flat_slot = sk[fit] * np.int64(P) + pos[fit]
-    idx.reshape(-1)[flat_slot] = si[fit]
-    yb.reshape(-1)[flat_slot] = y[si[fit]]
-    w.reshape(-1)[flat_slot] = 1.0
-    counts = np.minimum(cell_counts, P).astype(np.float32) \
-        .reshape(T, n)
-    return idx, yb, w, counts
+    indices by ``core.engine``. Per-cell lists and a
+    :class:`FlatStreams` stage alike, in O(samples): one pass over the
+    cells, one scatter per staged array; a cell keeps its first P
+    samples."""
+    P = int(max_points)
+    return _dense_slots(*_cells_upto(processed, P), y, P)
 
 
 @dataclasses.dataclass
@@ -532,6 +483,14 @@ class ScenarioBatch:
 # cell) instead of S·P_max. Larger chunks mean fewer rows (less
 # parameter gather/scatter traffic) but more slot padding per cell.
 RAGGED_CHUNK = 8
+
+
+# chunk size of the scan engine's packed rows (stage_rounds_scan).
+# At the paper's CNN cell C=32, 64 and 128 all execute 2,048 slots a
+# round; on a TPU v5e the scan program took 1,312, 652 and 370 ms a job
+# against 1,230 ms on dense slots (PERF.md): larger rows batch the
+# vmapped convolutions into fewer, larger groups
+PACKED_CHUNK = 128
 
 
 @dataclasses.dataclass
@@ -578,21 +537,123 @@ class RaggedScenarioBatch:
         return S, T_b, n_b, R_b, C
 
 
-def _cell_table(processed, y=None):
+def _cell_table(processed):
     """Normalize per-cell lists or a :class:`FlatStreams` into
     ((T, n) sample counts, concatenated ids in (t, dev, within-cell)
-    order) — the inputs the ragged stager scatters from."""
+    order) — the inputs the chunk-row and slot stagers scatter from —
+    in one pass over the cells."""
     if isinstance(processed, FlatStreams):
         T, n = processed.T, processed.n
         lens = np.bincount(processed.cell_key(),
                            minlength=T * n).astype(np.int64).reshape(T, n)
         return lens, np.asarray(processed.idx, np.int64)
-    lens = np.array([[len(ix) for ix in row] for row in processed],
-                    np.int64).reshape(len(processed), -1)
-    cells = [np.asarray(ix, np.int64) for row in processed for ix in row]
-    ids = (np.concatenate(cells) if cells and lens.sum()
-           else np.empty(0, np.int64))
+    cells = [ix for row in processed for ix in row]
+    lens = np.fromiter((len(ix) for ix in cells), np.int64,
+                       len(cells)).reshape(len(processed), -1)
+    ids = (np.concatenate(cells).astype(np.int64, copy=False)
+           if cells and lens.sum() else np.empty(0, np.int64))
     return lens, ids
+
+
+def _cells_upto(processed, P: int):
+    """:func:`_cell_table` with each cell cut to its first P samples
+    (warning once when one holds more)."""
+    lens, ids = _cell_table(processed)
+    if lens.size and lens.max() > P:
+        warnings.warn(
+            f"staging: a device holds {int(lens.max())} samples but "
+            f"P={P}; truncating", stacklevel=3)
+        T, n = lens.shape
+        lens_flat = lens.reshape(-1)
+        cell_of = np.repeat(np.arange(T * n, dtype=np.int64), lens_flat)
+        starts = np.cumsum(lens_flat) - lens_flat
+        ids = ids[np.arange(ids.size, dtype=np.int64) - starts[cell_of] < P]
+        lens = np.minimum(lens, P)
+    return lens, ids
+
+
+def _dense_slots(lens, ids, y, P: int):
+    """(idx, yb, w, counts) of :func:`stage_rounds` from the cells."""
+    T, n = lens.shape
+    idx = np.zeros((T, n, P), np.int32)
+    yb = np.zeros((T, n, P), np.int32)
+    w = np.zeros((T, n, P), np.float32)
+    _scatter_samples(lens, ids, y, np.arange(T * n, dtype=np.int64) * P,
+                     idx, yb, w)
+    return idx, yb, w, lens.astype(np.float32)
+
+
+def _scatter_samples(lens, ids, y, first, idx, yb, w):
+    """Write each cell's samples, in order, to consecutive flat slots of
+    ``idx``/``yb``/``w`` from the cell's first slot ``first`` (flat,
+    one per cell of the (T, n) ``lens``): one scatter per staged
+    array."""
+    if ids.size:
+        lens_flat = lens.reshape(-1)
+        flat = np.arange(ids.size, dtype=np.int64) + np.repeat(
+            first - (np.cumsum(lens_flat) - lens_flat), lens_flat)
+        idx.reshape(-1)[flat] = ids
+        yb.reshape(-1)[flat] = y[ids]
+        w.reshape(-1)[flat] = 1.0
+
+
+def _scatter_rows(lens, ids, y, base: int, off, idx, yb, w, cell):
+    """Scatter one stream's samples into chunk-row tables, in place:
+    cell (t, dev) of ``lens`` becomes ceil(count / C) consecutive rows
+    of round t from row ``off[t]`` on, devices in index order, each row
+    labelled ``base + dev`` in ``cell``; a cell's samples fill its rows
+    slot by slot. Returns the rows per round."""
+    T, n = lens.shape
+    R_b, C = idx.shape[1:]
+    nrows = -(-lens // C)
+    nr_flat = nrows.reshape(-1)
+    per_round = nrows.sum(1)
+    # flat row id of each cell's first row
+    first = ((np.cumsum(nr_flat) - nr_flat).reshape(T, n)
+             - (np.cumsum(per_round) - per_round)[:, None]
+             + (np.asarray(off, np.int64)
+                + np.arange(T, dtype=np.int64) * R_b)[:, None]).reshape(-1)
+    _scatter_samples(lens, ids, y, first * C, idx, yb, w)
+    rows = np.arange(int(nr_flat.sum()), dtype=np.int64) + np.repeat(
+        first - (np.cumsum(nr_flat) - nr_flat), nr_flat)
+    cell.reshape(-1)[rows] = base + np.repeat(
+        np.tile(np.arange(n, dtype=np.int64), T), nr_flat)
+    return per_round
+
+
+def stage_rounds_scan(processed, y: np.ndarray, max_points: int):
+    """Stage the horizon for the scan engine: as packed chunk rows where
+    those execute fewer sample slots than the dense (T, n, P) slab of
+    :func:`stage_rounds`, else as that slab — decided from the staged
+    counts alone.
+
+    Each (t, dev) cell keeps its first ``max_points`` samples, as
+    :func:`stage_rounds` does. Packed, a cell becomes ceil(count / C)
+    rows of ``C = PACKED_CHUNK`` slots; round t's rows are packed
+    device-major into an (R, C) table with ``cell[t, r]`` the owning
+    device (``n`` marks a phantom row). R is the pow2 bucket of the
+    busiest round's row count, without the ``BUCKET_MAX_INFLATION``
+    cap: jobs whose loads vary within 2x share one compiled program.
+    The rows are taken only when T·R·C < T·n·P.
+
+    Returns (idx, yb int32, w float32 slot mask — each (T, R, C) packed
+    or (T, n, P) dense —, cell (T, R) int32 or None when dense, counts
+    (T, n) float32). The dense arrays and the counts equal
+    :func:`stage_rounds`'."""
+    C, P = int(PACKED_CHUNK), int(max_points)
+    lens, ids = _cells_upto(processed, P)
+    T, n = lens.shape
+    rows = (-(-lens // C)).sum(1)
+    R = bucket_size(max(int(rows.max(initial=0)), 1), "pow2")
+    if R * C >= n * P:
+        idx, yb, w, counts = _dense_slots(lens, ids, y, P)
+        return idx, yb, w, None, counts
+    idx = np.zeros((T, R, C), np.int32)
+    yb = np.zeros((T, R, C), np.int32)
+    w = np.zeros((T, R, C), np.float32)
+    cell = np.full((T, R), n, np.int32)
+    _scatter_rows(lens, ids, y, 0, np.zeros(T, np.int64), idx, yb, w, cell)
+    return idx, yb, w, cell, lens.astype(np.float32)
 
 
 def stage_scenario_ragged(processed_list, y: np.ndarray,
@@ -604,8 +665,8 @@ def stage_scenario_ragged(processed_list, y: np.ndarray,
     """Ragged counterpart of :func:`stage_scenario_batch`.
 
     Per-round chunk-row tables are built with one scatter per staged
-    array (the :func:`stage_rounds_flat` idiom): every (scenario,
-    round, device) cell becomes ceil(count/chunk) rows, rows of one
+    array (:func:`_scatter_rows`): every (scenario, round, device) cell
+    becomes ceil(count/chunk) rows, rows of one
     round packed scenario-major (scenario rows contiguous, devices in
     index order — the order the in-bucket-equals-alone bitwise
     guarantee rests on), the row axis bucketed like the other compute
@@ -658,28 +719,8 @@ def stage_scenario_ragged(processed_list, y: np.ndarray,
         counts[b, :T, :n] = lens
         act[b, :T, :n] = np.asarray(act_list[b], np.float32)
         is_agg[b, :T] = (np.arange(T) + 1) % tau == 0
-        if ids.size:
-            nr_flat = nrows[b].reshape(-1)
-            lens_flat = lens.reshape(-1)
-            cell_of = np.repeat(np.arange(T * n, dtype=np.int64),
-                                lens_flat)
-            starts = np.concatenate([[0], np.cumsum(lens_flat)])[:-1]
-            pos = np.arange(ids.size, dtype=np.int64) - starts[cell_of]
-            # scenario-local row index of each cell within its round
-            rowbase = np.cumsum(nr_flat) - nr_flat
-            round_start = np.concatenate(
-                [[0], np.cumsum(nrows[b].sum(1))])[:-1]
-            rowbase -= np.repeat(round_start, n)
-            t_of = cell_of // n
-            row = off[t_of] + rowbase[cell_of] + pos // C
-            slot = pos % C
-            flat = (t_of * np.int64(R_b) + row) * C + slot
-            idx.reshape(-1)[flat] = ids
-            yb.reshape(-1)[flat] = y[ids]
-            w.reshape(-1)[flat] = 1.0
-            cell.reshape(-1)[t_of * np.int64(R_b) + row] = \
-                b * n_b + (cell_of % n)
-        off[:T] += nrows[b].sum(1)
+        off[:T] += _scatter_rows(lens, ids, y, b * n_b, off[:T], idx, yb,
+                                 w, cell)
     return RaggedScenarioBatch(
         idx=idx, yb=yb, w=w, cell=cell, counts=counts, act=act,
         is_agg=is_agg, T=T_s, n=n_s, P=P_s, tau=tau, chunk=C,

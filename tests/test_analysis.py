@@ -667,7 +667,7 @@ class TestOraclePairingBackfill:
                                            np.random.default_rng(5))
         P = max(len(ix) for row in proc_lists for ix in row) or 1
         idx_l, yb_l, w_l, c_l = pl.stage_rounds(proc_lists, y_tr, P)
-        idx_f, yb_f, w_f, c_f = pl.stage_rounds_flat(proc_flat, y_tr, P)
+        idx_f, yb_f, w_f, c_f = pl.stage_rounds(proc_flat, y_tr, P)
         np.testing.assert_array_equal(c_l, c_f)
         np.testing.assert_array_equal(w_l.sum(-1), w_f.sum(-1))
         T, n = c_l.shape

@@ -125,12 +125,21 @@ def test_scan_path_spans_and_counters():
         assert tot[name]["calls"] == 1, name
     processed, P = prep[1], prep[3]
     samples = sum(len(ix) for row in processed for ix in row)
+    # the slots executed: packed chunk rows where they are fewer than
+    # the dense (T, n, P) slab's
+    C = pl.PACKED_CHUNK
+    rows = max(sum(-(-len(ix) // C) for ix in row) for row in processed)
+    R = 1 << (max(rows, 1) - 1).bit_length()
+    packed = R * C < cfg.n * P
+    slots = cfg.T * R * C if packed else cfg.T * cfg.n * P
     st = tot["train.stage"]
-    assert st["slots"] == cfg.T * cfg.n * P
+    assert st["packed"] == int(packed)
+    assert st["slots"] == slots
     assert st["samples"] == samples
-    # idx, yb, w (12 B a slot); counts and activity (4 B a cell); is_agg
-    assert st["h2d_bytes"] == 12 * cfg.T * cfg.n * P + 8 * cfg.T * cfg.n \
-        + cfg.T
+    # idx, yb, w (12 B a slot); the row owners (4 B a packed row);
+    # counts and activity (4 B a cell); is_agg
+    assert st["h2d_bytes"] == 12 * slots + 4 * cfg.T * R * packed \
+        + 8 * cfg.T * cfg.n + cfg.T
     route = tot["prep.route"]
     assert route["processed"] == samples
     assert route["collected"] == sum(len(ix) for row in streams.collected
