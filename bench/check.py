@@ -105,15 +105,14 @@ def plane_wrong(call, out, tau):
     return wrong
 
 
-def reference_window(call, out, config, data):
+def reference_window(call, out, config, model, data):
     """The reference's (losses (τ + 1, n), test loss) of one job, trained
-    on the samples the program processed."""
+    on the samples the program processed; ``model`` is the
+    configuration's model module."""
     tau = int(config["tau"])
-    x_tr, y_tr, x_te, y_te = data
-    return ref.first_window(
-        config["model"], call.seed, float(config["eta"]), x_tr, y_tr,
-        x_te, y_te, out["processed"][:tau + 1], pad=int(config["max_points"]),
-        precision=config["matmul_precision"])
+    return ref.first_window(model, config, call.seed, data,
+                            out["processed"][:tau + 1],
+                            precision=config["matmul_precision"])
 
 
 def window_gaps(prog, losses):
@@ -123,7 +122,7 @@ def window_gaps(prog, losses):
     return np.abs(prog - losses) / np.maximum(np.abs(losses), floor)
 
 
-def training_numbers(call, out, config, data, *, model_out=None,
+def training_numbers(call, out, config, model, data, *, model_out=None,
                      reference=None):
     """first_loss_gap, loss_gap, broadcast_loss_gap and test_loss_gap of
     one job against the reference.
@@ -133,7 +132,7 @@ def training_numbers(call, out, config, data, *, model_out=None,
     ``reference`` is the reference's, when already worked out."""
     tau = int(config["tau"])
     losses, test_loss = reference or reference_window(call, out, config,
-                                                      data)
+                                                      model, data)
     if model_out is None:
         hist = out["hist"]
         prog = np.stack([np.asarray(v, np.float64)
@@ -148,12 +147,12 @@ def training_numbers(call, out, config, data, *, model_out=None,
             "test_loss_gap": abs(prog_test - test_loss) / abs(test_loss)}
 
 
-def call_numbers(call, out, config, adj, data, reference=None):
+def call_numbers(call, out, config, model, adj, data, reference=None):
     """Every number one job compares."""
     nums = planner_numbers(call, adj, config["setting"], float(call.D.mean()),
                            out)
     nums["plane_wrong"] = plane_wrong(call, out, int(config["tau"]))
-    nums.update(training_numbers(call, out, config, data,
+    nums.update(training_numbers(call, out, config, model, data,
                                  reference=reference))
     return nums
 

@@ -10,7 +10,7 @@ check compares three ways:
   reading of a limit comes from these);
 * ``control``: the reference put in the program's place one precision
   below the configuration's: float32 becomes bfloat16 (the planner's
-  costs, the model's weights, pixels and activations), at the
+  costs, the model's weights, floating inputs and activations), at the
   configuration's matmul precision;
 * the faults the check must catch, planted in the reference put in the
   program's place: ``unchanged`` (a step returns the weights it got),
@@ -75,11 +75,11 @@ def moved_sample(processed, seed):
     return processed[:t] + [row] + processed[t + 1:]
 
 
-def readings(call, out, config, adj, data, rounds_gap=None):
+def readings(call, out, config, model, adj, data, rounds_gap=None):
     """{variant: {number: reading}} of one call. ``rounds_gap``, where
     given, collects {variant: largest loss gap of each round}."""
-    reference = check.reference_window(call, out, config, data)
-    res = {"program": check.call_numbers(call, out, config, adj, data,
+    reference = check.reference_window(call, out, config, model, data)
+    res = {"program": check.call_numbers(call, out, config, model, adj, data,
                                          reference=reference)}
     c = call.costs
     bf16 = ml_dtypes.bfloat16
@@ -100,7 +100,6 @@ def readings(call, out, config, adj, data, rounds_gap=None):
         call, dict(out, processed=moved_sample(out["processed"], call.seed)),
         int(config["tau"]))
     tau = int(config["tau"])
-    x_tr, y_tr, x_te, y_te = data
     rounds = out["processed"][:tau + 1]
     variants = {"control": dict(dtype="bfloat16"),
                 "unchanged": dict(fault="unchanged"),
@@ -110,13 +109,12 @@ def readings(call, out, config, adj, data, rounds_gap=None):
                                   out["hist"]["device_loss"][:tau + 1]]),
                         None)}
     for name, kw in variants.items():
-        mo = ref.first_window(config["model"], call.seed, float(config["eta"]),
-                              x_tr, y_tr, x_te, y_te, rounds,
-                              pad=int(config["max_points"]),
+        mo = ref.first_window(model, config, call.seed, data, rounds,
                               precision=config["matmul_precision"], **kw)
         outs[name] = mo
         res.setdefault(name, {}).update(check.training_numbers(
-            call, out, config, data, model_out=mo, reference=reference))
+            call, out, config, model, data, model_out=mo,
+            reference=reference))
     if rounds_gap is not None:
         for name, (losses, _) in outs.items():
             g = check.window_gaps(losses, reference[0]).max(axis=1)
@@ -132,7 +130,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT):
     ap.add_argument("--calls", type=int, default=3)
     args = ap.parse_args(argv)
     cell = run.load_cell(args.workload, root)
-    config, traffic = cell["config"], cell["traffic"]
+    config, traffic, model = cell["config"], cell["traffic"], cell["model"]
     sys.path.insert(0, os.path.join(root, "src"))
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -141,10 +139,9 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT):
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     run.find_devices(int(cell["workload"]["chips"]), require_tpu)
-    data = gen.image_dataset(int(config["n_train"]), int(config["n_test"]),
-                             int(config["data_seed"]))
+    data = model.dataset(config)
     tg = gen.Traffic(config, traffic)
-    sut = run.System(config, data)
+    sut = run.System(config, data, model)
     spans = run.Spans(annotate=False)
     worst = {}
     for seed in [int(s) for s in args.seeds.split(",")]:
@@ -152,7 +149,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT):
         for k in range(args.calls):
             call = tg.call(seed, k)
             out = sut.run(call, spans)
-            for variant, nums in readings(call, out, config, sut.adj,
+            for variant, nums in readings(call, out, config, model, sut.adj,
                                           data, rounds_gap).items():
                 d = per_seed.setdefault(variant, {})
                 for name, v in nums.items():

@@ -12,26 +12,14 @@ import os
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def _conv(h, w, cin, cout, k):
-    return 2 * h * w * cout * k * k * cin
-
-
-def forward_flops(model: str) -> int:
-    """Multiply-adds x 2 of one sample's forward pass."""
-    if model == "mlp":                        # 784-200-10
-        return 2 * 784 * 200 + 2 * 200 * 10
-    if model == "cnn":                        # conv5x5(16), pool, conv5x5(32), pool, 1568-128-10
-        return (_conv(28, 28, 1, 16, 5) + _conv(14, 14, 16, 32, 5)
-                + 2 * 1568 * 128 + 2 * 128 * 10)
-    raise KeyError(f"no FLOP count for model {model!r}")
-
-
-def job_flops(model: str, processed: int, n_test: int, aggregations: int):
+def job_flops(config: dict, model, processed: int, aggregations: int):
     """A training job's required FLOPs: forward and backward (3 x forward)
-    on every processed sample, and one forward pass over the test set at
-    every aggregation."""
-    f = forward_flops(model)
-    return 3 * f * processed + f * n_test * aggregations
+    on every processed sample, and one forward pass over the configuration's
+    test set at every aggregation. ``model`` is the configuration's model
+    module, whose ``forward_flops(config)`` counts one sample's forward
+    pass."""
+    f = model.forward_flops(config)
+    return 3 * f * processed + f * int(config["n_test"]) * aggregations
 
 
 def peaks(device_kind: str, table: str = os.path.join(HERE, "peaks.json")

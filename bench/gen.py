@@ -9,8 +9,11 @@ program; ``run.py`` wraps the arrays into the program's own types.
 
 What a run sees:
 
-* the dataset: synthetic 28x28 images made from the configuration's
-  ``data_seed`` (vectorised copy of the program's per-sample loop);
+* the dataset: what the configuration's model module
+  (``bench/models/<model>.py``) makes from the configuration alone. The
+  image models take ``image_dataset``: synthetic 28x28 images made from
+  the configuration's ``data_seed`` (vectorised copy of the program's
+  per-sample loop);
 * the network: one cost trace drawn from the configuration's
   ``network_seed``. A deployment is one fog network, so every seed of a
   cell plans over the same devices and links, and the work of a call does

@@ -9,7 +9,7 @@ def read(run):
     if not calls:
         return None
     cfg = run["config"]
-    work = sum(flops.job_flops(cfg["model"], c["samples"], cfg["n_test"],
+    work = sum(flops.job_flops(cfg, run["model"], c["samples"],
                                c["aggregations"]) for c in calls)
     peak = run["device"]["peaks"]["flops_bf16"]
     return 100.0 * work / (run["window_s"] * run["chips"] * peak)

@@ -1,8 +1,9 @@
 """The plain reference the benchmark holds the timed path to.
 
-Written from the paper (arXiv:2004.08488 §III-§V) and the model
-definitions, in straightforward numpy and ``jax.numpy``, importing nothing
-of the program and taking nothing it made:
+Written from the paper (arXiv:2004.08488 §III-§V) in straightforward
+numpy and ``jax.numpy``, importing nothing of the program and taking
+nothing it made; each model's own reference is its module under
+``bench/models/``:
 
 * ``greedy_rule``: Theorem 3 in float64: each (t, i) processes, offloads
   to its cheapest out-neighbour k (cost c_ik(t) + c_k(t+1)) or discards
@@ -19,10 +20,11 @@ of the program and taking nothing it made:
   rounds, the H-weighted aggregation (eq. 4), the test loss of the
   aggregated model, and the first round after it, which every device
   starts from the aggregated model, at a stated dtype and matmul
-  precision.
+  precision, for any model module.
 """
 from __future__ import annotations
 
+import json
 import zlib
 
 import numpy as np
@@ -174,30 +176,28 @@ def split_counts(s, r, D):
 
 
 # --------------------------------------------------------------------------
-# models and local SGD
+# local SGD over a model found by name
 # --------------------------------------------------------------------------
-
-# name -> (shape, fan-in) of a gaussian leaf, or (shape, None) for zeros
-MODEL_LEAVES = {
-    "mlp": {"w1": ((784, 200), 784), "b1": ((200,), None),
-            "w2": ((200, 10), 200), "b2": ((10,), None)},
-    "cnn": {"c1": ((5, 5, 1, 16), 25), "cb1": ((16,), None),
-            "c2": ((5, 5, 16, 32), 400), "cb2": ((32,), None),
-            "w1": ((1568, 128), 1568), "b1": ((128,), None),
-            "w2": ((128, 10), 128), "b2": ((10,), None)},
-}
+#
+# A model is a module ``bench/models/<name>.py`` (``run.load_plugin``) with
+# ``dataset(config)``, ``program_model(config)``, ``init(config, seed)``,
+# ``loss(config, p, x, y, w, precision)``, ``test_loss(config, p, x_te,
+# y_te, precision)``, ``forward_flops(config)`` and, optionally,
+# ``REF_DEVICE_BLOCK``: how many devices' batches one step of the
+# reference takes at once (all ``n`` where it is absent).
 
 
-def init_model(model: str, seed: int):
-    """Initial weights from the job's seed: N(0, 1/fan_in) per gaussian
-    leaf with the key folded by the CRC-32 of the leaf's path, zeros for
+def gaussian_leaves(leaves: dict, seed: int):
+    """Initial weights of ``leaves`` (name -> (shape, fan-in), or (shape,
+    None) for zeros) from the job's seed: N(0, 1/fan_in) per gaussian leaf
+    with the key folded by the CRC-32 of the leaf's path, zeros for
     biases."""
     import jax
     import jax.numpy as jnp
 
     key = jax.random.PRNGKey(seed)
     out = {}
-    for name, (shape, fan_in) in MODEL_LEAVES[model].items():
+    for name, (shape, fan_in) in leaves.items():
         if fan_in is None:
             out[name] = jnp.zeros(shape, jnp.float32)
             continue
@@ -208,68 +208,75 @@ def init_model(model: str, seed: int):
     return out
 
 
-def _apply(model, p, x, precision):
+def weighted_xent(logits, y, w):
+    """Cross-entropy of one batch, weighted by ``w``: log-probabilities in
+    the logits' dtype, the batch mean in float32."""
     import jax
     import jax.numpy as jnp
 
-    if model == "mlp":
-        h = jnp.dot(x.reshape(x.shape[0], -1), p["w1"],
-                    precision=precision) + p["b1"]
-        h = jnp.maximum(h, 0)
-        return jnp.dot(h, p["w2"], precision=precision) + p["b2"]
-
-    def conv(h, w, b):
-        y = jax.lax.conv_general_dilated(
-            h, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=precision)
-        return jnp.maximum(y + b, 0)
-
-    def pool(h):
-        B, H, W, C = h.shape
-        return h.reshape(B, H // 2, 2, W // 2, 2, C).max(axis=(2, 4))
-
-    h = pool(conv(x[..., None], p["c1"], p["cb1"]))
-    h = pool(conv(h, p["c2"], p["cb2"]))
-    h = h.reshape(h.shape[0], -1)
-    h = jnp.maximum(jnp.dot(h, p["w1"], precision=precision) + p["b1"], 0)
-    return jnp.dot(h, p["w2"], precision=precision) + p["b2"]
-
-
-def _loss(model, p, x, y, w, precision):
-    import jax
-    import jax.numpy as jnp
-
-    # log-probabilities in the weights' dtype; the batch mean in float32
-    logp = jax.nn.log_softmax(_apply(model, p, x, precision))
+    logp = jax.nn.log_softmax(logits)
     ll = jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0]
     return -(ll.astype(jnp.float32) * w).sum() / jnp.maximum(w.sum(), 1.0)
 
 
-def first_window(model: str, seed: int, eta: float, x_tr, y_tr, x_te, y_te,
-                 rounds, *, precision, pad=8, dtype="float32", fault=None):
+def blocked_xent(logits_fn, x, y, block: int):
+    """Mean cross-entropy of a test set, ``block`` rows at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    xs = x.reshape(-1, block, *x.shape[1:])
+    ys = y.reshape(-1, block, *y.shape[1:])
+
+    def blk(_, xy):
+        logp = jax.nn.log_softmax(logits_fn(xy[0]))
+        ll = jnp.take_along_axis(logp, xy[1][:, None], axis=1)[:, 0]
+        return None, -ll.astype(jnp.float32).sum()
+
+    _, s = jax.lax.scan(blk, None, (xs, ys))
+    return s.sum() / y.shape[0]
+
+
+def _as(a, dt):
+    """An input on the device: floating arrays (pixels) in ``dt``,
+    integer ones (token ids) as they are."""
+    import jax.numpy as jnp
+
+    a = np.asarray(a)
+    return jnp.asarray(a, dt) if np.issubdtype(a.dtype, np.floating) \
+        else jnp.asarray(a)
+
+
+def first_window(model, config: dict, seed: int, data, rounds, *,
+                 precision, dtype="float32", fault=None):
     """Local SGD of every device over ``rounds`` (list of τ + 1 (n,) lists
     of sample-id arrays: the processed cells of the first window and of
     the round after it), eq. (4) over the devices that held data after the
     first τ, the test loss of the aggregate, then round τ from the
     aggregate on every device.
 
-    Returns (losses (τ + 1, n) float64, test_loss float). ``dtype`` is the
-    dtype of the weights, the pixels and every activation; ``precision``
-    that of every matmul and convolution, as ``jax.lax.Precision`` names
-    it ("default": one bfloat16 pass on the TPU). ``fault``
-    plants one of the faults the comparison must catch: "unchanged" (the
-    step returns the weights it got), "half_batch" (the second half of
-    every device's batch is left out), "no_broadcast" (the devices keep
-    their own models after the aggregation).
+    ``model`` is the configuration's model module, ``data`` its
+    ``(x_tr, y_tr, x_te, y_te)``; the step size is ``config["eta"]``, the
+    batch is padded to ``config["max_points"]``. Returns (losses (τ + 1,
+    n) float64, test_loss float). ``dtype`` is the dtype of the weights,
+    the floating inputs and every activation; ``precision`` that of every
+    matmul and convolution, as ``jax.lax.Precision`` names it ("default":
+    one bfloat16 pass on the TPU). ``fault`` plants one of the faults the
+    comparison must catch: "unchanged" (the step returns the weights it
+    got), "half_batch" (the second half of every device's batch is left
+    out), "no_broadcast" (the devices keep their own models after the
+    aggregation).
     """
     import jax
     import jax.numpy as jnp
 
+    x_tr, y_tr, x_te, y_te = data
     dt = jnp.dtype(dtype)
     n = len(rounds[0])
+    block = int(getattr(model, "REF_DEVICE_BLOCK", n))
     # one batch shape for every job of a cell: the cell's pad size, unless
     # a job holds more
-    P = max(pad, max(len(ix) for row in rounds for ix in row))
+    P = max(int(config["max_points"]),
+            max(len(ix) for row in rounds for ix in row))
     P = -(-P // 8) * 8
 
     def stage(row):
@@ -283,21 +290,30 @@ def first_window(model: str, seed: int, eta: float, x_tr, y_tr, x_te, y_te,
                 k_used = k
             idx[i, :k] = ix
             w[i, :k_used] = 1.0
-        return (jnp.asarray(x_tr[idx], dt), jnp.asarray(y_tr[idx]),
-                jnp.asarray(w))
+        return _as(x_tr[idx], dt), jnp.asarray(y_tr[idx]), jnp.asarray(w)
 
-    w0 = {k: v.astype(dt) for k, v in init_model(model, seed).items()}
+    w0 = jax.tree_util.tree_map(lambda a: a.astype(dt),
+                                model.init(config, seed))
 
     def stack(w):
         return jax.tree_util.tree_map(
             lambda a: jnp.broadcast_to(a, (n,) + a.shape), w)
 
+    step = _step_program(model, config, precision, fault == "unchanged")
+
+    def step_all(W, x, y, w):
+        if block >= n:
+            return step(W, x, y, w)
+        outs = [step(*jax.tree_util.tree_map(lambda a: a[s:s + block],
+                                             (W, x, y, w)))
+                for s in range(0, n, block)]
+        return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
+
     W = stack(w0)
-    step = _step_program(model, float(eta), precision, fault == "unchanged")
     H = np.zeros(n)
     losses = []
     for row in rounds[:-1]:
-        W, loss = step(W, *stage(row))
+        W, loss = step_all(W, *stage(row))
         losses.append(np.asarray(loss, np.float64))
         # H counts what each device processed, whatever a fault dropped
         H += np.array([len(ix) for ix in row], np.float64)
@@ -306,55 +322,51 @@ def first_window(model: str, seed: int, eta: float, x_tr, y_tr, x_te, y_te,
     wg = jax.tree_util.tree_map(
         lambda a: (jnp.einsum("n...,n->...", a.astype(jnp.float32), Hj,
                               precision=precision) / tot).astype(dt), W)
-    test_loss = _eval_program(model, precision)(
-        wg, jnp.asarray(x_te, dt), jnp.asarray(y_te))
+    test_loss = _test_program(model, config, precision)(
+        wg, _as(x_te, dt), jnp.asarray(y_te))
     if fault != "no_broadcast":
         W = stack(wg)
-    _, loss = step(W, *stage(rounds[-1]))
+    _, loss = step_all(W, *stage(rounds[-1]))
     losses.append(np.asarray(loss, np.float64))
     return np.stack(losses), float(test_loss)
 
 
+# compiled programs by (kind, model module, configuration, ...); each
+# entry holds its module, so that no other module takes its id
 _PROGRAMS: dict = {}
 
 
-def _step_program(model, eta, precision, unchanged):
+def _program(kind, model, config, rest, make):
+    key = (kind, id(model), json.dumps(config, sort_keys=True, default=repr),
+           *rest)
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = (model, make())
+    return _PROGRAMS[key][1]
+
+
+def _step_program(model, config, precision, unchanged):
     import jax
     import jax.numpy as jnp
 
-    key = ("step", model, eta, precision, unchanged)
-    if key not in _PROGRAMS:
+    eta = float(config["eta"])
+
+    def make():
         def one(p, x, y, w):
             loss, g = jax.value_and_grad(
-                lambda q: _loss(model, q, x, y, w, precision))(p)
+                lambda q: model.loss(config, q, x, y, w, precision))(p)
             if unchanged:
                 return p, loss
             scale = jnp.minimum(w.sum(), 1.0)
             return jax.tree_util.tree_map(
                 lambda a, b: (a - eta * scale * b).astype(a.dtype), p, g), loss
 
-        _PROGRAMS[key] = jax.jit(jax.vmap(one))
-    return _PROGRAMS[key]
+        return jax.jit(jax.vmap(one))
+
+    return _program("step", model, config, (precision, unchanged), make)
 
 
-def _eval_program(model, precision):
+def _test_program(model, config, precision):
     import jax
-    import jax.numpy as jnp
 
-    key = ("eval", model, precision)
-    if key not in _PROGRAMS:
-        def ev(p, x, y):
-            # blocks of 1,000 test images keep the activations small
-            xs = x.reshape(-1, 1000, *x.shape[1:])
-            ys = y.reshape(-1, 1000)
-
-            def blk(_, xy):
-                logp = jax.nn.log_softmax(_apply(model, p, xy[0], precision))
-                ll = jnp.take_along_axis(logp, xy[1][:, None], axis=1)[:, 0]
-                return None, -ll.astype(jnp.float32).sum()
-
-            _, s = jax.lax.scan(blk, None, (xs, ys))
-            return s.sum() / y.shape[0]
-
-        _PROGRAMS[key] = jax.jit(ev)
-    return _PROGRAMS[key]
+    return _program("test", model, config, (precision,), lambda: jax.jit(
+        lambda p, x, y: model.test_loss(config, p, x, y, precision)))

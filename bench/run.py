@@ -4,11 +4,13 @@
 
 Run from the root of a checkout on a machine with a TPU. Everything a
 cell is made of is found by name: the cell in ``BENCHMARK.json``, its
-configuration in ``bench/configs/<config>.json``, its traffic mix in
+configuration in ``bench/configs/<config>.json``, the configuration's
+model (its data, plain reference, FLOP count and the program's model
+argument) in ``bench/models/<model>.py``, its traffic mix in
 ``bench/traffic/<traffic>.json``, the limits of its correctness check in
 ``bench/limits/<cell>.json`` and each metric's reader in
-``bench/metrics/<metric>.py``. Adding a cell, a traffic mix or a metric
-is adding files and entries, never editing this harness.
+``bench/metrics/<metric>.py``. Adding a cell, a model, a traffic mix or
+a metric is adding files and entries, never editing this harness.
 
 A run: set-up (dataset, network, one warm-up job at the cell's shapes,
 which compiles or loads from the compile cache everything the window
@@ -61,9 +63,9 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     wl = cells[name]
     bench = os.path.join(root, "bench")
-    return {"spec": spec, "workload": wl,
-            "config": load_json(os.path.join(bench, "configs",
-                                             wl["config"] + ".json")),
+    config = load_json(os.path.join(bench, "configs", wl["config"] + ".json"))
+    return {"spec": spec, "workload": wl, "config": config,
+            "model": load_plugin("models", config["model"], root),
             "traffic": load_json(os.path.join(bench, "traffic",
                                               wl["traffic"] + ".json")),
             "limits": load_json(os.path.join(bench, "limits",
@@ -83,13 +85,21 @@ def cell_metrics(spec: dict, name: str, trace: bool) -> list:
                 else m["moves"] in moved)]
 
 
-def load_reader(metric: str, root: str = ROOT):
-    path = os.path.join(root, "bench", "metrics", metric + ".py")
+def load_plugin(kind: str, name: str, root: str = ROOT):
+    """The module ``bench/<kind>/<name>.py``, loaded by its path; an
+    unknown name is an error that lists the known ones."""
+    folder = os.path.join(root, "bench", kind)
+    path = os.path.join(folder, name + ".py")
+    if not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(folder) if f.endswith(".py"))
+        raise SystemExit(f"bench: no {kind} module {name!r} in {folder}; "
+                         f"known: {known}")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
 
 
 class Spans:
@@ -134,9 +144,11 @@ class System:
     settings, ``with_capacity`` and ``movement.repair_capacities``, as
     ``launch.train.solve_setting`` composes them), the host data plane
     (``federated._prepare_streams``) and the engine
-    (``federated.run_network_aware`` on the scan engine)."""
+    (``federated.run_network_aware`` on the scan engine). ``model`` is
+    the configuration's model module, found by its name in this checkout
+    where it is not given."""
 
-    def __init__(self, config: dict, data):
+    def __init__(self, config: dict, data, model=None):
         import numpy as np
 
         from repro.core import federated as F
@@ -147,6 +159,8 @@ class System:
         self.F, self.mv, self.pl = F, mv, pl
         self.CostTraces, self.with_capacity = CostTraces, with_capacity
         self.config, self.data = config, data
+        model = model or load_plugin("models", config["model"])
+        self.program_model = model.program_model(config)
         n = int(config["n"])
         if config["topology"] != "full":
             raise ValueError(f"topology {config['topology']!r}: only the "
@@ -166,7 +180,7 @@ class System:
         streams = self.pl.FogStreams(collected=call.cells, n=n, T=T)
         cfg = self.F.FedConfig(
             n=n, T=T, tau=int(self.config["tau"]),
-            eta=float(self.config["eta"]), model=self.config["model"],
+            eta=float(self.config["eta"]), model=self.program_model,
             seed=call.seed, max_points=int(self.config["max_points"]))
         return traces, streams, cfg
 
@@ -221,8 +235,10 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT) -> dict:
     args = ap.parse_args(argv)
     cell = load_cell(args.workload, root)
     wl, config, traffic = cell["workload"], cell["config"], cell["traffic"]
+    model = cell["model"]
     metrics = cell_metrics(cell["spec"], args.workload, bool(args.trace))
-    readers = {m["name"]: load_reader(m["name"], root) for m in metrics}
+    readers = {m["name"]: load_plugin("metrics", m["name"], root).read
+               for m in metrics}
 
     if os.path.join(root, "src") not in sys.path:
         sys.path.insert(0, os.path.join(root, "src"))
@@ -240,10 +256,9 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT) -> dict:
 
     import check
 
-    data = gen.image_dataset(int(config["n_train"]), int(config["n_test"]),
-                             int(config["data_seed"]))
+    data = model.dataset(config)
     traffic_gen = gen.Traffic(config, traffic)
-    sut = System(config, data)
+    sut = System(config, data, model)
     spans = Spans(annotate=bool(args.trace))
     outs = {}
     # warm-up: one call at the cell's own shapes
@@ -295,7 +310,8 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT) -> dict:
 
     device["peaks"] = flops.peaks(device["kind"], os.path.join(
         root, "bench", "peaks.json"))
-    run = {"workload": args.workload, "config": config, "traffic": traffic,
+    run = {"workload": args.workload, "config": config, "model": model,
+           "traffic": traffic,
            "setup_s": setup_s, "window_s": window_s, "calls": calls,
            "device": device, "chips": len(devs), "trace": red,
            "compiles_in_window": compiles}
@@ -315,7 +331,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT) -> dict:
     for idx in pick:
         call = traffic_gen.call(args.seed, int(idx))
         per_call.append(check.call_numbers(call, outs[int(idx)], config,
-                                           sut.adj, data))
+                                           model, sut.adj, data))
     correct, failed, rows = check.judge(per_call, cell["limits"])
     device.pop("peaks")
     result = {"correct": correct, "attempted": k, "failed": failed,

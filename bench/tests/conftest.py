@@ -43,6 +43,25 @@ def add_cell(root, name, config, traffic, like):
                 os.path.join(root, "bench", "limits", name + ".json"))
 
 
+def add_config(root, name, like, **over):
+    """Add a configuration by a file and its BENCHMARK.json entry: the
+    configuration ``like`` with the keys ``over`` changed."""
+    bench = os.path.join(root, "bench")
+    with open(os.path.join(bench, "configs", like + ".json")) as f:
+        cfg = json.load(f)
+    cfg.update(over)
+    with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
+        json.dump(cfg, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": name, "source": "test",
+                            "file": f"bench/configs/{name}.json",
+                            "reduced": [], "why": "test"})
+    with open(path, "w") as f:
+        json.dump(spec, f)
+
+
 @pytest.fixture(scope="session")
 def mini_root(tmp_path_factory):
     root = str(tmp_path_factory.mktemp("checkout"))
@@ -57,20 +76,8 @@ def mini_root(tmp_path_factory):
                              "hbm_bytes": 1e9}
     with open(peaks, "w") as f:
         json.dump(table, f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        spec = json.load(f)
     for name, (base, over) in TINY.items():
-        with open(os.path.join(root, "bench", "configs", base + ".json")) as f:
-            cfg = json.load(f)
-        cfg.update(over)
-        with open(os.path.join(root, "bench", "configs", name + ".json"),
-                  "w") as f:
-            json.dump(cfg, f)
-        spec["configs"].append({"name": name, "source": "test",
-                                "file": f"bench/configs/{name}.json",
-                                "reduced": [], "why": "test"})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(spec, f)
+        add_config(root, name, base, **over)
     for name, like in CELLS.items():
         config, traffic = name.split(".")
         add_cell(root, name, config, traffic, like)

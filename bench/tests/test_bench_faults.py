@@ -15,6 +15,7 @@ import check
 import gen
 import reference as ref
 from conftest import ROOT
+from run import load_plugin
 
 
 def _limits(cell):
@@ -58,15 +59,14 @@ def test_bf16_control_of_a_job_fails_one_of_its_numbers(cell):
     trained in bfloat16 against the reference."""
     config, call = _cell(cell)
     tau = int(config["tau"])
-    data = gen.image_dataset(int(config["n_train"]), int(config["n_test"]),
-                             int(config["data_seed"]))
+    model = load_plugin("models", config["model"])
+    data = model.dataset(config)
     rounds = call.cells[:tau + 1]
-    mo = ref.first_window(config["model"], call.seed, float(config["eta"]),
-                          *data, rounds, pad=int(config["max_points"]),
+    mo = ref.first_window(model, config, call.seed, data, rounds,
                           dtype="bfloat16",
                           precision=config["matmul_precision"])
     nums = check.training_numbers(call, {"processed": call.cells}, config,
-                                  data, model_out=mo)
+                                  model, data, model_out=mo)
     lim = _limits(cell)
     assert any(nums[k] > lim[k] for k in nums), nums
 

@@ -1,16 +1,18 @@
 """The harness end to end on tiny cells on the CPU, the contract's shape
-of BENCHMARK.json, and cells, traffic and metrics added by files alone."""
+of BENCHMARK.json, and cells, models, traffic and metrics added by files
+alone."""
 from __future__ import annotations
 
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 
 import pytest
 
-from conftest import ROOT, add_cell
+from conftest import ROOT, add_cell, add_config
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -98,6 +100,35 @@ def test_files_alone_add_a_traffic_mix_a_cell_and_a_metric(
     assert res["metrics"]["jobs_in_window"]["value"] == res["attempted"]
 
 
+def test_files_alone_add_a_model(mini_root, run_cell):
+    # the program's linear model: a model module, a configuration, a cell
+    # and its limits, and no harness file edited
+    shutil.copy(os.path.join(ROOT, "bench", "tests", "fixtures", "linear.py"),
+                os.path.join(mini_root, "bench", "models", "linear.py"))
+    add_config(mini_root, "tiny_linear", "tiny_mlp", model="linear")
+    add_cell(mini_root, "tiny_linear.epoch", "tiny_linear", "epoch",
+             "paper_mlp_n10.epoch")
+    res = run_cell("tiny_linear.epoch")
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] >= 1
+    assert {"loss_gap", "test_loss_gap"} <= set(res["checks"])
+    res = run_cell("tiny_linear.epoch", trace=1)
+    assert res["correct"] and res["metrics"]["train_mfu"]["value"] > 0
+
+
+def test_an_unknown_model_exits_non_zero_naming_the_known_ones(mini_root):
+    add_config(mini_root, "tiny_nomodel", "tiny_mlp", model="no_such_model")
+    add_cell(mini_root, "tiny_nomodel.epoch", "tiny_nomodel", "epoch",
+             "paper_mlp_n10.epoch")
+    p = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "tiny_nomodel.epoch",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=mini_root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0 and '"correct"' not in p.stdout
+    assert "no_such_model" in p.stderr
+    assert "'cnn'" in p.stderr and "'mlp'" in p.stderr
+
+
 def test_the_command_refuses_to_report_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -111,8 +142,6 @@ def test_the_command_refuses_to_report_without_a_tpu():
 
 def test_the_command_needs_the_program_beside_it(tmp_path):
     # a checkout of the benchmark alone holds no system to measure
-    import shutil
-
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
