@@ -183,8 +183,13 @@ def split_counts(s, r, D):
 # ``dataset(config)``, ``program_model(config)``, ``init(config, seed)``,
 # ``loss(config, p, x, y, w, precision)``, ``test_loss(config, p, x_te,
 # y_te, precision)``, ``forward_flops(config)`` and, optionally,
-# ``REF_DEVICE_BLOCK``: how many devices' batches one step of the
-# reference takes at once (all ``n`` where it is absent).
+# ``REF_DEVICE_BLOCK``: how many devices' weights the reference holds at
+# once (all ``n`` where it is absent). With a block b < n the first window
+# is walked device-major, b devices at a time, each block padded to its own
+# busiest cell: the weights held are at most the initial ones, a float32
+# accumulator of eq. (4) and one block's weights before and after a step,
+# (2 + 2b) |θ| in float32, plus the step's own temporaries; no array of n
+# copies of the weights is ever made.
 
 
 def gaussian_leaves(leaves: dict, seed: int):
@@ -255,33 +260,33 @@ def first_window(model, config: dict, seed: int, data, rounds, *,
     aggregate on every device.
 
     ``model`` is the configuration's model module, ``data`` its
-    ``(x_tr, y_tr, x_te, y_te)``; the step size is ``config["eta"]``, the
-    batch is padded to ``config["max_points"]``. Returns (losses (τ + 1,
-    n) float64, test_loss float). ``dtype`` is the dtype of the weights,
-    the floating inputs and every activation; ``precision`` that of every
-    matmul and convolution, as ``jax.lax.Precision`` names it ("default":
-    one bfloat16 pass on the TPU). ``fault`` plants one of the faults the
-    comparison must catch: "unchanged" (the step returns the weights it
-    got), "half_batch" (the second half of every device's batch is left
-    out), "no_broadcast" (the devices keep their own models after the
-    aggregation).
+    ``(x_tr, y_tr, x_te, y_te)``; the step size is ``config["eta"]``. With
+    one block of devices (no ``REF_DEVICE_BLOCK``, or one of n or more) all
+    n devices step at once on batches padded to ``config["max_points"]``;
+    with blocks of b < n devices each block walks the window on its own,
+    its batch padded to its busiest cell of the round (a power of two, at
+    least 8). Returns (losses (τ + 1, n) float64, test_loss float).
+    ``dtype`` is the dtype of the weights, the floating inputs and every
+    activation; ``precision`` that of every matmul and convolution, as
+    ``jax.lax.Precision`` names it ("default": one bfloat16 pass on the
+    TPU). ``fault`` plants one of the faults the comparison must catch:
+    "unchanged" (the step returns the weights it got), "half_batch" (the
+    second half of every device's batch is left out), "no_broadcast" (the
+    devices keep their own models after the aggregation).
     """
     import jax
     import jax.numpy as jnp
 
+    tree_map = jax.tree_util.tree_map
     x_tr, y_tr, x_te, y_te = data
     dt = jnp.dtype(dtype)
     n = len(rounds[0])
+    tau = len(rounds) - 1
     block = int(getattr(model, "REF_DEVICE_BLOCK", n))
-    # one batch shape for every job of a cell: the cell's pad size, unless
-    # a job holds more
-    P = max(int(config["max_points"]),
-            max(len(ix) for row in rounds for ix in row))
-    P = -(-P // 8) * 8
 
-    def stage(row):
-        idx = np.zeros((n, P), np.int64)
-        w = np.zeros((n, P), np.float32)
+    def stage(row, P):
+        idx = np.zeros((len(row), P), np.int64)
+        w = np.zeros((len(row), P), np.float32)
         for i, ix in enumerate(row):
             k = len(ix)
             if fault == "half_batch":
@@ -292,43 +297,78 @@ def first_window(model, config: dict, seed: int, data, rounds, *,
             w[i, :k_used] = 1.0
         return _as(x_tr[idx], dt), jnp.asarray(y_tr[idx]), jnp.asarray(w)
 
-    w0 = jax.tree_util.tree_map(lambda a: a.astype(dt),
-                                model.init(config, seed))
+    w0 = tree_map(lambda a: a.astype(dt), model.init(config, seed))
 
-    def stack(w):
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (n,) + a.shape), w)
+    def stack(w, m):
+        return tree_map(lambda a: jnp.broadcast_to(a, (m,) + a.shape), w)
 
     step = _step_program(model, config, precision, fault == "unchanged")
-
-    def step_all(W, x, y, w):
-        if block >= n:
-            return step(W, x, y, w)
-        outs = [step(*jax.tree_util.tree_map(lambda a: a[s:s + block],
-                                             (W, x, y, w)))
-                for s in range(0, n, block)]
-        return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
-
-    W = stack(w0)
-    H = np.zeros(n)
-    losses = []
-    for row in rounds[:-1]:
-        W, loss = step_all(W, *stage(row))
-        losses.append(np.asarray(loss, np.float64))
-        # H counts what each device processed, whatever a fault dropped
-        H += np.array([len(ix) for ix in row], np.float64)
+    test = _test_program(model, config, precision)
+    # H counts what each device processed, whatever a fault dropped
+    H = np.array([[len(ix) for ix in row] for row in rounds[:-1]],
+                 np.float64).sum(axis=0)
     tot = H.sum()
     Hj = jnp.asarray(H, jnp.float32)
-    wg = jax.tree_util.tree_map(
-        lambda a: (jnp.einsum("n...,n->...", a.astype(jnp.float32), Hj,
-                              precision=precision) / tot).astype(dt), W)
-    test_loss = _test_program(model, config, precision)(
-        wg, _as(x_te, dt), jnp.asarray(y_te))
+
+    def weigh(a, Hb):
+        return jnp.einsum("n...,n->...", a.astype(jnp.float32), Hb,
+                          precision=precision)
+
+    if block >= n:
+        # one batch shape for every job of a cell: the cell's pad size,
+        # unless a job holds more
+        P = max(int(config["max_points"]),
+                max(len(ix) for row in rounds for ix in row))
+        P = -(-P // 8) * 8
+        W = stack(w0, n)
+        losses = []
+        for row in rounds[:-1]:
+            W, loss = step(W, *stage(row, P))
+            losses.append(np.asarray(loss, np.float64))
+        wg = tree_map(lambda a: (weigh(a, Hj) / tot).astype(dt), W)
+        test_loss = test(wg, _as(x_te, dt), jnp.asarray(y_te))
+        if fault != "no_broadcast":
+            W = stack(wg, n)
+        _, loss = step(W, *stage(rounds[-1], P))
+        losses.append(np.asarray(loss, np.float64))
+        return np.stack(losses), float(test_loss)
+
+    # devices are independent between aggregations, so the window is walked
+    # device-major and only one block's weights are ever held
+    losses = np.zeros((tau + 1, n))
+    spans = [(lo, min(lo + block, n)) for lo in range(0, n, block)]
+
+    def train(W, t, lo, hi):
+        row = rounds[t][lo:hi]
+        P = max(8, 1 << (max(len(ix) for ix in row) - 1).bit_length())
+        W, loss = step(W, *stage(row, P))
+        losses[t, lo:hi] = np.asarray(loss, np.float64)
+        return W
+
+    leaves, treedef = jax.tree_util.tree_flatten(w0)
+    acc = [jnp.zeros(a.shape, jnp.float32) for a in leaves]
+
+    def add(W, Hb):
+        # eq. (4) summed leaf by leaf, so that one leaf at a time is doubled
+        for i, a in enumerate(jax.tree_util.tree_leaves(W)):
+            acc[i] = acc[i] + weigh(a, Hb)
+
+    for lo, hi in spans:
+        W = stack(w0, hi - lo)
+        for t in range(tau):
+            W = train(W, t, lo, hi)
+        add(W, Hj[lo:hi])
+        if fault == "no_broadcast":
+            train(W, tau, lo, hi)
+        del W
+    wg = jax.tree_util.tree_unflatten(
+        treedef, [(a / tot).astype(dt) for a in acc])
+    del acc
+    test_loss = test(wg, _as(x_te, dt), jnp.asarray(y_te))
     if fault != "no_broadcast":
-        W = stack(wg)
-    _, loss = step_all(W, *stage(rounds[-1]))
-    losses.append(np.asarray(loss, np.float64))
-    return np.stack(losses), float(test_loss)
+        for lo, hi in spans:
+            train(stack(wg, hi - lo), tau, lo, hi)
+    return losses, float(test_loss)
 
 
 # compiled programs by (kind, model module, configuration, ...); each

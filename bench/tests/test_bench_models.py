@@ -12,6 +12,7 @@ where they are a property of the code alone.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -105,17 +106,24 @@ def test_image_dataset_reads_as_before(readings, parent):
 # the generic reference on a model of integer token rows
 # --------------------------------------------------------------------------
 
-TOKENS = {"eta": 0.5, "max_points": 8, "n_train": 120, "n_test": 40,
+TOKENS = {"eta": 0.5, "max_points": 8, "n_train": 300, "n_test": 40,
           "data_seed": 9, "vocab": 50, "seq": 12, "width": 16, "classes": 5}
+LINEAR = {"eta": 0.1, "max_points": 16, "n_train": 300, "n_test": 1000,
+          "data_seed": 3}
+# a few MB of weights: its leaves' shapes are held by nothing else
+WIDE = {"eta": 0.05, "max_points": 8, "n_train": 400, "n_test": 40,
+        "data_seed": 4, "vocab": 1000, "seq": 6, "width": 256, "depth": 4,
+        "classes": 5}
+CONFIGS = {"tokens": TOKENS, "linear": LINEAR, "wide": WIDE}
 
 
-def _tokens(**over):
-    """The token fixture as a fresh module object, with ``over`` set on
-    it."""
+def _fixture(name, **over):
+    """The fixture ``fixtures/<name>.py`` as a fresh module object, with
+    ``over`` set on it."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
-        "bench_fixture_tokens", os.path.join(HERE, "fixtures", "tokens.py"))
+        f"bench_fixture_{name}", os.path.join(HERE, "fixtures", f"{name}.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     for k, v in over.items():
@@ -123,10 +131,13 @@ def _tokens(**over):
     return mod
 
 
-def _token_rounds(n=4, tau=2):
+def _token_rounds(n=4, tau=2, empty=False):
+    """τ + 1 rounds of n devices' distinct sample ids, 1 to 8 a cell (0 to
+    8 with ``empty``)."""
     rng = np.random.default_rng(1)
-    ids = rng.permutation(TOKENS["n_train"])
-    sizes = rng.integers(1, 9, (tau + 1, n))
+    ids = rng.permutation(300)
+    sizes = rng.integers(0 if empty else 1, 9, (tau + 1, n))
+    assert sizes.sum() <= ids.size
     cuts = np.cumsum(sizes.reshape(-1))[:-1]
     flat = np.split(ids[:sizes.sum()], cuts)
     return [flat[t * n:(t + 1) * n] for t in range(tau + 1)]
@@ -136,13 +147,13 @@ def test_token_rows_reach_the_loss_as_integers():
     import reference as ref
 
     seen = []
-    plain = _tokens().loss
+    plain = _fixture("tokens").loss
 
     def loss(config, p, x, y, w, precision):
         seen.append(x.dtype)
         return plain(config, p, x, y, w, precision)
 
-    model = _tokens(loss=loss)
+    model = _fixture("tokens", loss=loss)
     data = model.dataset(TOKENS)
     assert data[0].dtype == np.int32
     ref.first_window(model, TOKENS, 3, data, _token_rounds(),
@@ -150,18 +161,105 @@ def test_token_rows_reach_the_loss_as_integers():
     assert seen and all(d == np.int32 for d in seen)
 
 
-def test_devices_stepped_in_blocks_read_as_all_at_once():
+N_DEVICES = 10
+
+
+@functools.lru_cache(maxsize=None)
+def _one_block(name, fault, dtype):
+    """The first window of ten devices stepped all at once."""
     import reference as ref
 
-    data = _tokens().dataset(TOKENS)
-    whole = ref.first_window(_tokens(), TOKENS, 3, data, _token_rounds(),
-                             precision="highest")
-    blocks = ref.first_window(_tokens(REF_DEVICE_BLOCK=1), TOKENS, 3, data,
-                              _token_rounds(), precision="highest")
+    model = _fixture(name)
+    return ref.first_window(model, CONFIGS[name], 3,
+                            model.dataset(CONFIGS[name]),
+                            _token_rounds(N_DEVICES, empty=True),
+                            precision="highest", dtype=dtype, fault=fault)
+
+
+# A block's step is another program than the ten devices' step, on batches
+# of another pad, and eq. (4) adds the blocks in another order. In float32
+# that moves a loss by a float32 step or two (2.1e-7 at most on an x86
+# CPU); in bfloat16 it read no gap there, but a sum that rounds the other
+# way moves a bfloat16 log-probability, and so a loss, by one bfloat16 step
+# (2^-8 relative).
+RTOL = {"float32": 1e-6, "bfloat16": 2.0 ** -8}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.parametrize("block", [1, 3, N_DEVICES])
+@pytest.mark.parametrize("name", ["tokens", "linear"])
+def test_devices_stepped_in_blocks_read_as_all_at_once(name, block, fault,
+                                                       dtype):
+    """Blocks of 1 and 3 devices (3 does not divide 10) walk the window
+    device-major and read as the ten devices stepped at once; a block of
+    all ten is the same program."""
+    import reference as ref
+
+    whole = _one_block(name, fault, dtype)
+    model = _fixture(name, REF_DEVICE_BLOCK=block)
+    blocks = ref.first_window(model, CONFIGS[name], 3,
+                              model.dataset(CONFIGS[name]),
+                              _token_rounds(N_DEVICES, empty=True),
+                              precision="highest", dtype=dtype, fault=fault)
     # the losses move over the window, so the blocks did train
     assert np.ptp(whole[0]) > 0
-    np.testing.assert_allclose(blocks[0], whole[0], rtol=1e-6)
-    assert blocks[1] == pytest.approx(whole[1], rel=1e-6)
+    if block == N_DEVICES:
+        np.testing.assert_array_equal(blocks[0], whole[0])
+        assert blocks[1] == whole[1]
+        return
+    np.testing.assert_allclose(blocks[0], whole[0], rtol=RTOL[dtype])
+    assert blocks[1] == pytest.approx(whole[1], rel=RTOL[dtype])
+
+
+@pytest.mark.parametrize("block", [1, 3])
+def test_blocked_reference_never_holds_n_copies_of_the_weights(monkeypatch,
+                                                                block):
+    """Ten devices in blocks of b: sampled around every step and the test
+    loss, no live array holds ten copies of a weight leaf, and the live
+    weights (the initial ones, the eq. (4) accumulator, a block's weights
+    before and after a step) never pass (2 + 2b) |θ|."""
+    import jax
+
+    import reference as ref
+
+    model = _fixture("wide", REF_DEVICE_BLOCK=block)
+    leaves = [s for s, _ in model.leaves(WIDE).values()]
+    shapes = set(leaves)
+    theta = sum(4 * int(np.prod(s)) for s in leaves)
+    samples = []
+
+    def sample():
+        stacked, held = [], 0
+        for a in jax.live_arrays():
+            if a.shape[:1] == (N_DEVICES,) and a.shape[1:] in shapes:
+                stacked.append(a.shape)
+            if a.shape in shapes or (a.ndim and a.shape[0] <= block
+                                     and a.shape[1:] in shapes):
+                held += a.nbytes
+        samples.append((stacked, held))
+
+    def watched(make):
+        def program(*args):
+            run = make(*args)
+
+            def call(*xs):
+                sample()
+                out = run(*xs)
+                sample()
+                return out
+            return call
+        return program
+
+    monkeypatch.setattr(ref, "_step_program", watched(ref._step_program))
+    monkeypatch.setattr(ref, "_test_program", watched(ref._test_program))
+    for fault in (None, "no_broadcast"):
+        ref.first_window(model, WIDE, 5, model.dataset(WIDE),
+                         _token_rounds(N_DEVICES, tau=3),
+                         precision="highest", fault=fault)
+    assert samples
+    assert [s for s, _ in samples if s] == []
+    assert max(h for _, h in samples) <= (2 + 2 * block) * theta
 
 
 if __name__ == "__main__":
