@@ -7,9 +7,10 @@ the whole horizon is one device-resident program:
 * the padded sample stream is staged once as ``(T, n, P)`` index /
   label / weight arrays, or as packed ``(T, R, C)`` chunk rows where
   those execute fewer slots (``_stage_scan``, :func:`ragged_step`)
-  (indices gathered on host, pixels gathered on device — either up
-  front when the pixel tensor fits ``PRESTAGE_LIMIT_BYTES``, or
-  per-round inside the scan body);
+  (indices gathered on host, pixels gathered on device as rows of the
+  ``(N, features)`` view of the training set — either up front when
+  the pixel tensor fits ``PRESTAGE_LIMIT_BYTES``, or per-round inside
+  the scan body — and reshaped to images per round);
 * the vmapped local-SGD step (eq. 3), the every-τ H-weighted
   aggregation (eq. 4), synchronization, churn masking and
   H-accumulation are folded into a single ``jax.lax.scan`` over rounds.
@@ -56,9 +57,10 @@ from repro.data import pipeline as pl
 from repro.models import mnist as mm
 from repro.models.module import init_params
 
-# Above this size the (T, n, P, ...) pixel tensor is not materialized;
-# pixels are gathered from the device-resident training set inside the
-# scan body instead (same program, lower peak memory at fog scale).
+# Above this size the (T, n, P, features) pixel tensor is not
+# materialized; the rows are gathered from the device-resident training
+# set inside the scan body instead (same program, lower peak memory at
+# fog scale). Both gathers read rows of the (N, features) view.
 PRESTAGE_LIMIT_BYTES = 256 * 1024 ** 2
 
 # dataset tensors pinned on device across engine invocations (sweeps call
@@ -73,17 +75,21 @@ _DEVICE_CACHE_CAP = 16
 _DEVICE_CACHE: collections.OrderedDict = collections.OrderedDict()
 
 
-def _to_device_cached(arr: np.ndarray):
+def _to_device_cached(arr: np.ndarray, rows: bool = False):
+    """``arr`` pinned on the device; with ``rows`` its ``(N, features)``
+    view instead (the prestaged gather's operand), in an entry of its
+    own keyed on ``arr`` itself, never on a per-call reshape."""
     arr = np.asarray(arr)
     flat = arr.reshape(-1)
     sample = flat[::max(1, flat.size // 4096)]
     key = (id(arr), arr.shape, str(arr.dtype),
-           float(np.asarray(sample, np.float64).sum()))
+           float(np.asarray(sample, np.float64).sum()), rows)
     hit = _DEVICE_CACHE.get(key)
     if hit is None:
         while len(_DEVICE_CACHE) >= _DEVICE_CACHE_CAP:
             _DEVICE_CACHE.popitem(last=False)     # oldest entry only
-        hit = _DEVICE_CACHE[key] = (arr, jnp.asarray(arr))
+        dev = arr.reshape(arr.shape[0], -1) if rows else arr
+        hit = _DEVICE_CACHE[key] = (arr, jnp.asarray(dev))
     else:
         _DEVICE_CACHE.move_to_end(key)
     return hit[1]
@@ -98,10 +104,15 @@ def make_model(name: str, rng):
 
 @jax.jit
 def _gather_rows(x, idx):
-    """The prestaged pixel gather ``x[idx]``, one program under the
-    ``gather`` scope like the in-scan gather."""
+    """The prestaged pixel gather: the rows ``idx`` of the ``(N, F)``
+    view of ``x``, as ``idx.shape + (F,)``; the scan bodies reshape
+    each round's rows to images. One program under the ``gather``
+    scope, like the in-scan gather; the staging passes the pinned
+    ``(N, F)`` view. On the TPU an (N, 28, 28) array is tiled with the
+    image index on the lanes: whole images gathered 45.9 ms a call at
+    the MLP cell's shapes on a TPU v5e, flat rows 2.8 ms (PERF.md)."""
     with jax.named_scope("gather"):
-        return jnp.take(x, idx, axis=0)
+        return jnp.take(x.reshape(x.shape[0], -1), idx, axis=0)
 
 
 def resolve_engine(engine: str) -> str:
@@ -368,9 +379,9 @@ def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
     ``hier=None`` this function is untouched — the flat trace is the
     historical program, bit for bit."""
     tree_map = jax.tree_util.tree_map
-    # the in-scan gather reads rows of the (N, features) view: on the
-    # TPU an (N, 28, 28) image pads to (32, 128) tiles, and a flat row
-    # gathers 80 ms a job faster at the CNN cell on a TPU v5e (PERF.md)
+    # both gathers read rows of the (N, features) view, in the scan or
+    # up front (``_gather_rows`` says why); the round's rows are
+    # reshaped to images here
     x_rows = None if prestage else x_tr.reshape(x_tr.shape[0], -1)
 
     def body(carry, xs):
@@ -381,10 +392,10 @@ def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
             xb, idx, yb, w, cell, cnt, a, agg, upl, cor = xs
         else:
             xb, idx, yb, w, cell, cnt, a, agg = xs
-        if not prestage:
-            with jax.named_scope("gather"):
-                xb = jnp.take(x_rows, idx, axis=0).reshape(
-                    idx.shape + x_tr.shape[1:])
+        with jax.named_scope("gather"):
+            if not prestage:
+                xb = jnp.take(x_rows, idx, axis=0)
+            xb = xb.reshape(xb.shape[:-1] + x_tr.shape[1:])
         active = a * (1.0 - waiting)
         with jax.named_scope("local_sgd"):
             W, losses = step(W, xb, yb, w, cell, cnt, active)
@@ -587,12 +598,14 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
                 faults, is_agg, *tail):
     """Stage one horizon for the scan programs, as the ``train.stage``
     span: the sample slots, the activity (crash outages ANDed in), the
-    fault views, and the pixels gathered up front when the slots' pixel
-    tensor fits ``PRESTAGE_LIMIT_BYTES``. The slots are
+    fault views, and the pixel rows gathered up front
+    (:func:`_gather_rows`) when the slots' pixel tensor fits
+    ``PRESTAGE_LIMIT_BYTES``. The slots are
     ``pipeline.stage_rounds_scan``'s: packed chunk-row tables where
     those execute fewer slots than the dense (T, n, P) slab, else the
     slab. Its counters: ``slots`` the sample slots executed (T·R·C packed,
     T·n·P dense), ``samples`` the unpadded ones, ``packed`` 1 or 0,
+    ``prestaged`` 1 when the rows were gathered up front, else 0,
     ``h2d_bytes`` the host arrays uploaded (the datasets stay pinned
     across calls). Host arrays in ``tail`` ride after the dataset
     operands. Returns (prestage, args, fault_ops, cell): ``cell`` the
@@ -614,7 +627,8 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
         item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
         prestage = idx.size * item_bytes <= PRESTAGE_LIMIT_BYTES
         if prestage:
-            xb_all, idx_arg = _gather_rows(x_dev, idx_dev), None
+            xb_all, idx_arg = _gather_rows(
+                _to_device_cached(x_tr, rows=True), idx_dev), None
         else:
             xb_all, idx_arg = None, idx_dev
         args = ((x_dev, xb_all, idx_arg)
@@ -623,7 +637,7 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
                 + tuple(jnp.asarray(a) for a in tail))
         cell = jnp.asarray(rows[0]) if rows else None
         sp.count(slots=idx.size, samples=int(counts.sum()),
-                 packed=len(rows),
+                 packed=len(rows), prestaged=int(prestage),
                  h2d_bytes=sum(a.nbytes for a in (idx,) + rows + host
                                + tail + tuple(fault_ops)))
     return prestage, args, fault_ops, cell
@@ -1235,6 +1249,14 @@ def _bucket_program(apply_fn, eta: float, prestage: bool, mesh,
 
     def train(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, cell_all,
               counts, act, agg_w, *fault_ops):
+        # the rows of the (N, features) view, as in the scan body
+        x_rows = x_tr.reshape(x_tr.shape[0], -1)
+
+        def pixels(xb, idx):
+            if not prestage:
+                xb = jnp.take(x_rows, idx, axis=0)
+            return xb.reshape(xb.shape[:-1] + x_tr.shape[1:])
+
         def window(carry, xs):
             if faults:
                 (W, wg, H, waiting, p_num, p_tot, p_act, p_flag,
@@ -1275,15 +1297,12 @@ def _bucket_program(apply_fn, eta: float, prestage: bool, mesh,
                 W, H = c
                 if ragged:
                     xb_r, ridx_r, ryb_r, rw_r, rcell_r, cnt_r, a_r = rxs
-                    if not prestage:
-                        xb_r = jnp.take(x_tr, ridx_r, axis=0)
-                    W, losses = ragged_round(W, xb_r, ryb_r, rw_r,
-                                             rcell_r, cnt_r, a_r)
+                    W, losses = ragged_round(W, pixels(xb_r, ridx_r), ryb_r,
+                                             rw_r, rcell_r, cnt_r, a_r)
                 else:
                     xb_r, idx_r, yb_r, w_r, cnt_r, a_r = rxs
-                    if not prestage:
-                        xb_r = jnp.take(x_tr, idx_r, axis=0)
-                    W, losses = vstep(W, xb_r, yb_r, w_r, a_r)
+                    W, losses = vstep(W, pixels(xb_r, idx_r), yb_r, w_r,
+                                      a_r)
                 return (W, H + cnt_r * a_r), losses
 
             (W, H), losses = jax.lax.scan(
@@ -1452,8 +1471,7 @@ def _staged_fingerprint(processed_list, act_list, tau, bucket, staging,
 
 
 def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
-                           staging, max_points, mesh, faults, x_dev,
-                           x_tr):
+                           staging, max_points, mesh, faults, x_tr):
     """Build the staged device operands of one bucket run (everything
     after W0/wg0 and x_tr in the program signature, fault views
     included) plus the host metadata needed to slice histories back
@@ -1531,7 +1549,8 @@ def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
 
     idx_dev = jnp.asarray(idx)
     if prestage:
-        xb_all, idx_arg = _gather_rows(x_dev, idx_dev), None
+        xb_all, idx_arg = _gather_rows(_to_device_cached(x_tr, rows=True),
+                                       idx_dev), None
     else:
         xb_all, idx_arg = None, idx_dev
 
@@ -1621,7 +1640,7 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
                          "pass mesh=None (or staging='dense')")
 
     mesh_shape = None if mesh is None else tuple(mesh.devices.shape)
-    with monitoring.span("train.stage"):
+    with monitoring.span("train.stage") as sp:
         x_dev = _to_device_cached(x_tr)
         cache_key = _staged_fingerprint(
             processed_list, act_list, tau, bucket, staging, max_points,
@@ -1635,11 +1654,11 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
             _STAGED_CACHE_STATS["misses"] += 1
             staged_args, meta = _stage_bucket_operands(
                 processed_list, act_list, y_tr, tau, bucket, staging,
-                max_points, mesh, faults if use_faults else None, x_dev,
-                x_tr)
+                max_points, mesh, faults if use_faults else None, x_tr)
             _staged_cache_put(cache_key, staged_args, meta)
         n_pad = meta["n_pad"]
         T_b, n_win = meta["T_b"], meta["n_win"]
+        sp.count(prestaged=int(meta["prestage"]))
 
         # parameter stacks staged host-side: one device put per leaf
         # instead of per-(bucket shape) broadcast/stack mini-programs.

@@ -306,3 +306,56 @@ def test_packed_hierarchical_matches_legacy(chunk8):
         np.ones((4, 4), bool), 2, 0.1, pl.pad_size(processed), tree=tree)
     assert monitoring.totals()["train.stage"]["packed"] == 1
     _assert_close(h, _legacy("mlp", processed, 2), 2e-3)
+
+
+# ---------------------------------------------------------------------------
+# the prestaged gather against the in-scan gather
+# ---------------------------------------------------------------------------
+
+
+def _run_engine(kind, processed, tau=2):
+    """One run of ``kind`` on the CNN (it reads images, so a wrong
+    reshape of the gathered rows cannot pass) and its ``train.stage``
+    counters."""
+    x_tr, y_tr, x_te, y_te = _data()
+    params, apply_fn = eng.make_model("cnn", jax.random.PRNGKey(0))
+    T, n = len(processed), len(processed[0])
+    act = np.ones((T, n), bool)
+    P = pl.pad_size(processed)
+    eng.reset_staged_cache()
+    monitoring.reset()
+    if kind.startswith("batched"):
+        h = eng.run_rounds_batched(
+            apply_fn, [params], x_tr, y_tr, x_te, y_te, [processed], [act],
+            tau, 0.1, mesh=None, staging=kind.split("_")[1])[0]
+    elif kind == "hierarchical":
+        h = eng.run_rounds_hierarchical(
+            apply_fn, params, x_tr, y_tr, x_te, y_te, processed, act, tau,
+            0.1, P, tree=hr.TierTree.balanced(n, (2, 1), (tau, 2 * tau)))
+    else:
+        h = eng.run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te,
+                                processed, act, tau, 0.1, P)
+    return h, monitoring.totals()["train.stage"]
+
+
+@pytest.mark.parametrize("kind", ["scan_dense", "scan_packed", "hierarchical",
+                                  "batched_dense", "batched_ragged"])
+def test_prestaged_and_in_scan_gathers_hand_the_step_the_same_pixels(
+        chunk8, monkeypatch, kind):
+    """Both gathers read rows of the (N, 784) view of the (N, 28, 28)
+    images and reshape each round's rows to images: the histories are
+    the same bits whether the rows were gathered up front or inside the
+    program."""
+    processed = _skewed(T=4)
+    if kind == "scan_dense":
+        monkeypatch.setattr(pl, "PACKED_CHUNK", 64)
+    assert _data()[0].ndim == 3
+    hists = []
+    for limit, prestaged in ((0, 0), (1 << 40, 1)):
+        monkeypatch.setattr(eng, "PRESTAGE_LIMIT_BYTES", limit)
+        h, st = _run_engine(kind, processed)
+        assert st["prestaged"] == prestaged
+        if kind.startswith("scan"):
+            assert st["packed"] == (kind == "scan_packed")
+        hists.append(h)
+    _assert_bitwise(*hists)

@@ -113,6 +113,19 @@ def test_device_cache_evicts_lru_only():
     eng._DEVICE_CACHE.clear()
 
 
+def test_device_cache_pins_the_row_view_once_per_array():
+    """The prestaged gather's (N, F) operand is keyed on the host array:
+    every later call finds it, beside the image-shaped entry."""
+    eng._DEVICE_CACHE.clear()
+    x = np.arange(24, dtype=np.float32).reshape(4, 3, 2)
+    rows = eng._to_device_cached(x, rows=True)
+    assert eng._to_device_cached(x, rows=True) is rows
+    np.testing.assert_array_equal(rows, x.reshape(4, 6))
+    assert eng._to_device_cached(x).shape == (4, 3, 2)
+    assert len(eng._DEVICE_CACHE) == 2
+    eng._DEVICE_CACHE.clear()
+
+
 def test_sharded_multi_device_equivalence():
     """8 forced host devices: sharded (n=6 padded to 8, then n=10 with
     2 fog devices per shard, plus churn) must match the scan engine and

@@ -243,10 +243,14 @@ def test_programs_carry_the_four_scopes_unnested(kind):
     assert all(len(hit) <= 1 for hit in seen)
 
 
-def test_prestaged_gather_is_its_own_scoped_program():
-    x = jnp.arange(24.0).reshape(6, 4)
+@pytest.mark.parametrize("shape", [(6, 4), (6, 2, 2)])
+def test_prestaged_gather_is_its_own_scoped_program(shape):
+    """Rows of the (N, F) view: an image array gathers as flat rows."""
+    x = jnp.arange(24.0).reshape(shape)
     idx = jnp.asarray([[0, 5], [2, 2]], jnp.int32)
-    np.testing.assert_array_equal(eng._gather_rows(x, idx),
-                                  np.asarray(x)[np.asarray(idx)])
+    got = eng._gather_rows(x, idx)
+    assert got.shape == (2, 2, 4)
+    np.testing.assert_array_equal(got,
+                                  np.asarray(x).reshape(6, -1)[np.asarray(idx)])
     text = eng._gather_rows.lower(x, idx).compile().as_text()
     assert {s for hit in _scopes_of(text) for s in hit} == {"gather"}
