@@ -544,8 +544,19 @@ def run_scenarios(scenarios: list[Scenario], scale: BenchScale, *,
             for b in idxs:
                 dispatches[b] = decision.as_row()
     elif train:
+        point_mesh = None if mesh == "auto" else mesh
         for b, (sc, plan) in enumerate(zip(scenarios, plans)):
             if b in hier_idx:
+                continue
+            if engines[b] == "batched":
+                # per point through the bucket program, one scenario
+                # at its exact shape
+                hists[b] = F.run_network_aware_batched(
+                    [sc.cfg], data, [plan], streams=[sc.streams],
+                    activities=[sc.activity], schedules=[sc.schedule],
+                    mesh=point_mesh, bucket="exact",
+                    faults=None if sc.faults is None else [sc.faults],
+                    guard=sc.guard, quorum=sc.quorum)[0]
                 continue
             hists[b] = F.run_network_aware(sc.cfg, data, sc.traces,
                                            sc.adj, plan,
@@ -553,8 +564,7 @@ def run_scenarios(scenarios: list[Scenario], scale: BenchScale, *,
                                            activity=sc.activity,
                                            schedule=sc.schedule,
                                            engine=engines[b],
-                                           mesh=None if mesh == "auto"
-                                           else mesh,
+                                           mesh=point_mesh,
                                            faults=sc.faults,
                                            guard=sc.guard,
                                            quorum=sc.quorum)

@@ -22,11 +22,10 @@ Engine / mesh knobs
   AsyncEvaluator. ``auto`` picks this whenever more than one device is
   visible — force a multi-device CPU mesh with
   ``XLA_FLAGS=--xla_force_host_platform_device_count=8``;
-* ``batched`` — the S=1 slice of the scenario-batched sweep engine
-  (the bucket window program, single-device and bitwise-equal to
-  ``scan``; sweeps batch many runs into one compiled program — sharded
-  on multi-device hosts — via ``benchmarks.fog.run_scenarios``);
 * ``legacy``  — the original per-round loop (numerical oracle).
+
+Sweeps batch many runs into one compiled program — sharded on
+multi-device hosts — via ``benchmarks.fog.run_scenarios``.
 
 Programmatic callers can pass an explicit mesh:
 ``run_network_aware(..., engine="sharded", mesh=make_data_mesh(4))``.
@@ -68,8 +67,7 @@ if __name__ == "__main__":
     ap.add_argument("--setting", default="B", choices=list("ABCDE"))
     ap.add_argument("--non-iid", action="store_true")
     ap.add_argument("--engine", default="auto",
-                    choices=["auto", "scan", "sharded", "batched",
-                             "legacy"])
+                    choices=["auto", "scan", "sharded", "legacy"])
     ap.add_argument("--schedule", default="static",
                     choices=["static", "churn", "flap"])
     ap.add_argument("--churn", type=float, default=0.0)

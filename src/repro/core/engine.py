@@ -1,8 +1,12 @@
 """Scan- and shard-compiled federated training engine.
 
-The hot path of ``run_network_aware`` used to dispatch T separate jitted
-steps, re-padding and re-uploading the batch tensor every round.  Here
-the whole horizon is one device-resident program:
+``run_network_aware`` picks one of three engines, below: scan, sharded
+and legacy; sweeps train whole buckets in ``run_rounds_batched``. In
+the scan engine (:func:`run_rounds_scan`) the whole horizon is one
+device-resident program from one factory (``_scan_program``), also
+when it composes eq. (4) up a tier tree (``tree=``, from
+``run_network_aware(hierarchy=)``) or runs window-chunked under a
+checkpoint:
 
 * the padded sample stream is staged once as ``(T, n, P)`` index /
   label / weight arrays, or as packed ``(T, R, C)`` chunk rows where
@@ -355,33 +359,103 @@ def _guarded_uploads(W, contributing, upl, cor, guard: bool,
 
 
 # ---------------------------------------------------------------------------
-# scan-compiled path
+# scan-compiled path: flat, tiered and window-chunked horizons
 # ---------------------------------------------------------------------------
 
+# static tier shape closed over by a tiered program: per-level
+# member->group maps, group counts, and per-level device ancestor maps
+# (all host numpy; they become jit constants)
+_HierSpec = collections.namedtuple("_HierSpec",
+                                   "group_ids num_groups anc")
 
-def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
-                    guard: bool, quorum: float, x_tr, x_te, y_te,
-                    hier=None):
-    """The per-round scan body, shared by the monolithic program and
-    the window-chunked checkpoint driver (same closure -> same jaxpr ->
-    the chunked dispatches reproduce the monolithic scan bit for bit).
-    ``step`` is :func:`_scan_step`'s local SGD; the xs row ``cell`` is
-    None on dense (n, P) slots and the packed rows' owners otherwise
-    (the sample rows are then (R, C)). With ``faults`` the xs gain
-    (upload_ok, corrupt) rows and the aggregation runs guarded +
-    quorum-gated; without, the trace is exactly the historical clean
-    program.
+# lru_cache keys must be hashable, so the program cache keys on the
+# tree FINGERPRINT and the spec arrays ride this side table
+_HIER_SPECS: dict = {}
+
+
+def _round_pixels(xb, idx, x_rows, image_shape):
+    """One round's pixels as images of ``image_shape``: the rows
+    gathered up front (``xb``) or, where ``xb`` is None, the rows
+    ``idx`` of the ``(N, F)`` view ``x_rows``, gathered here. Shared by
+    the scan and bucket bodies (``_gather_rows`` says why rows)."""
+    if xb is None:
+        xb = jnp.take(x_rows, idx, axis=0)
+    return xb.reshape(xb.shape[:-1] + image_shape)
+
+
+def _gate(mask, qok):
+    """``mask`` ANDed with the quorum decision ``qok`` (None: no
+    faults, so no quorum)."""
+    return mask if qok is None else mask & qok
+
+
+def _tier_aggregate(hier, anc, lvl, is_top, W, Wu, Hc, wg, a, qok):
+    """Eq. (4) composed up the tier tree ``hier``: tier l aggregates
+    tier l-1's stack under CUMULATIVE H weights (``Hc``, the devices'
+    H times their contributions), so feeding each tier's (models, H_g)
+    into the next telescopes to the flat eq. (4) over all contributing
+    devices — the top row IS the global model, taken at top-tier rounds
+    (``is_top``). Every device syncs from its ancestor group at the
+    round's highest aggregating tier ``lvl`` (``anc``: the devices'
+    ancestor at each tier); empty groups (H_g == 0) leave their
+    members' params untouched. ``qok`` gates both. Returns (global,
+    device stack)."""
+    tree_map = jax.tree_util.tree_map
+    Wl, Hl = Wu, Hc
+    tiers = []
+    for gids, ng in zip(hier.group_ids, hier.num_groups):
+        Wl, Hl = aggregate_tier(Wl, Hl, gids, ng)
+        tiers.append((Wl, Hl))
+    Wtop, Htop = tiers[-1]
+    ok_top = _gate(is_top & (Htop[0] > 0), qok)
+    wg2 = tree_map(lambda nw, old: jnp.where(ok_top, nw[0], old), Wtop, wg)
+
+    def pick(lv):
+        Wg, Hg = tiers[lv]
+        return tree_map(lambda g: g[anc[lv]], Wg), Hg[anc[lv]]
+
+    target, Hsel = jax.lax.switch(
+        jnp.maximum(lvl - 1, 0),
+        [lambda lv=lv: pick(lv) for lv in range(len(tiers))])
+    sync_ok = _gate((a > 0.5) & (Hsel > 0), qok)
+    W2 = tree_map(
+        lambda p, tg: jnp.where(
+            sync_ok.reshape(sync_ok.shape + (1,) * (p.ndim - 1)), tg, p),
+        W, target)
+    return wg2, W2
+
+
+def _make_scan_body(apply_fn, eta: float, prestage: bool, faults: bool,
+                    guard: bool, quorum: float, hier, operands, cell_all):
+    """The per-round scan body and its xs, shared by every scan program
+    (same closure -> same jaxpr -> the chunked dispatches reproduce the
+    monolithic scan bit for bit). ``operands`` are a program's
+    positional operands after its carry: ``(x_tr, xb_all, idx_all,
+    yb_all, w_all, counts, act, is_agg, x_te, y_te)``, then the tier
+    levels with ``hier``, then the (upload_ok, corrupt) fault views
+    with ``faults``. The local SGD is :func:`_scan_step`'s: ``cell_all``
+    is None on dense (n, P) slots and the packed rows' owners otherwise
+    (the sample rows are then (R, C)). With ``faults`` the aggregation
+    runs guarded + quorum-gated; without, the trace is exactly the
+    historical clean program.
 
     ``hier`` — optional :class:`_HierSpec`: the xs gain a trailing
-    per-round ``lvl`` row (highest aggregating tier, 0 = none) and the
-    aggregation branch composes eq. (4) up the tier tree instead of
-    straight to the server (see :func:`run_rounds_hierarchical`). With
-    ``hier=None`` this function is untouched — the flat trace is the
-    historical program, bit for bit."""
+    per-round ``lvl`` row (highest aggregating tier, 0 = none), eq. (4)
+    composes up the tier tree (:func:`_tier_aggregate`) instead of
+    straight to the server, and the global model is evaluated at
+    top-tier rounds only. The guarded uploads, the quorum and the H /
+    waiting bookkeeping are the flat aggregation's; H resets only once
+    the top tier has consumed it. With ``hier=None`` the trace is the
+    flat program, bit for bit."""
     tree_map = jax.tree_util.tree_map
+    (x_tr, xb_all, idx_all, yb_all, w_all, counts, act, is_agg, x_te,
+     y_te, *ops) = operands
+    lvl_xs = tuple(ops[:1]) if hier is not None else ()
+    xs = ((xb_all, idx_all, yb_all, w_all, cell_all, counts, act, is_agg)
+          + tuple(ops[len(lvl_xs):]) + lvl_xs)
+    step = _scan_step(apply_fn, eta, cell_all is not None)
     # both gathers read rows of the (N, features) view, in the scan or
-    # up front (``_gather_rows`` says why); the round's rows are
-    # reshaped to images here
+    # up front; the round's rows are reshaped to images in the body
     x_rows = None if prestage else x_tr.reshape(x_tr.shape[0], -1)
 
     def body(carry, xs):
@@ -393,40 +467,61 @@ def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
         else:
             xb, idx, yb, w, cell, cnt, a, agg = xs
         with jax.named_scope("gather"):
-            if not prestage:
-                xb = jnp.take(x_rows, idx, axis=0)
-            xb = xb.reshape(xb.shape[:-1] + x_tr.shape[1:])
+            xb = _round_pixels(xb, idx, x_rows, x_tr.shape[1:])
         active = a * (1.0 - waiting)
         with jax.named_scope("local_sgd"):
             W, losses = step(W, xb, yb, w, cell, cnt, active)
         H = H + cnt * active
+        if hier is not None:
+            # constants enter the program in the order they are made:
+            # the ancestor maps come before the aggregation's own
+            anc = [jnp.asarray(v, jnp.int32) for v in hier.anc]
+            is_top = lvl >= len(hier.num_groups)
 
         def do_agg(ops):
             W, wg, H, waiting = ops
             with jax.named_scope("aggregate"):
+                qok = None
+                Wu, contrib = W, active
                 if faults:
                     Wu, contrib = _guarded_uploads(W, active, upl, cor,
                                                    guard, 1)
                     surv = contrib.sum()
                     qok = surv >= quorum * active.sum()
+                if hier is None:
                     wg2 = aggregate(Wu, H, contrib, wg)
-                    # quorum failed: the whole aggregation event is
-                    # skipped — previous global carries forward, no sync,
-                    # H keeps accumulating into the next window
-                    wg2 = tree_map(lambda nw, old: jnp.where(qok, nw, old),
-                                   wg2, wg)
-                    W2 = _sync(W, wg2, (a > 0.5) & qok)
-                    H2 = jnp.where(qok, jnp.zeros_like(H), H)
-                    waiting2 = jnp.where(qok, 1.0 - a, waiting)
+                    if faults:
+                        # quorum failed: the whole aggregation event is
+                        # skipped — previous global carries forward, no
+                        # sync, H keeps accumulating into the next window
+                        wg2 = tree_map(
+                            lambda nw, old: jnp.where(qok, nw, old),
+                            wg2, wg)
+                    W2 = _sync(W, wg2, _gate(a > 0.5, qok))
+                    reset = qok
                 else:
-                    wg2 = aggregate(W, H, active, wg)
-                    W2 = _sync(W, wg2, a > 0.5)
-                    H2 = jnp.zeros_like(H)
-                    waiting2 = 1.0 - a
-            with jax.named_scope("eval"):
-                logits = apply_fn(wg2, x_te)
-                tl = mm.ce_loss(logits, y_te)
-                ta = mm.accuracy(logits, y_te)
+                    wg2, W2 = _tier_aggregate(hier, anc, lvl, is_top, W,
+                                              Wu, H * contrib, wg, a, qok)
+                    reset = _gate(is_top, qok)
+                # H resets once the event is consumed: on a tree only at
+                # the top tier (that is what makes the tiers telescope)
+                H2 = (jnp.zeros_like(H) if reset is None
+                      else jnp.where(reset, jnp.zeros_like(H), H))
+                waiting2 = (1.0 - a if qok is None
+                            else jnp.where(qok, 1.0 - a, waiting))
+
+            def ev(_=None):
+                with jax.named_scope("eval"):
+                    logits = apply_fn(wg2, x_te)
+                    return (mm.ce_loss(logits, y_te),
+                            mm.accuracy(logits, y_te))
+
+            if hier is None:
+                tl, ta = ev()
+            else:
+                tl, ta = jax.lax.cond(
+                    is_top, ev,
+                    lambda _: (jnp.float32(0.0), jnp.float32(0.0)), None)
             out = (W2, wg2, H2, waiting2, tl, ta, H)
             if faults:
                 out += (surv, qok.astype(jnp.float32))
@@ -440,121 +535,42 @@ def _make_scan_body(apply_fn, step, prestage: bool, faults: bool,
                 out += (z, jnp.float32(1.0))
             return out
 
-        if hier is not None:
-            L = len(hier.num_groups)
-            anc = [jnp.asarray(a, jnp.int32) for a in hier.anc]
-            is_top = lvl >= L
-
-            def hier_do_agg(ops):
-                W, wg, H, waiting = ops
-                with jax.named_scope("aggregate"):
-                    if faults:
-                        Wu, contrib = _guarded_uploads(W, active, upl, cor,
-                                                       guard, 1)
-                        surv = contrib.sum()
-                        qok = surv >= quorum * active.sum()
-                    else:
-                        Wu, contrib = W, active
-                        qok = None
-                    # compose eq. (4) up the tree: tier l aggregates tier
-                    # l-1's stack under CUMULATIVE H weights, so feeding
-                    # each tier's (models, H_g) into the next telescopes to
-                    # the flat eq. (4) over all contributing devices — the
-                    # top row IS the global model
-                    Wl, Hl = Wu, H * contrib
-                    tiers = []
-                    for gids, ng in zip(hier.group_ids, hier.num_groups):
-                        Wl, Hl = aggregate_tier(Wl, Hl, gids, ng)
-                        tiers.append((Wl, Hl))
-                    Wtop, Htop = tiers[-1]
-                    ok_top = is_top & (Htop[0] > 0)
-                    if qok is not None:
-                        ok_top = ok_top & qok
-                    wg2 = tree_map(
-                        lambda nw, old: jnp.where(ok_top, nw[0], old),
-                        Wtop, wg)
-
-                    # every device syncs from its ancestor group at the
-                    # round's highest aggregating tier; empty groups
-                    # (H_g == 0) leave their members' params untouched
-                    def pick(lv):
-                        Wg, Hg = tiers[lv]
-                        return (tree_map(lambda g: g[anc[lv]], Wg),
-                                Hg[anc[lv]])
-
-                    target, Hsel = jax.lax.switch(
-                        jnp.maximum(lvl - 1, 0),
-                        [lambda lv=lv: pick(lv) for lv in range(L)])
-                    sync_ok = (a > 0.5) & (Hsel > 0)
-                    if qok is not None:
-                        sync_ok = sync_ok & qok
-                    W2 = tree_map(
-                        lambda p, tg: jnp.where(
-                            sync_ok.reshape(sync_ok.shape
-                                            + (1,) * (p.ndim - 1)), tg, p),
-                        W, target)
-                    # H accumulates across sub-tier windows and resets only
-                    # once the TOP tier has consumed it (that is what makes
-                    # the tier composition telescope); quorum failure skips
-                    # the whole event, flat-plane style
-                    if faults:
-                        H2 = jnp.where(is_top & qok, jnp.zeros_like(H), H)
-                        waiting2 = jnp.where(qok, 1.0 - a, waiting)
-                    else:
-                        H2 = jnp.where(is_top, jnp.zeros_like(H), H)
-                        waiting2 = 1.0 - a
-
-                def ev(_):
-                    with jax.named_scope("eval"):
-                        logits = apply_fn(wg2, x_te)
-                        return (mm.ce_loss(logits, y_te),
-                                mm.accuracy(logits, y_te))
-
-                tl, ta = jax.lax.cond(
-                    is_top, ev,
-                    lambda _: (jnp.float32(0.0), jnp.float32(0.0)), None)
-                out = (W2, wg2, H2, waiting2, tl, ta, H)
-                if faults:
-                    out += (surv, qok.astype(jnp.float32))
-                return out
-
-            do_agg = hier_do_agg
-
         res = jax.lax.cond(agg, do_agg, skip, (W, wg, H, waiting))
         W, wg, H, waiting = res[:4]
         return (W, wg, H, waiting), (losses,) + res[4:]
 
-    return body
+    return body, xs
 
 
 @functools.lru_cache(maxsize=16)
 def _scan_program(apply_fn, eta: float, prestage: bool,
                   faults: bool = False, guard: bool = False,
-                  quorum: float = 0.0):
-    """One jitted program per (model, η, staging mode, fault config);
-    the aggregation schedule arrives as the traced ``is_agg`` round
-    mask, so changing τ does not recompile. With ``faults=False`` the
-    trace (and therefore the bits) is the historical clean program.
-    ``cell_all`` — the (T, R) row owners of packed staging
-    (``pipeline.stage_rounds_scan``); None on dense slots, whose
-    trace is unchanged by it."""
+                  quorum: float = 0.0, tree_fp: str | None = None):
+    """One jitted program per (model, η, staging mode, fault config,
+    tier-tree shape); the aggregation schedule arrives as the traced
+    ``is_agg`` round mask (and, on a tree, the per-round aggregation
+    level ``lvl``, the operand after ``y_te``), so changing τ does not
+    recompile. ``tree_fp`` — a fingerprint registered in
+    ``_HIER_SPECS``; None for the flat program. ``cell_all`` — the (T,
+    R) row owners of packed staging (``pipeline.stage_rounds_scan``);
+    None on dense slots."""
+    hier = None if tree_fp is None else _HIER_SPECS[tree_fp]
 
-    # the name is the module's in traces (``jit_fog_scan``) and in the
-    # persistent compile cache's key, which ignores op metadata: a
-    # program whose scopes change must not keep the name of one
-    # compiled without them
-    def fog_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all, counts,
-                 act, is_agg, x_te, y_te, *fault_ops, cell_all=None):
-        n = counts.shape[1]
-        step = _scan_step(apply_fn, eta, cell_all is not None)
-        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
-                               quorum, x_tr, x_te, y_te)
-        carry0 = (W0, wg0, jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32))
-        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act, is_agg)
-        xs = xs + tuple(fault_ops)
+    def fog_scan(W0, wg0, *operands, cell_all=None):
+        body, xs = _make_scan_body(apply_fn, eta, prestage, faults, guard,
+                                   quorum, hier, operands, cell_all)
+        n = jax.tree_util.tree_leaves(W0)[0].shape[0]
+        carry0 = (W0, wg0, jnp.zeros(n, jnp.float32),
+                  jnp.zeros(n, jnp.float32))
         (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
         return (wg,) + ys
 
+    # the name is the module's in traces (``jit_fog_scan``,
+    # ``jit_fog_hier_scan``) and in the persistent compile cache's key,
+    # which ignores op metadata: a program whose scopes change must not
+    # keep the name of one compiled without them
+    if hier is not None:
+        fog_scan.__name__ = "fog_hier_scan"
     return jax.jit(fog_scan)
 
 
@@ -562,20 +578,15 @@ def _scan_program(apply_fn, eta: float, prestage: bool,
 def _scan_chunk_program(apply_fn, eta: float, prestage: bool,
                         faults: bool = False, guard: bool = False,
                         quorum: float = 0.0):
-    """Window-chunked slice of ``_scan_program``: the SAME scan body
-    with the carry explicit in/out, so the checkpoint driver can
-    dispatch ``checkpoint_every`` windows at a time and snapshot the
+    """Window-chunked slice of the flat ``_scan_program``: the SAME
+    scan body with the carry explicit in/out, so the checkpoint driver
+    can dispatch ``checkpoint_every`` windows at a time and snapshot the
     carry at each boundary. Iterating the identical body over a sliced
     round axis reproduces the monolithic scan bit for bit on CPU."""
 
-    def fog_scan_chunk(carry, x_tr, xb_all, idx_all, yb_all, w_all,
-                       counts, act, is_agg, x_te, y_te, *fault_ops,
-                       cell_all=None):
-        step = _scan_step(apply_fn, eta, cell_all is not None)
-        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
-                               quorum, x_tr, x_te, y_te)
-        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act, is_agg)
-        xs = xs + tuple(fault_ops)
+    def fog_scan_chunk(carry, *operands, cell_all=None):
+        body, xs = _make_scan_body(apply_fn, eta, prestage, faults, guard,
+                                   quorum, None, operands, cell_all)
         return jax.lax.scan(body, carry, xs)
 
     return jax.jit(fog_scan_chunk)
@@ -594,18 +605,31 @@ def _stage_fault_ops(faults, T: int, n: int, tau: int):
     return jnp.asarray(upl), jnp.asarray(cor)
 
 
+def _stage_pixels(x_tr, idx):
+    """The pixel operands of staged sample slots ``idx``: the rows
+    gathered up front (:func:`_gather_rows`, from the pinned ``(N, F)``
+    view) when their pixel tensor fits ``PRESTAGE_LIMIT_BYTES``, else
+    the indices, for the program's in-scan gather. Returns (xb_all,
+    idx_arg, prestage), one of the first two None."""
+    item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
+    prestage = idx.size * item_bytes <= PRESTAGE_LIMIT_BYTES
+    idx_dev = jnp.asarray(idx)
+    if prestage:
+        return _gather_rows(_to_device_cached(x_tr, rows=True),
+                            idx_dev), None, True
+    return None, idx_dev, False
+
+
 def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
                 faults, is_agg, *tail):
     """Stage one horizon for the scan programs, as the ``train.stage``
     span: the sample slots, the activity (crash outages ANDed in), the
-    fault views, and the pixel rows gathered up front
-    (:func:`_gather_rows`) when the slots' pixel tensor fits
-    ``PRESTAGE_LIMIT_BYTES``. The slots are
-    ``pipeline.stage_rounds_scan``'s: packed chunk-row tables where
-    those execute fewer slots than the dense (T, n, P) slab, else the
-    slab. Its counters: ``slots`` the sample slots executed (T·R·C packed,
-    T·n·P dense), ``samples`` the unpadded ones, ``packed`` 1 or 0,
-    ``prestaged`` 1 when the rows were gathered up front, else 0,
+    fault views, and the pixel operands (:func:`_stage_pixels`). The
+    slots are ``pipeline.stage_rounds_scan``'s: packed chunk-row tables
+    where those execute fewer slots than the dense (T, n, P) slab, else
+    the slab. Its counters: ``slots`` the sample slots executed (T·R·C
+    packed, T·n·P dense), ``samples`` the unpadded ones, ``packed`` 1 or
+    0, ``prestaged`` 1 when the rows were gathered up front, else 0,
     ``h2d_bytes`` the host arrays uploaded (the datasets stay pinned
     across calls). Host arrays in ``tail`` ride after the dataset
     operands. Returns (prestage, args, fault_ops, cell): ``cell`` the
@@ -623,14 +647,7 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
             fault_ops = _stage_fault_ops(faults, T, n, tau)
         host = (yb, wts, counts, act.astype(np.float32), is_agg)
         x_dev = _to_device_cached(x_tr)
-        idx_dev = jnp.asarray(idx)
-        item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
-        prestage = idx.size * item_bytes <= PRESTAGE_LIMIT_BYTES
-        if prestage:
-            xb_all, idx_arg = _gather_rows(
-                _to_device_cached(x_tr, rows=True), idx_dev), None
-        else:
-            xb_all, idx_arg = None, idx_dev
+        xb_all, idx_arg, prestage = _stage_pixels(x_tr, idx)
         args = ((x_dev, xb_all, idx_arg)
                 + tuple(jnp.asarray(a) for a in host)
                 + (_to_device_cached(x_te), _to_device_cached(y_te))
@@ -643,23 +660,56 @@ def _stage_scan(processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau,
     return prestage, args, fault_ops, cell
 
 
+def _history(losses, report, tl, ta, H, fault_ys=(), at=None) -> dict:
+    """The engine's history pieces from its outputs on the host:
+    ``device_loss`` every round of ``losses``; the test loss and
+    accuracy, H and — with ``fault_ys`` = (survivors, quorum_ok) — the
+    fault counts at the ``report`` rounds, read from rows ``at`` of
+    their arrays (the report rounds themselves by default)."""
+    at = report if at is None else at
+    tl, ta, H = np.asarray(tl), np.asarray(ta), np.asarray(H)
+    out = {"device_loss": list(np.asarray(losses)),
+           "test_loss": [float(v) for v in tl[at]],
+           "test_acc": [float(v) for v in ta[at]],
+           "agg_round": [int(t) for t in report],
+           "H_agg": list(H[at])}
+    if fault_ys:
+        surv, qok = (np.asarray(v) for v in fault_ys)
+        out["agg_survivors"] = [float(v) for v in surv[at]]
+        out["agg_quorum_ok"] = [bool(v > 0) for v in qok[at]]
+    return out
+
+
 def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
                     act_all, tau: int, eta: float, max_pts: int, *,
-                    faults=None, guard: bool = True, quorum: float = 0.0,
+                    tree=None, faults=None, guard: bool = True,
+                    quorum: float = 0.0,
                     checkpoint_path: str | None = None,
                     checkpoint_every: int = 1, resume: str | None = None,
                     stop_after: int | None = None) -> dict:
     """Train all T rounds in one compiled scan; returns history pieces.
 
+    ``tree`` — optional :class:`repro.core.hierarchy.TierTree`: at each
+    round whose index hits a tier period eq. (4) composes UP the tree
+    — devices to gateways, gateways to regional groups, … — as
+    :func:`_tier_aggregate` says; the global history (test_loss /
+    test_acc / H_agg / agg_round) is reported at TOP-tier rounds, and
+    ``tier_agg_round``/``tier_agg_level`` record the full per-tier
+    cadence. The tree's first period must equal ``tau`` and its device
+    count the run's; it does not compose with checkpointing. An L=1
+    tree runs the flat program, so the collapse is bitwise by
+    construction (``tests/test_hierarchy.py`` pins it).
+
     ``faults`` — optional :class:`repro.core.faults.FaultSchedule`:
     crash outages are ANDed into the staged activity and the
     (upload_ok, corrupt) views ride the scan as extra operands, with
     the aggregation guarded (``guard`` finite-masking + H-weight
-    renormalization over survivors) and quorum-gated (``quorum`` —
-    windows whose surviving-upload fraction falls below it skip the
-    aggregation and carry the previous global forward). ``faults=None``
-    runs the historical clean program, bitwise-identical to before the
-    fault plane existed.
+    renormalization over survivors, at the DEVICE tier on a tree) and
+    quorum-gated (``quorum`` — windows whose surviving-upload fraction
+    falls below it skip the aggregation, the whole composed event on a
+    tree, and carry the previous global forward). ``faults=None`` runs
+    the historical clean program, bitwise-identical to before the fault
+    plane existed.
 
     ``checkpoint_path`` — snapshot (params stack, global, H, waiting,
     history, round index) every ``checkpoint_every`` aggregation
@@ -672,164 +722,61 @@ def run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
         T, n = processed.T, processed.n
     else:
         T, n = len(processed), len(processed[0])
-    is_agg = (np.arange(T) + 1) % tau == 0
+    checkpointed = checkpoint_path is not None or resume is not None
+    fp, tail = None, ()
+    if tree is not None:
+        if tau != tree.taus[0]:
+            raise ValueError(f"run tau={tau} but the tier tree aggregates "
+                             f"its first tier every {tree.taus[0]}")
+        if n != tree.n:
+            raise ValueError(f"run has n={n} devices but the tree has "
+                             f"n={tree.n}")
+        if checkpointed or stop_after is not None:
+            raise ValueError("checkpoint/resume is a flat scan-engine "
+                             "feature; it does not compose with a tier "
+                             "tree")
+        if tree.levels == 1:
+            tree = None
+    if tree is None:
+        is_agg = (np.arange(T) + 1) % tau == 0
+        report = np.nonzero(is_agg)[0]
+    else:
+        with monitoring.span("train.tiers"):
+            lvl = tree.level_rounds(T)
+            is_agg, tail = lvl > 0, (lvl,)
+            fp = tree.fingerprint()
+            if fp not in _HIER_SPECS:
+                _HIER_SPECS[fp] = _HierSpec(group_ids=tree.parents,
+                                            num_groups=tree.group_counts,
+                                            anc=tree.ancestors())
+        report = np.nonzero(lvl == tree.levels)[0]
     use_faults = faults is not None
     guard_f = bool(guard) if use_faults else False
     quorum_f = float(quorum) if use_faults else 0.0
     prestage, args, fault_ops, cell = _stage_scan(
         processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
-        is_agg)
+        is_agg, *tail)
 
-    if checkpoint_path is not None or resume is not None:
+    if checkpointed:
         return _run_scan_checkpointed(
             apply_fn, params, n, T, tau, eta, prestage, args, fault_ops,
             cell, use_faults, guard_f, quorum_f, checkpoint_path,
             checkpoint_every, resume, stop_after)
 
     fn = _scan_program(apply_fn, float(eta), prestage, use_faults,
-                       guard_f, quorum_f)
+                       guard_f, quorum_f, fp)
     # sanitize hook: under run_network_aware(sanitize=True) the guard
     # disallows implicit transfers across the whole-horizon dispatch
     # (staging above and history readback below are explicit, by design)
     with monitoring.span("train.device"), sanitize.hot_loop_guard():
         res = fn(_stack(params, n), params, *args, *fault_ops,
                  cell_all=cell)
-        losses, tl, ta, H_at = res[1:5]
-        jax.block_until_ready(losses)
+        jax.block_until_ready(res[1])
     with monitoring.span("train.readback"):
-        agg_rounds = np.nonzero(is_agg)[0]
-        tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
-        out = {"device_loss": list(np.asarray(losses)),
-               "test_loss": [float(v) for v in tl[agg_rounds]],
-               "test_acc": [float(v) for v in ta[agg_rounds]],
-               "agg_round": [int(t) for t in agg_rounds],
-               "H_agg": list(H_at[agg_rounds])}
-        if use_faults:
-            surv, qokf = np.asarray(res[5]), np.asarray(res[6])
-            out["agg_survivors"] = [float(v) for v in surv[agg_rounds]]
-            out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[agg_rounds]]
-    return out
-
-
-# ---------------------------------------------------------------------------
-# hierarchical (tier-tree) scan path
-# ---------------------------------------------------------------------------
-
-# static tier shape closed over by the compiled hierarchical program:
-# per-level member->group maps, group counts, and per-level device
-# ancestor maps (all host numpy; they become jit constants)
-_HierSpec = collections.namedtuple("_HierSpec",
-                                   "group_ids num_groups anc")
-
-# lru_cache keys must be hashable, so the program cache keys on the
-# tree FINGERPRINT and the spec arrays ride this side table
-_HIER_SPECS: dict = {}
-
-
-@functools.lru_cache(maxsize=8)
-def _hier_program(apply_fn, eta: float, prestage: bool,
-                  faults: bool = False, guard: bool = False,
-                  quorum: float = 0.0, tree_fp: str = ""):
-    """One jitted program per (model, η, staging mode, fault config,
-    tier-tree shape). The per-round aggregation LEVEL arrives as a
-    traced xs row, so trees with identical shape but different τ
-    chains share one compiled program."""
-    spec = _HIER_SPECS[tree_fp]
-
-    def fog_hier_scan(W0, wg0, x_tr, xb_all, idx_all, yb_all, w_all,
-                      counts, act, is_agg, x_te, y_te, lvl, *fault_ops,
-                      cell_all=None):
-        n = counts.shape[1]
-        step = _scan_step(apply_fn, eta, cell_all is not None)
-        body = _make_scan_body(apply_fn, step, prestage, faults, guard,
-                               quorum, x_tr, x_te, y_te, hier=spec)
-        carry0 = (W0, wg0, jnp.zeros(n, jnp.float32),
-                  jnp.zeros(n, jnp.float32))
-        xs = (xb_all, idx_all, yb_all, w_all, cell_all, counts, act,
-              is_agg)
-        xs = xs + tuple(fault_ops) + (lvl,)
-        (_, wg, _, _), ys = jax.lax.scan(body, carry0, xs)
-        return (wg,) + ys
-
-    return jax.jit(fog_hier_scan)
-
-
-def run_rounds_hierarchical(apply_fn, params, x_tr, y_tr, x_te, y_te,
-                            processed, act_all, tau: int, eta: float,
-                            max_pts: int, *, tree, faults=None,
-                            guard: bool = True,
-                            quorum: float = 0.0) -> dict:
-    """Tier-aware window scan over a :class:`repro.core.hierarchy.
-    TierTree`: local SGD every round, and at each round whose index
-    hits a tier period the eq. (4) aggregation composes UP the tree —
-    devices to gateways, gateways to regional groups, … — with devices
-    syncing from their ancestor at the round's highest aggregating
-    tier. H accumulates across sub-tier windows and resets once the
-    top tier consumes it, so the top-tier model telescopes to the flat
-    eq. (4) over all contributing devices. The global history
-    (test_loss / test_acc / H_agg / agg_round) is reported at TOP-tier
-    rounds; ``tier_agg_round``/``tier_agg_level`` record the full
-    per-tier cadence.
-
-    An L=1 tree delegates to :func:`run_rounds_scan` — the same
-    lru-cached flat program, so the collapse is bitwise by
-    construction (the contract ``tests/test_hierarchy.py`` pins).
-
-    ``faults`` ride exactly as on the flat path (crash outages ANDed
-    into activity, guarded uploads at the DEVICE tier, quorum gating
-    the whole composed event)."""
-    if tau != tree.taus[0]:
-        raise ValueError(f"run tau={tau} but the tier tree aggregates "
-                         f"its first tier every {tree.taus[0]}")
-    if tree.levels == 1:
-        return run_rounds_scan(apply_fn, params, x_tr, y_tr, x_te, y_te,
-                               processed, act_all, tau, eta, max_pts,
-                               faults=faults, guard=guard, quorum=quorum)
-    if isinstance(processed, pl.FlatStreams):
-        T, n = processed.T, processed.n
-    else:
-        T, n = len(processed), len(processed[0])
-    if n != tree.n:
-        raise ValueError(f"run has n={n} devices but the tree has "
-                         f"n={tree.n}")
-
-    with monitoring.span("train.tiers"):
-        lvl = tree.level_rounds(T)
-        is_agg = lvl > 0
-        fp = tree.fingerprint()
-        if fp not in _HIER_SPECS:
-            _HIER_SPECS[fp] = _HierSpec(group_ids=tree.parents,
-                                        num_groups=tree.group_counts,
-                                        anc=tree.ancestors())
-
-    use_faults = faults is not None
-    guard_f = bool(guard) if use_faults else False
-    quorum_f = float(quorum) if use_faults else 0.0
-    prestage, args, fault_ops, cell = _stage_scan(
-        processed, act_all, y_tr, max_pts, x_tr, x_te, y_te, tau, faults,
-        is_agg, lvl)
-
-    fn = _hier_program(apply_fn, float(eta), prestage, use_faults,
-                       guard_f, quorum_f, fp)
-    with monitoring.span("train.device"), sanitize.hot_loop_guard():
-        res = fn(_stack(params, n), params, *args, *fault_ops,
-                 cell_all=cell)
-        losses, tl, ta, H_at = res[1:5]
-        jax.block_until_ready(losses)
-    with monitoring.span("train.readback"):
-        top = np.nonzero(lvl == tree.levels)[0]
-        tl, ta, H_at = np.asarray(tl), np.asarray(ta), np.asarray(H_at)
-        out = {"device_loss": list(np.asarray(losses)),
-               "test_loss": [float(v) for v in tl[top]],
-               "test_acc": [float(v) for v in ta[top]],
-               "agg_round": [int(t) for t in top],
-               "H_agg": list(H_at[top]),
-               "tier_agg_round": [int(t) for t in np.nonzero(is_agg)[0]],
-               "tier_agg_level": [int(v) for v in lvl[is_agg]]}
-        if use_faults:
-            surv, qokf = np.asarray(res[5]), np.asarray(res[6])
-            out["agg_survivors"] = [float(v) for v in surv[top]]
-            out["agg_quorum_ok"] = [bool(v > 0) for v in qokf[top]]
+        out = _history(res[1], report, *res[2:5], res[5:])
+        if tree is not None:
+            out["tier_agg_round"] = [int(t) for t in np.nonzero(is_agg)[0]]
+            out["tier_agg_level"] = [int(v) for v in lvl[is_agg]]
     return out
 
 
@@ -878,8 +825,8 @@ def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
 
     fn = _scan_chunk_program(apply_fn, float(eta), prestage, use_faults,
                              guard, quorum)
-    (x_dev, xb_all, idx_arg, yb, wts, counts, act, is_agg, x_te,
-     y_te) = args
+    # the operands from xb_all to is_agg have a leading round axis
+    x_dev, rounds, (x_te, y_te) = args[0], args[1:8], args[8:]
     keys = ["losses", "tl", "ta", "H_at"] + (
         ["surv", "qok"] if use_faults else [])
     t0 = start
@@ -890,11 +837,8 @@ def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
         sl = slice(t0, t1)
         with monitoring.span("train.device"), sanitize.hot_loop_guard():
             carry, ys = fn(
-                carry, x_dev,
-                None if xb_all is None else xb_all[sl],
-                None if idx_arg is None else idx_arg[sl],
-                yb[sl], wts[sl], counts[sl], act[sl], is_agg[sl], x_te,
-                y_te, *(op[sl] for op in fault_ops),
+                carry, x_dev, *(None if a is None else a[sl] for a in rounds),
+                x_te, y_te, *(op[sl] for op in fault_ops),
                 cell_all=None if cell is None else cell[sl])
             jax.block_until_ready(ys)
         with monitoring.span("train.readback"):
@@ -905,16 +849,9 @@ def _run_scan_checkpointed(apply_fn, params, n, T, tau, eta, prestage,
             ckpt.save(checkpoint_path, _as_state(carry, hist, t0),
                       metadata=run_meta)
 
-    is_agg_np = np.asarray(is_agg)
-    agg_rounds = np.nonzero(is_agg_np[:t0])[0]
-    out = {"device_loss": list(hist["losses"][:t0]),
-           "test_loss": [float(v) for v in hist["tl"][agg_rounds]],
-           "test_acc": [float(v) for v in hist["ta"][agg_rounds]],
-           "agg_round": [int(t) for t in agg_rounds],
-           "H_agg": list(hist["H_at"][agg_rounds])}
-    if use_faults:
-        out["agg_survivors"] = [float(v) for v in hist["surv"][agg_rounds]]
-        out["agg_quorum_ok"] = [bool(v > 0) for v in hist["qok"][agg_rounds]]
+    out = _history(hist["losses"][:t0],
+                   np.nonzero(np.asarray(rounds[-1])[:t0])[0], hist["tl"],
+                   hist["ta"], hist["H_at"], [hist[k] for k in keys[4:]])
     if t0 < T:
         out["stopped_at"] = int(t0)
     return out
@@ -1253,9 +1190,7 @@ def _bucket_program(apply_fn, eta: float, prestage: bool, mesh,
         x_rows = x_tr.reshape(x_tr.shape[0], -1)
 
         def pixels(xb, idx):
-            if not prestage:
-                xb = jnp.take(x_rows, idx, axis=0)
-            return xb.reshape(xb.shape[:-1] + x_tr.shape[1:])
+            return _round_pixels(xb, idx, x_rows, x_tr.shape[1:])
 
         def window(carry, xs):
             if faults:
@@ -1478,28 +1413,17 @@ def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
     out. This is the unit the warm re-staging cache memoizes."""
     S = len(processed_list)
     mp = list(max_points) if max_points is not None else None
-    item_bytes = int(np.prod(x_tr.shape[1:], dtype=np.int64)) * 4
 
-    if staging == "ragged":
-        batch = pl.stage_scenario_ragged(
-            processed_list, y_tr, act_list, tau, max_points=mp,
-            bucket=bucket)
-        _, T_b, n_b, R_b, C = batch.dims
-        n_pad = n_b                       # ragged is mesh=None only
-        n_win = T_b // tau
-        prestage = T_b * R_b * C * item_bytes <= PRESTAGE_LIMIT_BYTES
-    else:
-        batch = pl.stage_scenario_batch(
-            processed_list, y_tr, act_list, tau, max_points=mp,
-            bucket=bucket)
-        _, T_b, n_b, P_b = batch.dims
-        n_pad = n_b
-        if mesh is not None:
-            ndev = int(np.prod(mesh.devices.shape))
-            n_pad = -(-n_b // ndev) * ndev
-        n_win = T_b // tau
-        prestage = (S * T_b * n_pad * P_b * item_bytes
-                    <= PRESTAGE_LIMIT_BYTES)
+    stage_batch = (pl.stage_scenario_ragged if staging == "ragged"
+                   else pl.stage_scenario_batch)
+    batch = stage_batch(processed_list, y_tr, act_list, tau,
+                        max_points=mp, bucket=bucket)
+    T_b, n_b = batch.dims[1:3]
+    n_pad = n_b
+    if mesh is not None:                  # dense only: ragged has no mesh
+        ndev = int(np.prod(mesh.devices.shape))
+        n_pad = -(-n_b // ndev) * ndev
+    n_win = T_b // tau
 
     def stage(a):
         """(S, T_b, n_b, ...) -> (windows, tau, S, n_pad, ...): scan
@@ -1547,13 +1471,7 @@ def _stage_bucket_operands(processed_list, act_list, y_tr, tau, bucket,
             np.moveaxis(upl_w, 0, 1))), jnp.asarray(
             np.ascontiguousarray(np.moveaxis(cor_w, 0, 1))))
 
-    idx_dev = jnp.asarray(idx)
-    if prestage:
-        xb_all, idx_arg = _gather_rows(_to_device_cached(x_tr, rows=True),
-                                       idx_dev), None
-    else:
-        xb_all, idx_arg = None, idx_dev
-
+    xb_all, idx_arg, prestage = _stage_pixels(x_tr, idx)
     staged_args = (xb_all, idx_arg, jnp.asarray(yb), jnp.asarray(wts),
                    cell, jnp.asarray(counts), jnp.asarray(act),
                    jnp.asarray(agg_w)) + fault_ops
@@ -1687,43 +1605,19 @@ def run_rounds_batched(apply_fn, params_list, x_tr, y_tr, x_te, y_te,
         (tl,), (ta,) = ev.collect()
 
     with monitoring.span("train.readback"):
-        if use_faults:
-            surv_win, expd_win, qok_win = (np.asarray(r) for r in res[3:])
+        fault_ys = ([np.asarray(res[3]), np.asarray(res[5])]
+                    if use_faults else [])
         losses = np.asarray(losses).reshape(T_b, S, n_pad)
         H_w = np.asarray(H_w)
         hists = []
         for b in range(S):
             T, n = meta["T"][b], meta["n"][b]
             agg_rounds = np.nonzero(meta["is_agg"][b, :T])[0]
-            wins = agg_rounds // tau
-            h = {
-                "device_loss": list(losses[:T, b, :n]),
-                "test_loss": [float(v) for v in tl[wins, b]],
-                "test_acc": [float(v) for v in ta[wins, b]],
-                "agg_round": [int(t) for t in agg_rounds],
-                "H_agg": list(H_w[wins, b][:, :n])}
-            if use_faults:
-                h["agg_survivors"] = [float(v) for v in surv_win[wins, b]]
-                h["agg_quorum_ok"] = [bool(v > 0)
-                                      for v in qok_win[wins, b]]
-            hists.append(h)
+            hists.append(_history(
+                losses[:T, b, :n], agg_rounds, tl[:, b], ta[:, b],
+                H_w[:, b, :n], [f[:, b] for f in fault_ys],
+                at=agg_rounds // tau))
     return hists
-
-
-def run_rounds_batched_single(apply_fn, params, x_tr, y_tr, x_te, y_te,
-                              processed, act_all, tau: int, eta: float,
-                              max_pts: int, *, mesh="auto",
-                              staging: str = "dense", faults=None,
-                              guard: bool = True,
-                              quorum: float = 0.0) -> dict:
-    """Single-scenario entry to the batched path (``engine="batched"``
-    with S=1): same program structure, exact pad sizes."""
-    return run_rounds_batched(
-        apply_fn, [params], x_tr, y_tr, x_te, y_te, [processed],
-        [act_all], tau, eta, [max_pts], bucket="exact", mesh=mesh,
-        staging=staging,
-        faults=None if faults is None else [faults], guard=guard,
-        quorum=quorum)[0]
 
 
 def run_rounds_sharded(apply_fn, params, x_tr, y_tr, x_te, y_te, processed,

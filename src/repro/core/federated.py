@@ -9,10 +9,13 @@ Data offloading/discarding is applied to the physical sample streams by
 
 The training loop itself lives in :mod:`repro.core.engine`:
 ``run_network_aware`` is a thin wrapper that prepares the sample streams
-on the host and dispatches to the scan-compiled engine (default), the
-device-sharded engine (``engine="sharded"`` — shard_map over a "data"
-mesh, psum aggregation, eval streamed off the hot path) or the legacy
-per-round loop (``engine="legacy"``, kept as oracle/baseline).
+on the host and dispatches to one of three engines: the scan-compiled
+engine (``engine="scan"``, the default; ``hierarchy=`` selects its
+tiered program), the device-sharded engine (``engine="sharded"`` —
+shard_map over a "data" mesh, psum aggregation, eval streamed off the
+hot path) or the legacy per-round loop (``engine="legacy"``, kept as
+oracle/baseline). ``run_network_aware_batched`` trains a sweep bucket
+in one program.
 
 Baselines: ``centralized`` (all data at one node) and ``federated``
 (no movement, G_i = D_i) — both used by the Table II/III benchmarks.
@@ -121,15 +124,16 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
     ``fault_summary``/``agg_survivors``/``agg_quorum_ok``.
 
     ``checkpoint_path``/``checkpoint_every``/``resume``/``stop_after``
-    — window-boundary checkpointing of the scan engine (see
-    ``core.engine.run_rounds_scan``); other engines reject them.
+    — window-boundary checkpointing of the flat scan engine (see
+    ``core.engine.run_rounds_scan``); other engines and tier trees
+    reject them.
 
     ``hierarchy`` — optional :class:`repro.core.hierarchy.TierTree`:
-    aggregation composes up the tier tree on the scan substrate
-    (``core.engine.run_rounds_hierarchical``), with the tree's first
-    tier period required to equal ``cfg.tau``. Only ``engine`` values
-    "scan"/"auto"/"hierarchical" compose with it (the tree picks the
-    compiled program); an L=1 tree reproduces the flat scan bitwise.
+    aggregation composes up the tier tree in the scan engine's tiered
+    program (``core.engine.run_rounds_scan(tree=)``), with the tree's
+    device count required to equal ``cfg.n`` and its first tier period
+    ``cfg.tau``. Only ``engine`` values "scan"/"auto" compose with it;
+    an L=1 tree reproduces the flat scan bitwise.
     """
     x_tr, y_tr, x_te, y_te = data
     if prepared is not None:
@@ -140,27 +144,16 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
 
     extra = {}
     if hierarchy is not None:
-        if engine not in ("auto", "scan", "hierarchical"):
-            raise ValueError("hierarchy= runs on the scan substrate; "
+        if engine not in ("auto", "scan"):
+            raise ValueError("hierarchy= runs on the scan engine; "
                              f"got engine={engine!r}")
-        if hierarchy.n != cfg.n:
-            raise ValueError(f"tier tree has n={hierarchy.n} devices "
-                             f"but cfg.n={cfg.n}")
-        if hierarchy.taus[0] != cfg.tau:
-            raise ValueError(f"tier tree aggregates its first tier "
-                             f"every {hierarchy.taus[0]} rounds but "
-                             f"cfg.tau={cfg.tau}")
-        engine = "hierarchical"
+        engine = "scan"
         extra["hierarchy"] = {"levels": hierarchy.levels,
                               "group_counts": list(hierarchy.group_counts),
                               "taus": list(hierarchy.taus)}
     else:
-        if engine == "hierarchical":
-            raise ValueError("engine='hierarchical' needs a hierarchy= "
-                             "TierTree")
         engine = eng.resolve_engine(engine)
-    if (isinstance(streams, pl.FlatStreams)
-            and engine not in ("scan", "hierarchical")):
+    if isinstance(streams, pl.FlatStreams) and engine != "scan":
         raise ValueError("FlatStreams sparse staging is a scan-engine "
                          f"feature; got engine={engine!r}")
     fault_kw = {}
@@ -177,17 +170,10 @@ def run_network_aware(cfg: FedConfig, data, traces: CostTraces,
         ckpt_kw = dict(checkpoint_path=checkpoint_path,
                        checkpoint_every=checkpoint_every,
                        resume=resume, stop_after=stop_after)
-    runners = {"scan": eng.run_rounds_scan,
-               "hierarchical": functools.partial(
-                   eng.run_rounds_hierarchical, tree=hierarchy),
+    runners = {"scan": functools.partial(eng.run_rounds_scan,
+                                         tree=hierarchy),
                "sharded": functools.partial(eng.run_rounds_sharded,
                                             mesh=mesh),
-               # engine="batched" uses the mesh as given — None is the
-               # single-device program (the bitwise twin of "scan");
-               # pass a mesh, or go through run_network_aware_batched
-               # (mesh="auto"), for the sharded composition
-               "batched": functools.partial(
-                   eng.run_rounds_batched_single, mesh=mesh),
                "legacy": eng.run_rounds_legacy}
     if engine not in runners:
         raise ValueError(f"unknown engine {engine!r}; "

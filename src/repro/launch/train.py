@@ -356,16 +356,12 @@ def main(argv=None):
                          "20); the first period must equal --tau and "
                          "the last group count must be 1")
     ap.add_argument("--engine", default="auto",
-                    choices=["auto", "scan", "sharded", "batched",
-                             "legacy"],
-                    help="fog training engine: one compiled scan, the "
+                    choices=["auto", "scan", "sharded", "legacy"],
+                    help="fog training engine: one compiled scan "
+                         "(--tiers selects its tiered program), the "
                          "device-sharded scan (shard_map over a 'data' "
                          "mesh; auto picks it on multi-device hosts), "
-                         "the scenario-batched bucket program (S=1 "
-                         "slice of the sweep engine, single-device; "
-                         "sweeps shard it via run_network_aware_"
-                         "batched), or the legacy per-round oracle "
-                         "loop")
+                         "or the legacy per-round oracle loop")
     ap.add_argument("--faults", default="none",
                     choices=["none", "straggle", "drop", "crash",
                              "corrupt", "mixed"],

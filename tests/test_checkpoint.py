@@ -174,5 +174,5 @@ def test_resume_requires_scan_engine():
     cfg, data, traces, adj, plan, streams = _setup()
     with pytest.raises(ValueError, match="scan-engine"):
         F.run_network_aware(cfg, data, traces, adj, plan,
-                            streams=streams, engine="batched",
+                            streams=streams, engine="sharded",
                             resume="/tmp/does-not-matter.msgpack")

@@ -191,9 +191,7 @@ def test_stage_scenario_batch_shapes_and_phantoms():
 def test_batched_single_matches_scan_bitwise():
     s = _setup()
     h_scan = _scan(s)
-    h_bat = F.run_network_aware(s[0], s[1], None, None, s[2],
-                                streams=copy.deepcopy(s[3]),
-                                engine="batched", mesh=None)
+    h_bat = _batched([s], s[1], mesh=None, bucket="exact")[0]
     _assert_matches(h_scan, h_bat)
 
 

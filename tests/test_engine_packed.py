@@ -301,7 +301,7 @@ def test_packed_hierarchical_matches_legacy(chunk8):
     params, apply_fn = eng.make_model("mlp", jax.random.PRNGKey(0))
     tree = hr.TierTree.balanced(4, (2, 1), (2, 2))
     monitoring.reset()
-    h = eng.run_rounds_hierarchical(
+    h = eng.run_rounds_scan(
         apply_fn, params, x_tr, y_tr, x_te, y_te, processed,
         np.ones((4, 4), bool), 2, 0.1, pl.pad_size(processed), tree=tree)
     assert monitoring.totals()["train.stage"]["packed"] == 1
@@ -329,7 +329,7 @@ def _run_engine(kind, processed, tau=2):
             apply_fn, [params], x_tr, y_tr, x_te, y_te, [processed], [act],
             tau, 0.1, mesh=None, staging=kind.split("_")[1])[0]
     elif kind == "hierarchical":
-        h = eng.run_rounds_hierarchical(
+        h = eng.run_rounds_scan(
             apply_fn, params, x_tr, y_tr, x_te, y_te, processed, act, tau,
             0.1, P, tree=hr.TierTree.balanced(n, (2, 1), (tau, 2 * tau)))
     else:

@@ -213,7 +213,10 @@ def test_scan_matches_legacy_under_faults():
 def test_batched_matches_scan_under_faults():
     fs = _mixed_faults()
     hs = _run("scan", faults=fs, guard=True, quorum=0.3)
-    hb = _run("batched", faults=fs, guard=True, quorum=0.3)
+    cfg, data, _, _, plan, streams = _setup()
+    hb = F.run_network_aware_batched([cfg], data, [plan], streams=[streams],
+                                     mesh=None, bucket="exact", faults=[fs],
+                                     guard=True, quorum=0.3)[0]
     # the batched engine evaluates through the stacked AsyncEvaluator,
     # not in-scan: its test-loss mean rounds in another order (1 ulp
     # observed on the CPU), so losses match to 1e-6 (~8 f32 ulps, as

@@ -1,7 +1,7 @@
 """Hierarchical fog aggregation (ISSUE-10): TierTree validation and
 staging, aggregate_tier vs the flat aggregate_edges oracle, tier
 composition telescoping to eq. (4), the L=1 bitwise-collapse contract
-through run_rounds_hierarchical/run_network_aware (clean, churn and
+through run_rounds_scan(tree=)/run_network_aware (clean, churn and
 fault runs), intra-tier movement boundaries, per-tier schedule
 restriction, traffic accounting, and the (pod, data) tier mesh."""
 import os
@@ -232,11 +232,14 @@ def test_hierarchy_wiring_validation():
     tree = hr.TierTree.balanced(8, (2, 1), (2, 4))
     with pytest.raises(ValueError, match="engine"):
         F.run_network_aware(cfg, data, etr, None, plan, streams=streams,
-                            schedule=sched, engine="batched",
+                            schedule=sched, engine="sharded",
                             hierarchy=tree)
     with pytest.raises(ValueError):
         F.run_network_aware(cfg, data, etr, None, plan, streams=streams,
                             schedule=sched, engine="hierarchical")
+    with pytest.raises(ValueError, match="checkpoint"):
+        F.run_network_aware(cfg, data, etr, None, plan, streams=streams,
+                            schedule=sched, hierarchy=tree, stop_after=4)
     bad_tau = hr.TierTree.balanced(8, (2, 1), (4, 8))
     with pytest.raises(ValueError, match="tau"):
         F.run_network_aware(cfg, data, etr, None, plan, streams=streams,

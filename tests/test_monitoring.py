@@ -192,8 +192,8 @@ def test_batched_path_records_the_engine_spans():
     cfg, data, traces, adj, plan, streams = _setup()
     eng.reset_staged_cache()
     monitoring.reset()
-    F.run_network_aware(cfg, data, traces, adj, plan, streams=streams,
-                        engine="batched")
+    F.run_network_aware_batched([cfg], data, [plan], streams=[streams],
+                                mesh=None, bucket="exact")
     tot = monitoring.totals()
     for name in ("train.stage", "train.device", "train.eval",
                  "train.readback"):
@@ -230,7 +230,7 @@ def test_programs_carry_the_four_scopes_unnested(kind):
         eng._HIER_SPECS[fp] = eng._HierSpec(
             group_ids=tree.parents, num_groups=tree.group_counts,
             anc=tree.ancestors())
-        fn = eng._hier_program(apply_fn, 0.1, False, tree_fp=fp)
+        fn = eng._scan_program(apply_fn, 0.1, False, tree_fp=fp)
         args = args + (jnp.asarray(tree.level_rounds(T)),)
     text = fn.lower(*args).compile().as_text()
     # the persistent compile cache keys a program by its name and its
