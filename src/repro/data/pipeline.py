@@ -159,11 +159,12 @@ def apply_movement(streams: FogStreams, plan: MovementPlan,
     round t (= retained local share + arrivals offloaded at t−1).
     Fractions are realized by randomized rounding of contiguous splits.
 
-    Operates on the plan's sparse edges: each device's (n+1,) share
-    row is reconstructed into one reused buffer from its outgoing
-    edges, so routing never touches the dense (T, n, n) tensor yet
-    stays bitwise-identical to ``apply_movement_dense`` (the preserved
-    oracle) — the reconstructed row IS the dense row.
+    Operates on the plan's sparse edges: the (n+1,) share rows are
+    rebuilt from the edges and ``r`` a block of cells at a time, so
+    routing never touches the dense (T, n, n) tensor yet stays
+    bitwise-identical to ``apply_movement_dense`` (the preserved
+    oracle), generator state included — the rebuilt row IS the dense
+    row.
     """
     with monitoring.span("prep.route") as sp:
         processed, collected, kept = _route_cells(streams, plan, rng)
@@ -171,51 +172,70 @@ def apply_movement(streams: FogStreams, plan: MovementPlan,
     return processed
 
 
+# share rows built per block of cells: the (cells, n+1) float64 scratch
+# stays under 8 MB whatever n is
+_ROUTE_BLOCK = 1 << 20
+
+
 def _route_cells(streams: FogStreams, plan: MovementPlan, rng):
     """:func:`apply_movement` without its span; also returns the
-    samples collected and the samples processed."""
+    samples collected and the samples processed.
+
+    Array work over the horizon; only the permutations stay per cell:
+    one ``rng.permutation`` per nonempty (t, i) cell, in (t, i) order,
+    so the generator is drawn exactly as a per-cell loop draws it. Each
+    cell's (n+1) share row (edges, then ``r``) is clipped, divided by
+    its whole row's sum, cumsummed and floored — the loop's arithmetic
+    on a (cells, n+1) table, row for row. Every nonempty part gets its
+    destination cell ((t, i) kept, (t+1, j) offloaded; offloads past
+    the horizon and the tail beyond the last cut are discarded) and one
+    stable sort by destination orders a cell as arrivals from t−1 by
+    source, then its own retained part. The cells returned are views of
+    one array."""
     # foglint: disable=rng-stream-discipline -- documented default: rng=None selects fixed stream 1 (kept distinct from the collection stream); callers on the scenario path pass a derived Generator
     rng = rng or np.random.default_rng(1)
     n, T = streams.n, streams.T
-    # per-destination part lists; one concatenate per (t, i) at the end
-    # instead of the old per-(i, j) quadratic re-concatenation
-    buckets: list[list[list[np.ndarray]]] = \
-        [[[] for _ in range(n)] for _ in range(T)]
-    row_buf = np.zeros(n + 1)
-    collected = kept = 0
-    for t in range(T):
-        src, dst, qty = plan.round_edges(t)
-        starts_e = np.searchsorted(src, np.arange(n + 1))
-        r_t = plan.r[t]
-        for i in range(n):
-            idx = streams.collected[t][i]
-            k = len(idx)
-            if k == 0:
-                continue
-            collected += k
-            idx = rng.permutation(idx)
-            row_buf[:] = 0.0
-            sl = slice(starts_e[i], starts_e[i + 1])
-            row_buf[dst[sl]] = qty[sl]
-            row_buf[n] = r_t[i]
-            fracs = np.clip(row_buf, 0, None)
-            fracs = fracs / max(fracs.sum(), 1e-12)
-            cuts = np.floor(np.cumsum(fracs) * len(idx) + 1e-9).astype(int)
-            ends = cuts[:-1]                     # last bucket = discard
-            starts = np.empty_like(ends)
-            starts[0] = 0
-            starts[1:] = ends[:-1]
-            for j in np.nonzero(ends > starts)[0]:
-                part = idx[starts[j]:ends[j]]
-                if j == i:
-                    buckets[t][i].append(part)
-                    kept += part.size
-                elif t + 1 < T:
-                    buckets[t + 1][j].append(part)
-                    kept += part.size
-    processed = [[np.concatenate(cell) if cell else np.empty(0, np.int64)
-                  for cell in row] for row in buckets]
-    return processed, collected, kept
+    cells = [ix for row in streams.collected for ix in row]
+    lens = np.fromiter((len(ix) for ix in cells), np.int64, T * n)
+    perms = [rng.permutation(ix) for ix in cells if len(ix)]
+    ids = np.concatenate(perms) if perms else np.empty(0, np.int64)
+    first = np.cumsum(lens) - lens                  # cell starts in ids
+    e = plan.edges
+    ekey = e.t * np.int64(n) + e.src                # sorted: (t, src, dst)
+    r = np.asarray(plan.r).reshape(-1)
+    src, size, dest = [], [], []
+    block = max(1, _ROUTE_BLOCK // (n + 1))
+    for c0 in range(0, T * n, block):
+        c1 = min(c0 + block, T * n)
+        lo, hi = np.searchsorted(ekey, (c0, c1))
+        rows = np.zeros((c1 - c0, n + 1))
+        rows[ekey[lo:hi] - c0, e.dst[lo:hi]] = e.qty[lo:hi]
+        rows[:, n] = r[c0:c1]
+        np.clip(rows, 0, None, out=rows)
+        rows /= np.maximum(rows.sum(1), 1e-12)[:, None]
+        ends = np.floor(np.cumsum(rows[:, :n], 1) * lens[c0:c1, None]
+                        + 1e-9).astype(np.int64)
+        sizes = np.diff(ends, axis=1, prepend=0)
+        cc, jj = np.nonzero(sizes)                  # (cell, j) order
+        c = cc + c0
+        own = jj == c % n
+        keep = own | (c < (T - 1) * n)              # t + 1 < T
+        cc, jj, c, own = cc[keep], jj[keep], c[keep], own[keep]
+        sz = sizes[cc, jj]
+        src.append(first[c] + ends[cc, jj] - sz)
+        size.append(sz)
+        dest.append(np.where(own, c, (c // n + 1) * n + jj))
+    src, size, dest = (np.concatenate(a) for a in (src, size, dest))
+    order = np.argsort(dest, kind="stable")
+    size = size[order]
+    at = np.concatenate(([0], np.cumsum(size)))     # part starts in out
+    out = ids[np.repeat(src[order] - at[:-1], size)
+              + np.arange(at[-1], dtype=np.int64)]
+    bounds = at[np.searchsorted(dest[order], np.arange(T * n + 1))]
+    processed = [[out[bounds[c]:bounds[c + 1]]
+                  if bounds[c + 1] > bounds[c] else np.empty(0, np.int64)
+                  for c in range(t * n, t * n + n)] for t in range(T)]
+    return processed, int(lens.sum()), int(at[-1])
 
 
 def apply_movement_flat(flat: FlatStreams, plan: MovementPlan,

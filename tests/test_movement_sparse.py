@@ -139,24 +139,102 @@ def test_sparse_repair_bitwise_on_greedy_plans():
     np.testing.assert_array_equal(got.r, want.r)
 
 
-@pytest.mark.parametrize("fractional", [False, True])
-def test_apply_movement_bitwise_vs_dense_oracle(fractional):
+# one (n+1) share row, unnormalised, whose floor cuts at 54 samples
+# differ when it is normalised by a sequential sum instead of numpy's
+# pairwise sum of the whole row
+_ULP_ROW = [0.24167571409434496, 0.8881183206591798, 0.22586942841732438,
+            0.1245547058352835, 0.2883307570075776, 0.5861230648127328,
+            0.5540905021732678, 0.8097107759127777, 0.5604759520061858,
+            0.2884212144312105, 0.6840672449878391]
+
+
+def _route_case(case):
+    """(streams, plan) of one routing case of the oracle test."""
     rng = np.random.default_rng(11)
-    n, T = 6, 7
-    y = rng.integers(0, 10, 1500)
-    streams = pl.poisson_streams(n, T, y, rng=rng, mean_per_round=12)
+    n, T, mean = 6, 7, 12
+    if case in ("greedy", "softmax", "discard_rows", "last_round_offload",
+                "empty_cells", "zero_row", "pairwise_sum"):
+        n, T, mean = 10, 12, 25
+    elif case == "setting_d":
+        n, T, mean = 10, 100, 60
+    y = rng.integers(0, 10, 6000)
+    streams = pl.poisson_streams(n, T, y, rng=rng, mean_per_round=mean)
     adj = make_topology("random", n, rng, rho=0.6)
-    if fractional:
+    if case == "fractional":
         plan = _fractional_plan(T, n, adj, rng)
-        plan = mv.MovementPlan(r=plan.r, edges=plan.edges, n=n)
-    else:
-        plan = mv.greedy_linear(synthetic_costs(n, T, rng), adj)
-    got = pl.apply_movement(streams, plan, np.random.default_rng(42))
-    want = pl.apply_movement_dense(streams, plan,
-                                   np.random.default_rng(42))
+        return streams, mv.MovementPlan(r=plan.r, edges=plan.edges, n=n)
+    if case == "softmax":
+        return streams, _fractional_plan(T, n, adj, rng)
+    if case == "pairwise_sum":
+        plan = _fractional_plan(T, n, fully_connected(n), rng)
+        s, r = plan.s.copy(), plan.r.copy()
+        row = np.array(_ULP_ROW)
+        s[1, 0], r[1, 0] = row[:n], row[n]
+        streams.collected[1][0] = rng.choice(len(y), 54, replace=False)
+
+        def cuts(total):
+            return np.floor(np.cumsum(row / total) * 54 + 1e-9)
+        assert not np.array_equal(cuts(row.sum()), cuts(np.cumsum(row)[-1]))
+        return streams, mv.MovementPlan(s=s, r=r)
+    if case == "setting_d":
+        D = pl.counts(streams)
+        tr = with_capacity(synthetic_costs(n, T, rng), float(D.mean()))
+        adj = fully_connected(n)
+        plan = mv.repair_capacities(mv.greedy_linear(tr, adj), tr, adj, D)
+        qty = plan.edges.qty
+        assert ((qty > 0) & (qty < 1)).any()       # fractional repaired rows
+        return streams, plan
+    tr = synthetic_costs(n, T, rng, f_err=0.4 if case == "greedy" else 0.7)
+    plan = mv.greedy_linear(tr, adj)
+    if case == "discard_rows":
+        s, r = plan.s.copy(), plan.r.copy()
+        drop = rng.random((T, n)) < 0.3
+        s[drop], r[drop] = 0.0, 1.0
+        plan = mv.MovementPlan(s=s, r=r)
+    elif case == "last_round_offload":
+        s = np.zeros((T, n, n))
+        s[:, np.arange(n), (np.arange(n) + 1) % n] = 0.5
+        s[:, np.arange(n), np.arange(n)] = 0.25
+        plan = mv.MovementPlan(s=s, r=np.full((T, n), 0.25))
+    elif case == "empty_cells":
+        for t, i in zip(*np.nonzero(rng.random((T, n)) < 0.3)):
+            streams.collected[t][i] = np.empty(0, np.int64)
+        streams.collected[3] = [np.empty(0, np.int64)] * n
+    elif case == "zero_row":
+        s, r = plan.s.copy(), plan.r.copy()
+        s[2, 4], r[2, 4] = 0.0, 0.0
+        s[T - 1, 0], r[T - 1, 0] = 0.0, 0.0
+        plan = mv.MovementPlan(s=s, r=r)
+    return streams, plan
+
+
+# ids "False" and "True" name the bang-bang and the fractional plan at
+# n = 6; the cases after them widen the plans and the streams
+@pytest.mark.parametrize("case", [
+    pytest.param("bang_bang", id="False"),
+    pytest.param("fractional", id="True"),
+    "greedy", "softmax", "setting_d", "discard_rows",
+    "last_round_offload", "empty_cells", "zero_row", "pairwise_sum"])
+def test_apply_movement_bitwise_vs_dense_oracle(case):
+    from repro.core import monitoring
+
+    streams, plan = _route_case(case)
+    n, T = streams.n, streams.T
+    monitoring.reset()
+    rng_got, rng_want = np.random.default_rng(42), np.random.default_rng(42)
+    got = pl.apply_movement(streams, plan, rng_got)
+    want = pl.apply_movement_dense(streams, plan, rng_want)
     for t in range(T):
         for i in range(n):
+            assert got[t][i].dtype == want[t][i].dtype
             np.testing.assert_array_equal(got[t][i], want[t][i])
+    # the generator is left where the per-cell loop leaves it
+    assert rng_got.integers(0, 2 ** 62) == rng_want.integers(0, 2 ** 62)
+    route = monitoring.totals()["prep.route"]
+    assert route["collected"] == sum(len(ix) for row in streams.collected
+                                     for ix in row)
+    assert route["processed"] == sum(len(ix) for row in want for ix in row)
+    monitoring.reset()
 
 
 def test_plan_cost_matches_dense_formula():
